@@ -1,5 +1,5 @@
 # Top-level convenience targets (parity: reference ./configure && make).
-.PHONY: all native test test-quick test-native asan bench smoke \
+.PHONY: all native test test-quick test-native asan bench smoke chip-smoke \
 	telemetry-check chaos stream lint sanitize recovery crash qos \
 	paged timeline perfgate fleet fleet-chaos mesh help
 
@@ -17,11 +17,18 @@ test-native:
 asan:
 	$(MAKE) -C quiver_tpu/cpp asan
 
+# needs a TPU (exits 2 without one)
 bench:
 	python bench.py
 
+# CPU rehearsal of bench.py's control flow: says nothing about the chip
 smoke:
-	python bench.py --small --iters 5
+	JAX_PLATFORMS=cpu python bench.py --small --iters 5
+
+# the quickest proof that the main path runs on the chip (needs a TPU;
+# from a sandbox: chiprun -- python chip_smoke.py)
+chip-smoke:
+	python chip_smoke.py
 
 test-quick:
 	python -m pytest tests/ -m "not slow" -q
@@ -107,4 +114,4 @@ mesh:
 	python -m pytest tests/ -m mesh -q
 
 help:
-	@echo "targets: native | test | test-quick | test-native | asan | bench | smoke | telemetry-check | chaos | stream | lint | sanitize | recovery | crash | qos | paged | timeline | perfgate | fleet | fleet-chaos | mesh | help"
+	@echo "targets: native | test | test-quick | test-native | asan | bench | smoke | chip-smoke | telemetry-check | chaos | stream | lint | sanitize | recovery | crash | qos | paged | timeline | perfgate | fleet | fleet-chaos | mesh | help"

@@ -342,8 +342,8 @@ def load_baseline(path: str, backend: str) -> Optional[dict]:
 
 def save_baseline(path: str, backend: str, measured: Dict[str, dict],
                   device: bool) -> None:
-    """Read-merge-replace under the same flock bench.py's section saver
-    takes, so a concurrent bench run can't lose either side's write."""
+    """Read-merge-replace under a flock, so two gate runs can't lose
+    each other's write."""
     import fcntl
 
     metrics = {m: {"min_ms": r.get("min_ms", r["median_ms"]),
@@ -354,8 +354,6 @@ def save_baseline(path: str, backend: str, measured: Dict[str, dict],
         fcntl.flock(lk, fcntl.LOCK_EX)
         try:
             disk = _load_state(path)
-            disk.setdefault("version", 2)
-            disk.setdefault("states", {})
             disk.setdefault("perfgate", {})[backend] = {
                 "metrics": metrics, "device": device,
                 "source": "live_device" if device else "cpu_rehearsal",

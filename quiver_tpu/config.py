@@ -476,12 +476,11 @@ def resolve_sample_rng(sample_rng: str,
 
     Resolution order: explicit kwarg > gather-mode requirement >
     ``QUIVER_TPU_SAMPLE_RNG`` env / tuned file > backend default.
-    Backend default (measured on a real v5e, docs/TPU_MEASUREMENTS.md
-    round 2): ``"hash"`` (counter-hash uniforms) on accelerators — the
-    3-hop pipeline runs 50.8M SEPS with hash vs 34.6M threefry / 31.3M
-    rbg — and ``"key"`` (key-based ``jax.random.uniform``) on CPU, where
-    threefry is fast and tests want reproducible streams.  PROVISIONAL:
-    measured at 100K-node scale; pending products-scale re-measurement.
+    Backend default: ``"hash"`` (counter-hash uniforms) on accelerators
+    and ``"key"`` (key-based ``jax.random.uniform``) on CPU, where
+    threefry is fast and tests want reproducible streams.  hash against
+    threefry / rbg on the chip at a real graph size: not measured
+    (ROADMAP D10) — the default is a choice, not a result.
 
     ``gather_mode`` (the RESOLVED mode, if the caller has one): the
     fused Pallas window kernel (``pwindow``) only supports the in-kernel
@@ -573,12 +572,10 @@ def resolve_gather_mode(gather_mode: str,
 
     Resolution order: explicit kwarg > ``QUIVER_TPU_GATHER_MODE`` env /
     tuned file > backend default.  Backend default: ``"lanes"``
-    (row-gather + VPU lane select) on accelerators, where XLA's 1-D
-    scalar gather serializes (docs/TPU_MEASUREMENTS.md round 2: 3-hop
-    lanes 27 ms vs xla 237 ms per batch on v5e); plain ``"xla"`` take on
-    CPU.  PROVISIONAL: those numbers come from a 100K-node graph — the
-    ranking is pending re-measurement at production scale (100M+ nodes,
-    where HBM pressure and table width change the gather trade-offs).
+    (row-gather + VPU lane select) on accelerators, on the expectation
+    that XLA's 1-D scalar gather serializes there; plain ``"xla"`` take
+    on CPU.  The ranking of the modes on the chip at a real graph size:
+    not measured (ROADMAP D10) — the default is a choice, not a result.
 
     ``sample_rng`` (the caller's RAW kwarg): when ``auto`` resolution
     lands on the Pallas ``pwindow`` kernel (hash-RNG-only) but the user

@@ -11,6 +11,7 @@ from __future__ import annotations
 import ctypes
 import subprocess
 import threading
+import warnings
 from pathlib import Path
 from typing import List, Optional, Sequence, Tuple
 
@@ -37,8 +38,13 @@ def _build() -> Optional[ctypes.CDLL]:
         ]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
             _build_failed = True
+            stderr = getattr(e, "stderr", None) or b""
+            warnings.warn(
+                f"native sampler build failed ({' '.join(cmd)}): {e}\n"
+                f"{stderr.decode(errors='replace')[-4000:]}\n"
+                "falling back to the numpy host sampler", RuntimeWarning)
             return None
         return ctypes.CDLL(str(_LIB))
 

@@ -16,12 +16,8 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 __all__ = ["TpuComm", "getNcclId"]
 

@@ -25,7 +25,7 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
                       avg_deg: int = 8, feat_dim: int = 16,
                       batch_per_dev: int = 16,
                       sizes: Sequence[int] = (4, 3),
-                      steps: int = 1, classes: int = 8,
+                      steps: int = 1, classes: int = 8, hidden: int = 32,
                       lr: float = 3e-3, seed: int = 0,
                       learnable_labels: bool = True,
                       hier: Optional[tuple] = None):
@@ -33,8 +33,11 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
 
     Returns a dict with per-step ``losses``, the sampler's summed overflow
     counts, and the feature-store overflow counts — callers assert on
-    them.  Labels are a linear function of the features by default so the
-    loss can actually decrease (random labels can't prove learning).
+    them — plus the ``sampler``, the ``feature`` store and the host truth
+    they were built from (``topo``, ``feat``), for callers that compare
+    the sharded stack with it.  Labels are a linear function of the
+    features by default so the loss can actually decrease (random labels
+    can't prove learning).
 
     ``hier=(n_hosts, hot_frac)`` swaps the flat DistFeature for the
     two-tier :class:`HierFeature` over a ``[n_hosts, n_devices/n_hosts]``
@@ -93,7 +96,7 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
         dist_feat = DistFeature.from_global_feature(feat, mesh, info)
     sampler = DistGraphSampler(topo, mesh, sizes=list(sizes))
 
-    model = GraphSAGE(hidden=32, out_dim=classes, num_layers=len(sizes),
+    model = GraphSAGE(hidden=hidden, out_dim=classes, num_layers=len(sizes),
                       dropout=0.0)
     B = batch_per_dev
     tx = optax.adam(lr)
@@ -139,4 +142,6 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
         losses.append(float(loss))
     return dict(losses=losses, sampler_overflow=sampler_overflow,
                 feature_overflow=feat_overflow, mesh=mesh,
-                node_count=n_nodes, dcn_crossings=dcn_crossings)
+                node_count=n_nodes, dcn_crossings=dcn_crossings,
+                sampler=sampler, feature=hier_feat or dist_feat,
+                topo=topo, feat=feat)

@@ -62,6 +62,18 @@ def _fresh_bucket(n: int) -> int:
     return p
 
 
+def _lookup_tables(tables, idx):
+    """``Feature.lookup_device`` over explicit ``(hot, order)`` arrays
+    (from ``Feature._device_tables``), so a jitted caller can take the
+    tables as arguments."""
+    import jax.numpy as jnp
+
+    hot, order = tables
+    if order is not None:
+        idx = jnp.take(order, idx, mode="clip")
+    return jnp.take(hot, idx, axis=0)
+
+
 @dataclass
 class DeviceConfig:
     """Pre-partitioned placement (parity: ``feature.py:17-24``)."""
@@ -421,6 +433,14 @@ class Feature:
         n_cold = self.node_count - self.cache_count
         if n_cold <= 0:
             return self  # fully HBM-resident: nothing to page
+        import jax
+
+        if jax.default_backend() == "tpu":
+            # the page-gather kernel compiles with Mosaic there: refuse
+            # now, by name, what its compiler would refuse at first use
+            from .ops.pallas import check_lane_width
+
+            check_lane_width("feature_paged (page_gather)", self.dim)
         dt = np.dtype(self._hot_dtype())
         row_bytes = dt.itemsize * self.dim
         R = int(page_rows) if page_rows else default_page_rows(row_bytes)
@@ -635,7 +655,7 @@ class Feature:
         merge compiles once per (batch, bucket) instead of per batch — and
         only ``~n_cold`` rows cross PCIe, not the full batch width (the
         round-1 path gathered full-size hot AND cold then ``where``-merged:
-        2x traffic; VERDICT weak #6).  With the overlay enabled, the
+        2x traffic).  With the overlay enabled, the
         recurring part of those cold rows stops crossing at all — it is
         served from the HBM overlay table (``_stage_overlay``).
         """
@@ -1019,22 +1039,29 @@ class Feature:
         while len(self._inflight) > 8 and self._inflight[0].done():
             self._inflight.popleft()
 
-    def lookup_device(self, idx):
-        """Pure-device gather for jit pipelines (requires full HBM cache).
-        Applies ``feature_order`` on device; safe to call under jit."""
+    def _device_tables(self):
+        """``(hot, order)``: the device arrays :meth:`lookup_device`
+        reads (``order`` is None without a cache reorder).  Jitted
+        pipelines pass these as ARGUMENTS to :func:`_lookup_tables` — a
+        table captured by the closure is baked into the executable as a
+        constant, one more copy of it in HBM per program."""
         import jax.numpy as jnp
 
         self.lazy_init_from_ipc_handle()
         assert 0 < self.node_count <= self.cache_count, (
             "lookup_device needs a (built) fully HBM-resident feature"
         )
-        if self.feature_order is not None:
-            if getattr(self, "_order_dev", None) is None:
-                self._order_dev = jnp.asarray(
-                    self.feature_order.astype(np.int32)
-                )
-            idx = jnp.take(self._order_dev, idx, mode="clip")
-        return jnp.take(self.hot, idx, axis=0)
+        if (self.feature_order is not None
+                and getattr(self, "_order_dev", None) is None):
+            self._order_dev = jnp.asarray(
+                self.feature_order.astype(np.int32)
+            )
+        return self.hot, getattr(self, "_order_dev", None)
+
+    def lookup_device(self, idx):
+        """Pure-device gather for jit pipelines (requires full HBM cache).
+        Applies ``feature_order`` on device; safe to call under jit."""
+        return _lookup_tables(self._device_tables(), idx)
 
     # ------------------------------------------------------------------
     def size(self, dim: int) -> int:

@@ -50,7 +50,8 @@ import numpy as np
 from .. import telemetry
 from ..ops.paged import PageTable, PagedStore, default_page_rows
 from ..recovery.registry import program_cache
-from .topology import SHARD_AXIS, build_mesh, row_shard, shard_ranges
+from .topology import (SHARD_AXIS, build_mesh, row_shard, shard_devices,
+                       shard_ranges, stack_shards)
 
 __all__ = ["MeshFeature"]
 
@@ -78,7 +79,7 @@ class MeshFeature:
     def __init__(self, table: np.ndarray, n_shards: Optional[int] = None,
                  mesh=None, page_rows: int = 0,
                  pool_pages: Optional[int] = None):
-        import jax.numpy as jnp
+        import jax
 
         from ..config import get_config
 
@@ -110,15 +111,21 @@ class MeshFeature:
         self._table_np = table
         self._fns = _ShardFaultFns(self)
         self._stores = []
-        for lo, hi in self.ranges:
+        self._devices = shard_devices(self.mesh)[0]
+        for (lo, hi), dev in zip(self.ranges, self._devices):
             rows = np.zeros((self.rows_per_shard, self.dim),
                             dtype=self.dtype)
             rows[: hi - lo] = table[lo:hi]
             pt = PageTable(n_rows=self.rows_per_shard, cache_count=0,
                            page_rows=self.page_rows,
                            pool_pages=self.pool_pages)
-            store = PagedStore(pt, rows, cache_count=0, dim=self.dim,
-                               dtype=self.dtype)
+            with jax.default_device(dev):
+                store = PagedStore(pt, rows, cache_count=0, dim=self.dim,
+                                   dtype=self.dtype)
+            # COMMIT the pool to its shard's device: faults then scatter
+            # into it there, and the mesh-wide view is assembled from
+            # the pools in place
+            store.frames = jax.device_put(store.frames, dev)
             store._feature = self._fns
             self._stores.append(store)
         self.pool_pages = self._stores[0].table.pool_pages  # post-clamp
@@ -165,7 +172,7 @@ class MeshFeature:
         batch size: ONE executable per ``(B_pad, n_shards)``."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         key = ("gather", b_pad, self.n_shards)
@@ -206,6 +213,7 @@ class MeshFeature:
         batch's working set (caller falls back), else whether any page
         actually faulted (caller marks the views dirty).  Call with
         ``_lock`` held."""
+        import jax
         import jax.numpy as jnp
 
         dirtied = False
@@ -217,7 +225,9 @@ class MeshFeature:
             resident = store.frame_of_pages()[pages] >= 0
             if resident.all():
                 continue
-            if store._fault_pages(pages, jnp, telemetry) is None:
+            with jax.default_device(self._devices[s]):  # H2D lands there
+                faulted = store._fault_pages(pages, jnp, telemetry)
+            if faulted is None:
                 store.fallbacks += 1
                 return None
             dirtied = True
@@ -229,13 +239,10 @@ class MeshFeature:
         fault dirtied a shard — the steady state moves zero bytes.
         Call with ``_lock`` held."""
         import jax
-        import jax.numpy as jnp
 
-        frames = jnp.stack([s.frames for s in self._stores])
         lookup = np.stack([s.frame_of_pages() for s in self._stores])
-        return (jax.device_put(frames, self._frames_sharding),
-                jax.device_put(jnp.asarray(lookup),
-                               self._frames_sharding))
+        return (stack_shards(self.mesh, [s.frames for s in self._stores]),
+                jax.device_put(lookup, self._frames_sharding))
 
     # -- the batch gather ----------------------------------------------
     def __getitem__(self, node_idx):
@@ -304,7 +311,10 @@ class MeshFeature:
         with self._lock:
             per_shard = [dict(range=list(r),
                               resident_pages=s.table.resident_pages(),
-                              fallbacks=s.fallbacks)
+                              fallbacks=s.fallbacks,
+                              # where the shard's frame pool lives
+                              device=str(next(iter(s.frames.devices()))),
+                              bytes=int(s.frames.nbytes))
                          for r, s in zip(self.ranges, self._stores)]
             return dict(
                 n_shards=self.n_shards, rows_per_shard=self.rows_per_shard,

@@ -33,7 +33,8 @@ from .. import telemetry
 from ..analysis.staging import no_sync
 from ..ops.sample import SampleOut, sample_neighbors_overlay
 from ..recovery.registry import program_cache
-from .topology import SHARD_AXIS, build_mesh, row_shard, shard_ranges
+from .topology import (SHARD_AXIS, build_mesh, row_shard, shard_devices,
+                       shard_ranges, stack_shards)
 
 __all__ = ["MeshSampler"]
 
@@ -51,7 +52,7 @@ class MeshSampler:
     def __init__(self, indptr: np.ndarray, indices: np.ndarray,
                  n_shards: Optional[int] = None, mesh=None,
                  gather_mode: str = "xla", sample_rng: str = "auto"):
-        import jax.numpy as jnp
+        import jax
 
         from ..config import get_config, resolve_sample_rng
 
@@ -76,21 +77,24 @@ class MeshSampler:
         # ONE sampling executable reused by every shard
         edge_pad = _pow2(max(
             int(indptr[hi] - indptr[lo]) for lo, hi in self.ranges))
-        self._indptr, self._indices = [], []
-        for lo, hi in self.ranges:
+        # each shard's CSR (and its empty overlay) is COMMITTED to that
+        # shard's device, so its hop runs there on data that never left
+        self._devices = shard_devices(self.mesh)[0]
+        self._indptr, self._indices, self._overlay = [], [], []
+        for (lo, hi), dev in zip(self.ranges, self._devices):
             lp = np.zeros(self.rows_per_shard + 1, dtype=np.int32)
             lp[: hi - lo + 1] = indptr[lo:hi + 1] - indptr[lo]
             lp[hi - lo + 1:] = lp[hi - lo]      # pad rows: degree 0
             li = np.zeros(edge_pad, dtype=np.int32)
             li[: lp[hi - lo]] = indices[indptr[lo]:indptr[hi]]
-            self._indptr.append(jnp.asarray(lp))
-            self._indices.append(jnp.asarray(li))
-        # frozen-graph mesh tier: no tombstones, empty delta overlay —
-        # the overlay op with zero deltas is bitwise the frozen sampler
-        self._tomb = jnp.zeros(edge_pad, dtype=jnp.int32)
-        self._d_indptr = jnp.zeros(self.rows_per_shard + 1,
-                                   dtype=jnp.int32)
-        self._d_indices = jnp.zeros(8, dtype=jnp.int32)
+            self._indptr.append(jax.device_put(lp, dev))
+            self._indices.append(jax.device_put(li, dev))
+            # frozen-graph mesh tier: no tombstones, empty delta overlay
+            # — the overlay op with zero deltas is bitwise the frozen
+            # sampler
+            self._overlay.append(tuple(
+                jax.device_put(np.zeros(n, dtype=np.int32), dev)
+                for n in (edge_pad, self.rows_per_shard + 1, 8)))
         self._sharding = row_shard(self.mesh)
         self._edge_base = np.asarray(
             [int(indptr[lo]) for lo, _ in self.ranges], dtype=np.int32)
@@ -105,7 +109,7 @@ class MeshSampler:
         blocks -> the global sample, as a collective over ``shard``."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         key = ("combine", B, k, self.n_shards)
@@ -157,13 +161,12 @@ class MeshSampler:
                             shard=str(s)).set(float(owned.sum()))
             local = np.clip(seeds - lo, 0, self.rows_per_shard - 1)
             outs.append(sample_neighbors_overlay(
-                self._indptr[s], self._indices[s], self._tomb,
-                self._d_indptr, self._d_indices,
+                self._indptr[s], self._indices[s], *self._overlay[s],
                 jnp.asarray(local, jnp.int32), k, key,
                 seed_mask=jnp.asarray(owned),
                 gather_mode=self.gather_mode,
                 sample_rng=self.sample_rng))
-        stack = [jax.device_put(jnp.stack(xs), self._sharding)
+        stack = [stack_shards(self.mesh, xs)
                  for xs in (tuple(o.nbrs for o in outs),
                             tuple(o.mask for o in outs),
                             tuple(o.counts for o in outs),
@@ -180,7 +183,12 @@ class MeshSampler:
         return dict(n_shards=self.n_shards,
                     rows_per_shard=self.rows_per_shard,
                     node_count=self.node_count,
-                    executables=len(self._jitted))
+                    executables=len(self._jitted),
+                    # where each shard's CSR lives, and how much of it
+                    placement=[dict(device=str(next(iter(ix.devices()))),
+                                    bytes=int(ip.nbytes + ix.nbytes))
+                               for ip, ix in zip(self._indptr,
+                                                 self._indices)])
 
     def __repr__(self):
         return (f"MeshSampler(nodes={self.node_count}, "

@@ -28,8 +28,8 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 __all__ = ["DATA_AXIS", "SHARD_AXIS", "require_devices", "build_mesh",
-           "row_shard", "replicated", "shard_ranges",
-           "match_partition_rules"]
+           "row_shard", "replicated", "shard_devices", "stack_shards",
+           "shard_ranges", "match_partition_rules"]
 
 DATA_AXIS = "data"
 SHARD_AXIS = "shard"
@@ -91,6 +91,33 @@ def replicated(mesh):
     return NamedSharding(mesh, PartitionSpec())
 
 
+def shard_devices(mesh, axis: str = SHARD_AXIS) -> np.ndarray:
+    """``[replicas, n_shards]`` devices: column ``s`` holds shard ``s``
+    (one row per index of the mesh's other axes, which replicate it)."""
+    i = mesh.axis_names.index(axis)
+    devs = np.moveaxis(mesh.devices, i, -1)
+    return devs.reshape(-1, devs.shape[-1])
+
+
+def stack_shards(mesh, parts, axis: str = SHARD_AXIS):
+    """The mesh-wide ``[S, ...]`` array whose slice ``s`` is ``parts[s]``,
+    row-sharded along ``axis``.  ``parts[s]`` already lives on shard
+    ``s``'s device, so nothing crosses devices (a ``jnp.stack`` would
+    first gather every shard onto one of them)."""
+    import jax
+
+    devs = shard_devices(mesh, axis)
+    arrays = []
+    for replica in devs:
+        for part, dev in zip(parts, replica):
+            block = part[None]
+            arrays.append(block if dev in block.devices()
+                          else jax.device_put(block, dev))
+    shape = (len(parts),) + tuple(parts[0].shape)
+    return jax.make_array_from_single_device_arrays(
+        shape, row_shard(mesh, axis), arrays)
+
+
 def shard_ranges(n_rows: int, n_shards: int
                  ) -> Tuple[int, List[Tuple[int, int]]]:
     """Balanced contiguous row ranges: ``rows_per_shard`` (the padded
@@ -109,8 +136,8 @@ def shard_ranges(n_rows: int, n_shards: int
 
 
 def match_partition_rules(rules: Sequence[Tuple[str, object]], tree):
-    """Regex -> ``PartitionSpec`` mapping over a param pytree (the
-    SNIPPETS.md exemplar shape): the first rule whose pattern searches
+    """Regex -> ``PartitionSpec`` mapping over a param pytree: the
+    first rule whose pattern searches
     the ``/``-joined path of a leaf supplies its spec.  An unmatched
     leaf raises — silent replication of a tensor someone meant to
     shard is how HBM budgets get blown."""

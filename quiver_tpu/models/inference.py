@@ -2,8 +2,7 @@
 
 The reference evaluates accuracy with PyG's layer-wise ``inference()``
 over ALL neighbors (no sampling) — e.g. the test pass of
-``examples/pyg/ogbn_products_sage_quiver.py``.  Round 1 only had a
-SAGE-specific version (VERDICT weak #8); this module does the exact
+``examples/pyg/ogbn_products_sage_quiver.py``.  This module does the exact
 per-layer math for :class:`GraphSAGE`, :class:`GCN`, and :class:`GAT`
 param layouts, streaming the CSR edge array in chunks so papers100M-scale
 graphs fit (aggregation is a chunked ``.at[].add`` segment-sum; GAT does

@@ -3,7 +3,7 @@
 The budgeted feature tier (``Feature`` with ``cache_count <
 node_count``) serves every cold row over the host link, every batch —
 even when zipf-skewed traffic re-requests the same rows batch after
-batch (BENCH_r05's budgeted tier is transport-limited).  The overlay
+batch (what that costs on the chip: not measured).  The overlay
 cache is a second device-resident tier *behind* the static degree-
 ordered hot prefix: a fixed-capacity ``[C, dim]`` HBM table holding
 whichever cold rows the traffic keeps touching.

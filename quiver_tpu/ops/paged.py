@@ -56,7 +56,7 @@ __all__ = ["PagedStore", "PageTable", "default_page_rows",
 DEVICE, OVERLAY, HOST = 0, 1, 2
 PAGE_STATES = {"DEVICE": DEVICE, "OVERLAY": OVERLAY, "HOST": HOST}
 
-_TXN_BYTES = 512          # HBM transaction granularity (BENCH_r05)
+_TXN_BYTES = 512          # HBM transaction granularity
 _TARGET_PAGE_BYTES = 4096  # auto-sizing floor: 8 transactions per page
 _VMEM_BUDGET = 2 << 20     # kernel scratch budget for the page window
 

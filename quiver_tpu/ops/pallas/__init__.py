@@ -1,7 +1,8 @@
 """Pallas TPU kernels (hot-path variants of the XLA ops).
 
 * ``gather_kernel`` — DMA row gather over a budgeted feature table.
-* ``element_gather_kernel`` — per-element DMA gather (BENCH_r05 probe).
+* ``element_gather_kernel`` — per-element DMA gather (on-chip time: not
+  measured).
 * ``sample_gather_kernel`` / ``window_sample_kernel`` — fused PRNG +
   per-seed window DMA + lane select for sampling.
 * ``page_gather_kernel`` — ragged whole-page gather for the paged
@@ -9,5 +10,45 @@
   padding, one executable per batch size.
 
 All kernels carry an ``interpret=`` escape hatch so CPU CI executes
-the exact kernel logic under the Pallas interpreter.
+the exact kernel logic under the Pallas interpreter.  Interpret mode
+accepts shapes Mosaic refuses; the two refusals the chip's compiler
+gave at the widths the repo benchmarks (``tests/test_aot_compile.py``)
+are checked here, so that asking for such a kernel on a TPU raises a
+:class:`KernelConstraintError` that names the constraint instead of a
+compiler internal error.
 """
+
+__all__ = ["KernelConstraintError", "check_lane_width",
+           "check_scalar_prefetch"]
+
+LANES = 128
+SMEM_BYTES = 1 << 20    # v5e scalar memory, as its compiler reports it
+
+
+class KernelConstraintError(ValueError):
+    """A Pallas kernel was asked to compile for the TPU (``interpret=
+    False``) at a shape Mosaic refuses."""
+
+
+def check_lane_width(kernel: str, dim: int) -> None:
+    """Row DMAs slice the lane dimension whole: it must be a multiple of
+    128 (Mosaic: "Slice shape along dimension N must be aligned to
+    tiling (128)")."""
+    if dim % LANES:
+        raise KernelConstraintError(
+            f"{kernel}: row width {dim} is not a multiple of {LANES} "
+            f"lanes — Mosaic refuses the row DMA on a TPU (pad the "
+            f"table's lane dimension to {-(-dim // LANES) * LANES}, or "
+            f"use the XLA gather)")
+
+
+def check_scalar_prefetch(kernel: str, nbytes: int) -> None:
+    """Scalar-prefetched operands live in SMEM whole, for the entire
+    grid (XLA: "would exceed memory ... space=smem ... prefetched SMEM
+    operand")."""
+    if nbytes > SMEM_BYTES:
+        raise KernelConstraintError(
+            f"{kernel}: {nbytes} bytes of scalar-prefetched indices "
+            f"exceed the {SMEM_BYTES}-byte SMEM of a v5e — the index "
+            f"vectors are prefetched whole, not per block (split the "
+            f"batch, or use the XLA gather)")

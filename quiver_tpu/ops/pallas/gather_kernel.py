@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import check_lane_width, check_scalar_prefetch
+
 __all__ = ["gather_rows"]
 
 NBUF = 4  # outstanding DMAs per program
@@ -67,6 +69,9 @@ def gather_rows(table: jax.Array, idx: jax.Array, block: int = 256,
     m = idx.shape[0]
     assert m % block == 0, (m, block)
     d = table.shape[1]
+    if not interpret:
+        check_lane_width("gather_rows", d)
+        check_scalar_prefetch("gather_rows", 4 * m)
     grid = (m // block,)
     return pl.pallas_call(
         _kernel,

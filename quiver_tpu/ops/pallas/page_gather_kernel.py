@@ -4,10 +4,13 @@ The data-layer application of the Ragged Paged Attention design
 (PAPERS.md, arxiv 2604.15464): feature rows live in fixed-size HBM
 pages (``page_rows`` x row-bytes, sized to a multiple of the 512B HBM
 transaction), and one kernel gathers a variable-length frontier by
-walking ``(page, offset)`` pairs — whole-page DMAs instead of the
-per-element transfers that leave both XLA's element gather and the
-per-row-DMA kernel transaction-bound (BENCH_r05 ``micro_gather``:
-~26 ms/1M elems for either).
+walking ``(page, offset)`` pairs — whole-page DMAs instead of
+per-element transfers (on-chip time of either: not measured).
+
+On a TPU the kernel compiles only for row widths that are a multiple of
+128 lanes and plans whose scalar-prefetched vectors fit SMEM; anything
+else raises :class:`~quiver_tpu.ops.pallas.KernelConstraintError`
+(``tests/test_aot_compile.py`` keeps the compiler's own refusals).
 
 Contract with the host-side planner (``ops/paged.py``):
 
@@ -37,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import check_lane_width, check_scalar_prefetch
 
 __all__ = ["page_gather", "NBUF"]
 
@@ -115,6 +120,10 @@ def page_gather(frames: jax.Array, blk_pages: jax.Array,
     assert m % block == 0, (m, block)
     d = frames.shape[2]
     nb = m // block
+    if not interpret:
+        check_lane_width("page_gather", d)
+        check_scalar_prefetch("page_gather",
+                              4 * (2 * m + nb * ppb + nb))
     return pl.pallas_call(
         functools.partial(_kernel, page_rows=page_rows, ppb=ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(

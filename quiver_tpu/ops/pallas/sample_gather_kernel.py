@@ -95,7 +95,7 @@ def pallas_element_gather(table2d: jax.Array, idx: jax.Array,
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((GPB, GROUP), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((GPB, GROUP), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),

@@ -23,8 +23,8 @@ stages.  The draws never leave VMEM until the final ``[B, k]`` payload:
 
 Traffic per seed: ``U*512`` bytes in, 512 bytes out — vs the ``lanes``
 mode's ``k*512`` in + ``k*512 * 2`` intermediate, and one DMA issue per
-SEED instead of per DRAW (the per-element kernel's measured 26 ns/issue
-bound, docs/TPU_MEASUREMENTS.md, divided by k).
+SEED instead of per DRAW (on-chip time against the other modes: not
+measured).
 
 Seeds whose window spans more than ``U`` rows are recomputed outside by
 the compacted classic fallback (same policy/structure as

@@ -146,8 +146,8 @@ def _gather(table: jax.Array, idx: jax.Array, mode: str) -> jax.Array:
     'blocked*'/'pwindow*' apply only to the per-seed WINDOW gathers inside
     the samplers (``ops.blockgather`` / the fused Pallas window kernel);
     scattered [B] element gathers (the indptr reads) ride the lanes path
-    under them — per-element DMA of indptr rows is the measured-losing
-    pattern (docs/TPU_MEASUREMENTS.md: 26 ms/1M, issue-latency bound)."""
+    under them (per-element DMA of indptr rows against the lanes path
+    on the chip: not measured)."""
     if mode.startswith("blocked") or mode.startswith("pwindow"):
         mode = "lanes"
     if mode in ("lanes", "lanes_fused"):

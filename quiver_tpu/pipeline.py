@@ -13,14 +13,13 @@ fall back to the two-stage loop (``SeedLoader``).
 
 from __future__ import annotations
 
-import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import optax
 
-from .feature import Feature
+from .feature import Feature, _lookup_tables
 from .sampler import GraphSageSampler, run_pipeline
 from .parallel.train import TrainState
 
@@ -41,8 +40,30 @@ def make_fused_train_step(sampler: GraphSageSampler, feature: Feature,
                           loss_fn: Optional[Callable] = None):
     """Build ``(state, seeds, labels, label_mask, key) -> (state, loss)``
     with sampling and feature gather inside the jit."""
-    _check(feature)
+    impl = _fused_train_impl(sampler, feature, apply_fn, loss_fn)
+    tables = _tables(sampler, feature)
+    jitted = jax.jit(impl, donate_argnums=(1,))
+
+    def step(state: TrainState, seeds, labels, label_mask, key):
+        return jitted(tables, state, seeds, labels, label_mask, key)
+
+    return step
+
+
+def _tables(sampler: GraphSageSampler, feature: Feature):
+    """The device tables a fused program reads — ``(indptr, indices,
+    (hot, order))`` — handed to it as ARGUMENTS: a device array captured
+    by a jitted closure is baked into the executable as a constant, a
+    second copy of graph and features in HBM for every program."""
     indptr, indices = sampler.csr_topo.to_device(sampler.device)
+    return indptr, indices, feature._device_tables()
+
+
+def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
+                      apply_fn: Callable, loss_fn: Optional[Callable]):
+    """Un-jitted ``(tables, state, seeds, labels, label_mask, key) ->
+    (state, loss)`` shared by the fused step and the scan epoch."""
+    _check(feature)
     sizes = tuple(sampler.sizes)
     gm, srng = sampler.gather_mode, sampler.sample_rng
     dedup = sampler.dedup
@@ -56,14 +77,14 @@ def make_fused_train_step(sampler: GraphSageSampler, feature: Feature,
             m = mask.astype(ls.dtype)
             return (ls * m).sum() / jnp.maximum(m.sum(), 1.0)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def step(state: TrainState, seeds, labels, label_mask, key):
+    def step(tables, state: TrainState, seeds, labels, label_mask, key):
+        indptr, indices, feat_tables = tables
         ks, kd = jax.random.split(key)
         n_id, n_mask, num, blocks, _ = run_pipeline(
             dedup, indptr, indices, seeds, ks, sizes, caps, gather_mode=gm,
             sample_rng=srng
         )
-        x = feature.lookup_device(n_id)
+        x = _lookup_tables(feat_tables, n_id)
 
         def compute(params):
             logits = apply_fn(params, x, blocks, train=True,
@@ -89,25 +110,24 @@ def make_scan_epoch(sampler: GraphSageSampler, feature: Feature,
     Compile cost is paid once per (S, B) shape; use for steady production
     epochs, the plain fused step for interactive work.
     """
-    _check(feature)
-    step = make_fused_train_step(sampler, feature, apply_fn, tx, loss_fn)
-    # reuse the already-jitted step inside scan: re-expressing it as a
-    # traced body lets XLA pipeline across steps
-    indptr, indices = sampler.csr_topo.to_device(sampler.device)
+    step = _fused_train_impl(sampler, feature, apply_fn, loss_fn)
+    tables = _tables(sampler, feature)
 
     @jax.jit
-    def epoch(state: TrainState, seeds, labels, key):
+    def scan(tables, state: TrainState, seeds, labels, key):
         S, B = seeds.shape
         ones = jnp.ones((B,), bool)
 
         def body(state, xs):
             s, l, k = xs
-            state, loss = step(state, s, l, ones, k)
-            return state, loss
+            return step(tables, state, s, l, ones, k)
 
         keys = jax.random.split(key, S)
         state, losses = jax.lax.scan(body, state, (seeds, labels, keys))
         return state, losses
+
+    def epoch(state: TrainState, seeds, labels, key):
+        return scan(tables, state, seeds, labels, key)
 
     return epoch
 
@@ -116,7 +136,7 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
                        apply_fn: Callable):
     """``(params, seeds, key) -> logits`` with sampling inside the jit."""
     _check(feature)
-    indptr, indices = sampler.csr_topo.to_device(sampler.device)
+    tables = _tables(sampler, feature)
     sizes = tuple(sampler.sizes)
     gm, srng = sampler.gather_mode, sampler.sample_rng
 
@@ -124,12 +144,16 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
     caps = tuple(sampler.frontier_caps)
 
     @jax.jit
-    def eval_fn(params, seeds, key):
+    def jitted(tables, params, seeds, key):
+        indptr, indices, feat_tables = tables
         n_id, n_mask, num, blocks, _ = run_pipeline(
             dedup, indptr, indices, seeds, key, sizes, caps, gather_mode=gm,
             sample_rng=srng
         )
-        x = feature.lookup_device(n_id)
+        x = _lookup_tables(feat_tables, n_id)
         return apply_fn(params, x, blocks, train=False, rngs=None)
+
+    def eval_fn(params, seeds, key):
+        return jitted(tables, params, seeds, key)
 
     return eval_fn

@@ -20,7 +20,7 @@ the scattered dicts could not:
     compiles something cold is a bug this turns into a failure;
   * **persistent compilation** — ``enable_persistent_cache`` points
     JAX's compilation cache at a directory, so the *backend compile*
-    (the 5.2–37.6 s/program cost BENCH_r05 measured) is paid once per
+    (seconds per program on the chip: not measured) is paid once per
     fleet, not once per process.  ``persistent_cache_hits`` counts the
     disk hits via JAX's monitoring events; the warm-restart bench and
     crash-harness acceptance both key off it.
@@ -218,25 +218,32 @@ class ProgramRegistry:
     # -- persistent compilation cache ---------------------------------
     def enable_persistent_cache(self, cache_dir: str) -> bool:
         """Point JAX's compilation cache at ``cache_dir`` (created if
-        missing) and start counting disk hits.  Returns False — with
-        the reason logged — when this JAX build refuses, so boot
-        proceeds merely cold, not dead."""
+        missing) and start counting disk hits.  When the process was
+        started with ``JAX_COMPILATION_CACHE_DIR`` the cache stays where
+        that variable put it and ``cache_dir`` is not used
+        (``utils/compile_cache.py``).  Returns False — with the reason
+        logged — when this JAX build refuses, so boot proceeds merely
+        cold, not dead."""
         import logging
         import os
 
+        from ..utils import compile_cache
+
         log = logging.getLogger("quiver_tpu.recovery")
+        placed = os.environ.get(compile_cache.ENV)
         try:
-            os.makedirs(cache_dir, exist_ok=True)
             import jax
 
-            jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+            if placed:
+                cache_dir = placed
+            else:
+                os.makedirs(cache_dir, exist_ok=True)
+                jax.config.update("jax_compilation_cache_dir",
+                                  str(cache_dir))
             jax.config.update("jax_persistent_cache_min_compile_time_secs",
                               0.0)
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", -1)
-            except Exception:  # older jax: flag absent, threshold default
-                pass
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes", -1)
             self._install_hit_listener()
         except Exception as e:
             log.warning("persistent compilation cache unavailable: %s", e)

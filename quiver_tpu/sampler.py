@@ -22,6 +22,7 @@ sit far below the no-dedup bound, so a cap ~2x the typical frontier loses
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
@@ -487,12 +488,15 @@ class GraphSageSampler:
         srng = self.sample_rng
 
         @jax.jit
-        def fn(seeds, key):
+        def fn(indptr, indices, cw, seeds, key):
             return run_pipeline(dedup, indptr, indices, seeds, key, sizes,
                                 caps, gather_mode=gm, cum_weights=cw,
                                 return_eid=ret_eid, sample_rng=srng)
 
-        return fn
+        # the tables ride as ARGUMENTS: a device array captured by the
+        # closure is baked into the executable as a constant — a second
+        # copy of the graph in HBM per compiled batch size
+        return functools.partial(fn, indptr, indices, cw)
 
     def _build_stream_jit(self, batch_size: int, windowed: bool):
         """Compile the overlay pipeline for one (batch, snapshot-shape)

@@ -565,6 +565,7 @@ class InferenceServer:
         import jax
         import jax.numpy as jnp
 
+        from .feature import _lookup_tables
         from .sampler import run_pipeline
         from .utils.rng import make_key
 
@@ -572,20 +573,19 @@ class InferenceServer:
         fn = self._fused_fns.get(B)
         if fn is None:
             s = self.sampler
-            indptr, indices = s.csr_topo.to_device(s.device)
             sizes = tuple(s.sizes)
             caps = tuple(s.frontier_caps)
             dedup, gm = s.dedup, s.gather_mode
             srng = s.sample_rng
-            cw = s._cum_weights  # weighted samplers stay weighted here
-            feature, apply_fn = self.feature, self.apply_fn
+            apply_fn = self.apply_fn
 
             @jax.jit
-            def fn(params, seeds, key):
+            def fn(tables, params, seeds, key):
+                indptr, indices, cw, feat_tables = tables
                 n_id, _, _, blocks, _ = run_pipeline(
                     dedup, indptr, indices, seeds, key, sizes, caps,
                     gather_mode=gm, cum_weights=cw, sample_rng=srng)
-                x = feature.lookup_device(n_id)
+                x = _lookup_tables(feat_tables, n_id)
                 return apply_fn(params, x, blocks)
 
             # double-checked: the unlocked .get() above is the fast path;
@@ -593,8 +593,20 @@ class InferenceServer:
             # keeps exactly one (compile is lazy, losing a build is cheap)
             with self._lock:
                 fn = self._fused_fns.setdefault(B, fn)
-        return fn(self.params, jnp.asarray(padded_ids, jnp.int32),
+        return fn(self._fused_tables(), self.params,
+                  jnp.asarray(padded_ids, jnp.int32),
                   make_key(np.random.randint(0, 2**31 - 1)))
+
+    def _fused_tables(self):
+        """Graph, weights (weighted samplers stay weighted here) and
+        feature tables, handed to every bucket's program as ARGUMENTS:
+        captured by the jitted closure they would be baked into EVERY
+        bucket's executable as constants — |BUCKETS| more copies of them
+        in HBM."""
+        s = self.sampler
+        indptr, indices = s.csr_topo.to_device(s.device)
+        return (indptr, indices, s._cum_weights,
+                self.feature._device_tables())
 
     def warmup(self, example_node: int = 0):
         """Compile every bucket's executable before traffic arrives.

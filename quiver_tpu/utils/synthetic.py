@@ -38,8 +38,8 @@ def synthetic_products(seed: int = 0) -> CSRTopo:
 
 
 def synthetic_reddit(seed: int = 0) -> CSRTopo:
-    """Reddit scale: 233K nodes, ~11.6M edges."""
-    indptr, indices = synthetic_csr(232_965, 11_606_919, seed)
+    """Reddit scale: 233K nodes, ~114.6M edges (mean degree ~490)."""
+    indptr, indices = synthetic_csr(232_965, 114_615_892, seed)
     return CSRTopo(indptr=indptr, indices=indices)
 
 

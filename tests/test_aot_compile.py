@@ -1,0 +1,321 @@
+"""Real compiles for a described TPU v5e, asked of the chip's own compiler.
+
+Interpret-mode tests prove a kernel's semantics and skip Mosaic; an
+export to MLIR stops before it too.  These cases run the whole TPU
+compiler (XLA + Mosaic) against a ``v5e:2x2`` topology that is described,
+not attached, at the widths the repo benchmarks: ogbn-products (D=100,
+B=1024, fanout [15,10,5], 124M-entry ``indices``) and Reddit (D=602,
+[25,10]).  Nothing runs, so they say nothing about results or times —
+only that the chip's compiler accepts the program and, where a kernel was
+asked for, that the kernel is in it.
+
+Keep every such case in THIS file: the worker that describes the topology
+holds libtpu until it exits, so a second file on another worker would skip.
+The topology is described inside a fixture, never at import.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+PRODUCTS_NODES, PRODUCTS_EDGES = 2_449_029, 123_718_280
+PRODUCTS_DIM, PRODUCTS_CLASSES = 100, 47
+FANOUT, BATCH = (15, 10, 5), 1024
+REDDIT_NODES, REDDIT_EDGES = 232_965, 114_615_892
+REDDIT_DIM, REDDIT_CLASSES, REDDIT_FANOUT = 602, 41, (25, 10)
+
+# frontier a hop samples FROM and its fanout, dedup="none" (the frontier
+# grows by (1 + k) per hop): B=1024 [15,10,5]
+HOPS = [(1024, 15), (16_384, 10), (180_224, 5)]
+WIDTHS = [100, 128, 602]
+
+
+def _pad128(n):
+    return -(-n // 128) * 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A single-device sharding on the described chip, with the
+    persistent compile cache off while this file runs: a described
+    compile is written to the cache but cannot be read back without a
+    chip, so the next run would warn and compile again anyway."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    """Shapes only (a described device holds no array), each on the chip."""
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _s(sharding, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(f, *args, **kw):
+    return jax.jit(f, **kw).lower(*args).compile()
+
+
+def _graph(sharding, nodes, edges):
+    # CSRTopo.to_device pads both tables to a multiple of 128
+    return (_s(sharding, (_pad128(nodes + 1),)),
+            _s(sharding, (_pad128(edges),)))
+
+
+def _key(sharding):
+    return _on(sharding, jax.eval_shape(lambda: jax.random.key(0)))
+
+
+# --------------------------------------------------------------- sampling
+@pytest.mark.parametrize("B,k", HOPS)
+def test_default_tpu_hop_compiles(one_chip, B, k):
+    """What ``config.resolve_*`` pick on a TPU: lanes gather + hash RNG."""
+    from quiver_tpu.ops.sample import sample_neighbors
+
+    indptr, indices = _graph(one_chip, PRODUCTS_NODES, PRODUCTS_EDGES)
+    c = _compile(
+        lambda ip, ix, s, kk, m: sample_neighbors(
+            ip, ix, s, k, kk, seed_mask=m, gather_mode="lanes",
+            sample_rng="hash"),
+        indptr, indices, _s(one_chip, (B,)), _key(one_chip),
+        _s(one_chip, (B,), jnp.bool_))
+    assert "tpu_custom_call" not in c.as_text()   # pure XLA by design
+
+
+@pytest.mark.parametrize("B,k,U", [(1024, 15, 3), (300, 5, 2), (64, 8, 1),
+                                   (16_384, 10, 3), (180_224, 5, 3)])
+def test_window_sample_kernel_compiles(one_chip, B, k, U):
+    from quiver_tpu.ops.pallas.window_sample_kernel import (
+        pallas_window_sample)
+
+    table = _s(one_chip, (_pad128(PRODUCTS_EDGES) // 128, 128))
+    c = _compile(
+        lambda t, s, d, kk: pallas_window_sample(t, s, d, kk, k, U=U),
+        table, _s(one_chip, (B,)), _s(one_chip, (B,)), _key(one_chip))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("m", [4096, 1_081_344])
+def test_element_gather_kernel_compiles(one_chip, m):
+    from quiver_tpu.ops.pallas.sample_gather_kernel import (
+        pallas_element_gather)
+
+    table = _s(one_chip, (_pad128(PRODUCTS_EDGES) // 128, 128))
+    c = _compile(pallas_element_gather, table, _s(one_chip, (m,)))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("m", [2048, 1_081_344])
+def test_lane_select_kernel_compiles(one_chip, m):
+    from quiver_tpu.ops.pallas.element_gather_kernel import BLK, lane_select
+
+    m = -(-m // BLK) * BLK
+    c = _compile(lane_select, _s(one_chip, (m, 128)), _s(one_chip, (m,)))
+    assert "tpu_custom_call" in c.as_text()
+
+
+# ---------------------------------------------------------------- features
+# The two feature-row kernels compile only inside a narrow envelope.  The
+# library refuses the rest by name on a TPU (``KernelConstraintError``,
+# ops/pallas/__init__.py); the strict xfails below switch that check off
+# and keep the chip compiler's own words on record — when a repair makes
+# one of them compile, its XPASS fails the suite and the check can go.
+_LANES = ("Mosaic failed to compile TPU kernel: Slice shape along "
+          "dimension N must be aligned to tiling (128), but is 100 / 602")
+_SUBLANE = ("Mosaic failed to compile TPU kernel: Slice shape along "
+            "dimension 0 must be aligned to tiling (8), but is 1")
+_SMEM = ("RESOURCE_EXHAUSTED: Allocation (size=4325376) would exceed "
+         "memory (size=1048576) ... space=smem ... prefetched SMEM "
+         "operand 0")
+
+
+@pytest.fixture
+def unchecked(monkeypatch):
+    """Let a refused shape through to the compiler."""
+    from quiver_tpu.ops.pallas import gather_kernel, page_gather_kernel
+
+    for mod in (gather_kernel, page_gather_kernel):
+        monkeypatch.setattr(mod, "check_lane_width", lambda *a: None)
+        monkeypatch.setattr(mod, "check_scalar_prefetch", lambda *a: None)
+
+
+def _row_gather(one_chip, d, m):
+    from quiver_tpu.ops.pallas.gather_kernel import gather_rows
+
+    return _compile(gather_rows,
+                    _s(one_chip, (PRODUCTS_NODES, d), jnp.float32),
+                    _s(one_chip, (m,)))
+
+
+def _page_gather(one_chip, d, m):
+    from quiver_tpu.ops.paged import _plan_geometry, default_page_rows
+    from quiver_tpu.ops.pallas.page_gather_kernel import page_gather
+
+    rows = default_page_rows(d * 4)
+    block, ppb = _plan_geometry(rows, d, 4)
+    m = -(-m // block) * block
+    nb = m // block
+    return _compile(
+        functools.partial(page_gather, page_rows=rows, block=block,
+                          ppb=ppb),
+        _s(one_chip, (PRODUCTS_NODES // rows + 1, rows, d), jnp.float32),
+        _s(one_chip, (nb * ppb,)), _s(one_chip, (nb,)),
+        _s(one_chip, (m,)), _s(one_chip, (m,)))
+
+
+@pytest.mark.parametrize("kernel", [_row_gather, _page_gather])
+def test_feature_row_kernels_compile_at_128_lanes(one_chip, kernel):
+    """D=128 with an index plan that fits SMEM: the envelope."""
+    assert "tpu_custom_call" in kernel(one_chip, 128, 65_536).as_text()
+
+
+@pytest.mark.parametrize("kernel,d,m", [
+    pytest.param(_row_gather, 100, 65_536,
+                 marks=pytest.mark.xfail(strict=True, reason=_LANES)),
+    pytest.param(_row_gather, 602, 65_536,
+                 marks=pytest.mark.xfail(strict=True, reason=_SUBLANE)),
+    pytest.param(_row_gather, 128, 1_081_344,
+                 marks=pytest.mark.xfail(strict=True, reason=_SMEM)),
+    pytest.param(_page_gather, 100, 65_536,
+                 marks=pytest.mark.xfail(strict=True, reason=_LANES)),
+    pytest.param(_page_gather, 602, 65_536,
+                 marks=pytest.mark.xfail(strict=True, reason=_LANES)),
+    pytest.param(_page_gather, 128, 1_081_344,
+                 marks=pytest.mark.xfail(strict=True, reason=_SMEM)),
+])
+def test_feature_row_kernels_refused_by_mosaic(one_chip, unchecked, kernel,
+                                               d, m):
+    """Products (D=100) and Reddit (D=602) widths, and a products-sized
+    frontier (1.08M rows) at any width."""
+    kernel(one_chip, d, m)
+
+
+@pytest.mark.parametrize("kernel,d,m", [
+    (_row_gather, 100, 32_768), (_row_gather, 602, 32_768),
+    (_row_gather, 128, 2_162_688), (_page_gather, 100, 32_768),
+    (_page_gather, 602, 32_768), (_page_gather, 128, 2_162_688)])
+def test_feature_row_kernels_refuse_by_name(one_chip, kernel, d, m):
+    # sizes differ from the xfails above: those traced the same jitted
+    # wrappers with the check off, and jit caches a trace by shape
+    from quiver_tpu.ops.pallas import KernelConstraintError
+
+    with pytest.raises(KernelConstraintError):
+        kernel(one_chip, d, m)
+
+
+@pytest.mark.parametrize("nodes,d,m", [
+    (PRODUCTS_NODES, PRODUCTS_DIM, 1_081_344),
+    (REDDIT_NODES, REDDIT_DIM, 128 * 26 * 11)])
+def test_feature_hot_gather_compiles(one_chip, nodes, d, m):
+    """``Feature.lookup_device`` on a fully HBM-resident table."""
+    from quiver_tpu.feature import _lookup_tables
+
+    _compile(_lookup_tables,
+             (_s(one_chip, (nodes, d), jnp.float32), _s(one_chip, (nodes,))),
+             _s(one_chip, (m,)))
+
+
+# ------------------------------------------------------------ whole steps
+def _sage(hidden, classes, layers):
+    from quiver_tpu.models import GraphSAGE
+
+    model = GraphSAGE(hidden=hidden, out_dim=classes, num_layers=layers)
+
+    def apply_fn(p, x, blocks, train=False, rngs=None):
+        return model.apply(p, x, blocks, train=train, rngs=rngs)
+
+    return model, apply_fn
+
+
+def _sampled_shapes(nodes, edges, dim, B, sizes):
+    """Abstract ``(x, blocks)`` of one sampled batch, dedup='none'."""
+    from quiver_tpu.sampler import run_pipeline
+
+    indptr = jax.ShapeDtypeStruct((_pad128(nodes + 1),), jnp.int32)
+    indices = jax.ShapeDtypeStruct((_pad128(edges),), jnp.int32)
+    n_id, _, _, blocks, _ = jax.eval_shape(
+        lambda ip, ix, s, k: run_pipeline(
+            "none", ip, ix, s, k, tuple(sizes), (None,) * len(sizes),
+            gather_mode="lanes", sample_rng="hash"),
+        indptr, indices, jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.random.key(0))
+    return jax.ShapeDtypeStruct((n_id.shape[0], dim), jnp.float32), blocks
+
+
+def _train_state(model, x, blocks):
+    import optax
+
+    from quiver_tpu.parallel import TrainState
+
+    tx = optax.adam(3e-3)
+    params = jax.eval_shape(model.init, jax.random.key(1), x, blocks)
+    return tx, jax.eval_shape(lambda p: TrainState.create(p, tx), params)
+
+
+def test_sage_train_step_compiles(one_chip):
+    """``parallel.make_train_step``: 3-layer hidden-256 SAGE forward +
+    backward + adam on one sampled products batch."""
+    from quiver_tpu.parallel import make_train_step
+
+    model, apply_fn = _sage(256, PRODUCTS_CLASSES, 3)
+    x, blocks = _sampled_shapes(PRODUCTS_NODES, PRODUCTS_EDGES,
+                                PRODUCTS_DIM, BATCH, FANOUT)
+    tx, state = _train_state(model, x, blocks)
+    step = make_train_step(apply_fn, tx)
+    args = _on(one_chip, (state, x, blocks,
+                          jax.ShapeDtypeStruct((BATCH,), jnp.int32),
+                          jax.ShapeDtypeStruct((BATCH,), jnp.bool_)))
+    c = step.lower(*args, _key(one_chip)).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 12 << 30
+
+
+@pytest.mark.parametrize("bucket", [8, 128, 2048])
+def test_serving_bucket_forward_compiles(one_chip, bucket):
+    """One ``InferenceServer`` bucket at Reddit widths: sample [25,10] +
+    602-d gather + 2-layer SAGE forward in one program."""
+    from quiver_tpu.feature import _lookup_tables
+    from quiver_tpu.sampler import run_pipeline
+
+    model, apply_fn = _sage(128, REDDIT_CLASSES, 2)
+    x, blocks = _sampled_shapes(REDDIT_NODES, REDDIT_EDGES, REDDIT_DIM,
+                                bucket, REDDIT_FANOUT)
+    params = jax.eval_shape(model.init, jax.random.key(1), x, blocks)
+
+    def forward(tables, params, seeds, key):
+        indptr, indices, feat_tables = tables
+        n_id, _, _, blocks, _ = run_pipeline(
+            "none", indptr, indices, seeds, key, REDDIT_FANOUT,
+            (None, None), gather_mode="lanes", sample_rng="hash")
+        return apply_fn(params, _lookup_tables(feat_tables, n_id), blocks)
+
+    tables = (*_graph(one_chip, REDDIT_NODES, REDDIT_EDGES),
+              (_s(one_chip, (REDDIT_NODES, REDDIT_DIM), jnp.float32), None))
+    c = _compile(forward, tables, _on(one_chip, params),
+                 _s(one_chip, (bucket,)), _key(one_chip))
+    # graph + features (1 GB) are arguments, not constants in the program
+    m = c.memory_analysis()
+    assert m.argument_size_in_bytes > 1_000_000_000
+    assert m.generated_code_size_in_bytes < 64 << 20

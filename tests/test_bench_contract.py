@@ -1,111 +1,138 @@
-"""Driver/harvester contract of bench.py's emission + resume machinery.
+"""Contract of bench.py's emission and section machinery.
 
-The harvest gate keys on (device, backend, headline_source); the round-3
-failure mode was replayed or CPU-measured evidence passing for fresh TPU
-data.  These tests pin the honesty guards without any device.
+The run prints one JSON line holding only what THIS run measured on the
+device it names; a section that fails or times out makes the run fail;
+a backend without a TPU is refused unless a CPU rehearsal was asked for
+by name.  These tests pin that without any device.
 """
 
 import json
+import os
+import subprocess
+import sys
+import time
 
 import pytest
 
 import bench
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+CPU = {"platform": "cpu", "kind": "cpu", "count": 8}
 
-def _emit(capsys, sections, device_live, backend=None, note=None):
-    bench._emit_result(sections, device_live, note=note, backend=backend)
+
+def _emit(capsys, sections, failed, device):
+    bench._emit_result(sections, failed, device)
     return json.loads(capsys.readouterr().out.strip())
 
 
 class TestEmitResult:
-    def test_live_accelerator_headline(self, capsys):
-        out = _emit(capsys, {"sampling": {"seps": 3.429e7}}, True, "tpu")
-        assert out["device"] is True and out["backend"] == "tpu"
-        assert out["headline_source"] == "live"
-        assert out["vs_baseline"] == 1.0
+    def test_tpu_headline_is_scored_and_names_its_device(self, capsys):
+        out = _emit(capsys, {"sampling": {"seps": 3.429e7}}, {}, TPU)
+        assert out["ok"] is True and out["device"] == TPU
+        assert out["vs_baseline"] == 1.0 and out["failed"] == {}
 
-    def test_cpu_live_measurement_is_labeled_live_but_unscored(self, capsys):
-        out = _emit(capsys, {"sampling": {"seps": 1e7}}, False, "cpu")
-        assert out["headline_source"] == "live"  # THIS run measured it
-        assert out["device"] is False
-        assert out["vs_baseline"] is None  # but never scored vs the GPU
+    def test_cpu_rehearsal_is_never_scored(self, capsys):
+        out = _emit(capsys, {"sampling": {"seps": 1e7}}, {}, CPU)
+        assert out["device"]["platform"] == "cpu"
+        assert out["vs_baseline"] is None  # never scored vs the GPU
 
-    def test_replayed_sections_never_scored(self, capsys):
-        sections = {"sampling": {"seps": 5e7,
-                                 "source": "committed_measurement"}}
-        out = _emit(capsys, sections, True, "tpu")
-        assert out["headline_source"] == "prior"
-        assert out["vs_baseline"] is None
-        # the per-section provenance tag survives
-        assert out["sections"]["sampling"]["source"] == (
-            "committed_measurement")
-
-    def test_watchdog_emission_parses_and_is_unscored(self, capsys):
-        out = _emit(capsys, {}, False, note="no TPU")
+    def test_missing_headline_is_zero_and_unscored(self, capsys):
+        out = _emit(capsys, {}, {}, TPU)
         assert out["vs_baseline"] is None and out["value"] == 0.0
 
-
-class TestFallbackOverlay:
-    def test_small_and_forced_mode_fingerprints_excluded(self, monkeypatch):
-        states = {
-            "tpu|small=False|iters=20": {
-                "sections": {"sampling": {"seps": 1.0}}},
-            "tpu|small=True|iters=3": {
-                "sections": {"sampling": {"seps": 999.0}}},
-            "tpu|small=False|iters=20|gm=pallas": {
-                "sections": {"sampling": {"seps": 888.0}}},
-            "cpu|small=False|iters=20": {
-                "sections": {"sampling": {"seps": 777.0}}},
-        }
-        monkeypatch.setattr(bench, "_load_all_states", lambda: states)
-        monkeypatch.setattr(bench.os.path, "exists", lambda p: False)
-        sections = bench._fallback_sections()
-        # only the probed-mode, full-scale TPU fingerprint contributes
-        assert sections["sampling"]["seps"] == 1.0
-        assert sections["sampling"]["source"].startswith("cached:tpu")
+    def test_failed_section_makes_the_result_not_ok(self, capsys):
+        out = _emit(capsys, {"feature": {"hot_gbs": 1.0}},
+                    {"e2e": "RuntimeError: boom"}, TPU)
+        assert out["ok"] is False
+        assert out["failed"] == {"e2e": "RuntimeError: boom"}
 
 
-class TestSectionRunnerPersistence:
-    def test_save_and_resume_roundtrip(self, tmp_path, monkeypatch):
-        monkeypatch.setattr(bench, "STATE_PATH",
-                            str(tmp_path / "state.json"))
-        r = bench._SectionRunner("tpu|small=False|iters=20")
-        out = r.run("sampling_B1024", 30, lambda: {"seps": 42.0})
-        assert out == {"seps": 42.0}
-        # a second runner under the same fingerprint reuses the result
-        r2 = bench._SectionRunner("tpu|small=False|iters=20")
+class TestSectionRunner:
+    @pytest.fixture(autouse=True)
+    def _empty_registry(self):
+        # the runner attaches the registry's delta to a section, gauges
+        # included, and the registry is process-global: start from empty,
+        # whatever an earlier file in this worker left in it
+        from quiver_tpu import telemetry
+
+        telemetry.reset()
+
+    def test_result_is_kept_and_returned(self):
+        r = bench._SectionRunner()
+        assert r.run("sampling_B1024", 30, lambda: {"seps": 42.0}) == {
+            "seps": 42.0}
+        assert r.sections == {"sampling_B1024": {"seps": 42.0}}
+        assert r.failed == {}
+
+    def test_no_state_survives_the_runner(self, tmp_path, monkeypatch):
+        """Nothing is replayed: a second runner measures again."""
+        monkeypatch.chdir(tmp_path)
+        bench._SectionRunner().run("feature", 30, lambda: {"hot_gbs": 1.0})
         calls = []
-        out2 = r2.run("sampling_B1024", 30,
-                      lambda: calls.append(1) or {"seps": -1})
-        assert out2 == {"seps": 42.0} and not calls
+        out = bench._SectionRunner().run(
+            "feature", 30, lambda: calls.append(1) or {"hot_gbs": 2.0})
+        assert out == {"hot_gbs": 2.0} and calls == [1]
+        assert list(tmp_path.iterdir()) == []
 
-    def test_concurrent_fingerprints_do_not_clobber(self, tmp_path,
-                                                    monkeypatch):
-        monkeypatch.setattr(bench, "STATE_PATH",
-                            str(tmp_path / "state.json"))
-        a = bench._SectionRunner("tpu|small=False|iters=20")
-        b = bench._SectionRunner("cpu|small=True|iters=3")
-        a.run("feature", 30, lambda: {"hot_gbs": 1.0})
-        b.run("feature", 30, lambda: {"hot_gbs": 2.0})
-        states = bench._load_all_states()
-        assert states["tpu|small=False|iters=20"]["sections"][
-            "feature"]["hot_gbs"] == 1.0
-        assert states["cpu|small=True|iters=3"]["sections"][
-            "feature"]["hot_gbs"] == 2.0
-
-    def test_soft_failure_does_not_burn_attempts(self, tmp_path,
-                                                 monkeypatch):
-        monkeypatch.setattr(bench, "STATE_PATH",
-                            str(tmp_path / "state.json"))
-        r = bench._SectionRunner("tpu|small=False|iters=20")
+    def test_failed_section_is_recorded_and_the_rest_still_run(self):
+        r = bench._SectionRunner()
 
         def boom():
-            raise RuntimeError("transient")
+            raise RuntimeError("device lost")
 
         assert r.run("e2e", 30, boom) is None
-        assert r.state["attempts"]["e2e"] == 0  # rolled back
-        # and the section still runs on retry
-        assert r.run("e2e", 30, lambda: {"ok": 1}) == {"ok": 1}
+        assert r.failed == {"e2e": "RuntimeError: device lost"}
+        assert "e2e" not in r.sections
+        assert r.run("serving", 30, lambda: {"ok": 1}) == {"ok": 1}
+
+    def test_timed_out_section_fails(self):
+        r = bench._SectionRunner()
+        assert r.run("slow", 1, lambda: time.sleep(5)) is None
+        assert r.failed["slow"].startswith("_SectionTimeout")
+
+    def test_bounded_does_not_swallow(self):
+        with pytest.raises(ValueError):
+            with bench._bounded("x", 30):
+                raise ValueError("kept")
+
+
+def _run_bench(*args, env=None):
+    env = dict(os.environ, **(env or {}))
+    return subprocess.run([sys.executable, "bench.py", *args], cwd=REPO,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+
+
+class TestBackendGate:
+    def test_full_run_without_a_tpu_is_refused(self):
+        """No ``--small``: a measurement path that found no chip."""
+        p = _run_bench("--sections", "serving_qos",
+                       env={"JAX_PLATFORMS": "cpu"})
+        assert p.returncode == 2 and p.stdout == ""
+        assert "needs a TPU" in p.stderr
+
+    def test_failed_section_fails_the_run(self):
+        """A rehearsal whose one section raises: the JSON line says so
+        and the exit code is non-zero."""
+        p = _run_bench("--small", "--sections", "e2e", "--gather-mode",
+                       "no-such-mode", env={"JAX_PLATFORMS": "cpu"})
+        assert p.returncode == 1, p.stderr[-2000:]
+        out = json.loads(p.stdout.strip().splitlines()[-1])
+        assert out["ok"] is False and "e2e" in out["failed"]
+        assert out["device"]["platform"] == "cpu"
+        assert out["vs_baseline"] is None
+
+
+class TestProbe:
+    def test_probe_runs_in_this_process(self, small_graph):
+        """No child: the probe times a sampler on the graph it is given."""
+        ms = bench.probe_sampler(small_graph, "xla", [3, 2], 16)
+        assert ms > 0
+
+    def test_refused_mode_raises(self, small_graph):
+        with pytest.raises(ValueError):
+            bench.probe_sampler(small_graph, "blocked:0", [3, 2], 16)
 
 
 class TestServingSetupCache:
@@ -138,25 +165,3 @@ class TestServingSetupCache:
         v2 = bench._serving_setup(t2, 4, 2, 4)
         assert v2 is not v1
         assert bench._SERVING_CACHE["topo"] is t2
-
-
-class TestHarvestGate:
-    """bench.is_live_harvest — the ONE gate shared by the retry loop's
-    validity check and harvest_commit.py."""
-
-    def _base(self):
-        return {"value": 1e7, "sections": {"sampling": {"seps": 1e7}},
-                "device": True, "backend": "tpu",
-                "headline_source": "live"}
-
-    def test_accepts_live_tpu(self):
-        assert bench.is_live_harvest(self._base())
-
-    @pytest.mark.parametrize("patch", [
-        {"device": False}, {"backend": "cpu"}, {"backend": None},
-        {"headline_source": "prior"}, {"value": 0},
-        {"sections": {}},
-    ])
-    def test_rejects_anything_less(self, patch):
-        out = dict(self._base(), **patch)
-        assert not bench.is_live_harvest(out), patch

@@ -2,8 +2,8 @@
 
 Explicit kwarg > env (QUIVER_TPU_*) / tuned file > backend default.
 Backend default on CPU (the test backend): gather_mode="xla",
-sample_rng="key".  The accelerator branch ("lanes"/"hash",
-docs/TPU_MEASUREMENTS.md round 2) can't execute here; the precedence
+sample_rng="key".  The accelerator branch ("lanes"/"hash") can't
+execute here (chip_smoke.py prints what it resolves there); the precedence
 logic it shares is what's under test.
 
 All env mutation goes through ``monkeypatch`` so it is restored even on

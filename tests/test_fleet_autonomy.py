@@ -67,7 +67,12 @@ def counter_value(name, **labels):
 
 @pytest.fixture(autouse=True)
 def _clean():
+    # the election tests read counters: they need the process-global
+    # registry live whatever an earlier file in this worker left behind
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
     yield
+    telemetry.set_enabled(was)
     chaos.uninstall()
     breakers_reset()
 

@@ -247,6 +247,24 @@ class TestMeshSampler:
         np.testing.assert_array_equal(flat, ref_flat)
         np.testing.assert_array_equal(got, table[ref_flat])
 
+    def test_every_shard_lives_on_its_own_device(self, rng, table):
+        """Shard s's CSR and frame pool are committed to device s — not
+        parked on device 0 and resharded — and the mesh-wide view is
+        assembled from the pools where they are."""
+        indptr, indices = _csr(rng)
+        ms = MeshSampler(indptr, indices, n_shards=4)
+        mf = MeshFeature(table, n_shards=4)
+        mf[rng.integers(0, N, 32)]                   # stacks the view
+        want = [str(d) for d in ms.mesh.devices.reshape(-1)]
+        assert len(set(want)) == 4
+        assert [p["device"] for p in ms.stats()["placement"]] == want
+        assert [s["device"] for s in mf.stats()["shards"]] == want
+        assert [str(s.device) for s in
+                mf._frames_g.addressable_shards] == want
+        # after a fault the pool is still where it was
+        assert [str(next(iter(s.frames.devices())))
+                for s in mf._stores] == want
+
     def test_steady_state_sampler_builds_nothing(self, rng):
         indptr, indices = _csr(rng)
         ms = MeshSampler(indptr, indices, n_shards=4)

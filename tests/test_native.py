@@ -21,6 +21,20 @@ def test_native_builds():
     assert native.native_available(), "g++ build of quiver_cpu.so failed"
 
 
+def test_failed_build_says_what_the_compiler_said(tmp_path, monkeypatch):
+    """The numpy fallback is not silent: the compiler's stderr is in the
+    warning, once, and ``native_available()`` is then False."""
+    bad = tmp_path / "quiver_cpu.cpp"
+    bad.write_text("int main() { this is not c++; }\n")
+    monkeypatch.setattr(native, "_SRC", bad)
+    monkeypatch.setattr(native, "_LIB", tmp_path / "libquiver_cpu.so")
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_failed", False)
+    with pytest.warns(RuntimeWarning, match=r"(?s)build failed.*error"):
+        assert not native.native_available()
+    assert not native.native_available()     # remembered, not retried
+
+
 def test_coo_to_csr_native(csr):
     indptr, indices, n = csr
     assert indptr[-1] == len(indices)

@@ -22,6 +22,7 @@ import sys
 import time
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -147,6 +148,48 @@ class TestKernel:
 
 
 # -------------------------------------------------- bit-identical mixes
+class TestMosaicEnvelope:
+    """Interpret mode takes any width; Mosaic does not.  On a TPU the
+    shapes the chip's compiler refused (tests/test_aot_compile.py) are
+    refused by name, not run through a staged fallback."""
+
+    @pytest.mark.parametrize("dim", [100, 602])
+    def test_enable_paging_on_a_tpu_names_the_lane_constraint(
+            self, rng, monkeypatch, dim):
+        from quiver_tpu.ops.pallas import KernelConstraintError
+
+        feat = rng.normal(size=(64, dim)).astype(np.float32)
+        f = Feature(device_cache_size=16,
+                    cache_unit="rows").from_cpu_tensor(feat)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(KernelConstraintError, match="128 lanes"):
+            f.enable_paging()
+        assert f.paged is None
+
+    def test_kernel_on_a_tpu_names_the_smem_constraint(self):
+        from quiver_tpu.ops.pallas import KernelConstraintError
+
+        m = 1 << 20
+        with pytest.raises(KernelConstraintError, match="SMEM"):
+            jax.eval_shape(
+                lambda *a: page_gather(*a, page_rows=8, block=128,
+                                       ppb=128),
+                jax.ShapeDtypeStruct((64, 8, 128), jnp.float32),
+                jax.ShapeDtypeStruct((m,), jnp.int32),
+                jax.ShapeDtypeStruct((m // 128,), jnp.int32),
+                jax.ShapeDtypeStruct((m,), jnp.int32),
+                jax.ShapeDtypeStruct((m,), jnp.int32))
+
+    def test_interpret_mode_is_not_checked(self, rng):
+        """CPU tests keep running the kernel at D=100."""
+        feat = rng.normal(size=(64, 100)).astype(np.float32)
+        f = Feature(device_cache_size=16,
+                    cache_unit="rows").from_cpu_tensor(feat)
+        f.enable_paging()
+        ids = rng.integers(0, 64, 32)
+        np.testing.assert_array_equal(np.asarray(f[ids]), feat[ids])
+
+
 class TestPagedEquivalence:
     """Seeded property suite: every residency mix must come back equal
     to the source tensor bit for bit (float32 rows pass through gathers

@@ -139,3 +139,30 @@ def test_checkpoint_root_named_ckpt_prefix(tmp_path):
     save_checkpoint(str(root), state, step=5)
     state2, step = load_checkpoint(str(root), state)
     assert step == 5
+
+
+@pytest.mark.parametrize("helper,nodes,edges", [
+    ("synthetic_products", 2_449_029, 123_718_280),
+    ("synthetic_reddit", 232_965, 114_615_892)])
+def test_synthetic_shapes_are_the_published_ones(monkeypatch, helper,
+                                                 nodes, edges):
+    """The same constants bench.py and chip_smoke.py use (the Reddit
+    helper once built a tenth of the edges)."""
+    import bench
+    import chip_smoke
+    from quiver_tpu.utils import synthetic
+
+    asked = []
+
+    def fake_csr(n, e, seed=0):
+        asked.append((n, e))
+        return np.zeros(2, np.int64), np.zeros(0, np.int32)
+
+    monkeypatch.setattr(synthetic, "synthetic_csr", fake_csr)
+    getattr(synthetic, helper)()
+    assert asked == [(nodes, edges)]
+    name = helper.split("_")[1].upper()
+    assert (getattr(bench, name + "_NODES"),
+            getattr(bench, name + "_EDGES")) == (nodes, edges)
+    shape = getattr(chip_smoke, name)
+    assert (shape.nodes, shape.edges) == (nodes, edges)
