@@ -1,0 +1,10 @@
+"""Device milliseconds per traced step of operations under ``qt.sampler``
+and its hops (``qt.sampler.hop<n>``: draw, relabel, frontier), from the
+trace's operations joined with the program's scope table
+(cellbench/scope_split.py)."""
+
+import scope_split
+
+
+def read(ctx):
+    return scope_split.scope_ms(ctx, "qt.sampler")

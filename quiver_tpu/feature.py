@@ -32,6 +32,7 @@ from typing import List, Optional, Sequence, Union
 
 import numpy as np
 
+from .telemetry.device_scopes import FEATURE_GATHER
 from .utils.topology import CSRTopo, parse_size, reindex_feature
 
 __all__ = ["Feature", "DeviceConfig"]
@@ -66,12 +67,14 @@ def _lookup_tables(tables, idx):
     """``Feature.lookup_device`` over explicit ``(hot, order)`` arrays
     (from ``Feature._device_tables``), so a jitted caller can take the
     tables as arguments."""
+    import jax
     import jax.numpy as jnp
 
     hot, order = tables
-    if order is not None:
-        idx = jnp.take(order, idx, mode="clip")
-    return jnp.take(hot, idx, axis=0)
+    with jax.named_scope(FEATURE_GATHER):
+        if order is not None:
+            idx = jnp.take(order, idx, mode="clip")
+        return jnp.take(hot, idx, axis=0)
 
 
 @dataclass

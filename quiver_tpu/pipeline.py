@@ -22,6 +22,7 @@ import optax
 from .feature import Feature, _lookup_tables
 from .sampler import GraphSageSampler, run_pipeline
 from .parallel.train import TrainState
+from .telemetry.device_scopes import MODEL, OPTIMIZER, register_program
 
 __all__ = ["make_fused_train_step", "make_fused_eval_fn"]
 
@@ -43,9 +44,15 @@ def make_fused_train_step(sampler: GraphSageSampler, feature: Feature,
     impl = _fused_train_impl(sampler, feature, apply_fn, loss_fn)
     tables = _tables(sampler, feature)
     jitted = jax.jit(impl, donate_argnums=(1,))
+    registered = False
 
     def step(state: TrainState, seeds, labels, label_mask, key):
-        return jitted(tables, state, seeds, labels, label_mask, key)
+        nonlocal registered
+        args = (tables, state, seeds, labels, label_mask, key)
+        if not registered:      # before the call: ``state`` is donated
+            registered = True
+            register_program(jitted, args)
+        return jitted(*args)
 
     return step
 
@@ -62,7 +69,15 @@ def _tables(sampler: GraphSageSampler, feature: Feature):
 def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
                       apply_fn: Callable, loss_fn: Optional[Callable]):
     """Un-jitted ``(tables, state, seeds, labels, label_mask, key) ->
-    (state, loss)`` shared by the fused step and the scan epoch."""
+    (state, loss)`` shared by the fused step and the scan epoch.
+
+    The three jitted programs of this file carry names of their own
+    (``jit_qt_fused_train_step``, ``jit_qt_scan_epoch``,
+    ``jit_qt_fused_eval``): a trace's ``XLA Modules`` line names a program
+    by its function, and the persistent compile cache keys on that name but
+    NOT on scope names (debug information is stripped from the key), so a
+    program whose ``qt.*`` scopes change must change its name too or
+    ``telemetry.device_scopes`` reads the old scopes back from the cache."""
     _check(feature)
     sizes = tuple(sampler.sizes)
     gm, srng = sampler.gather_mode, sampler.sample_rng
@@ -77,7 +92,8 @@ def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
             m = mask.astype(ls.dtype)
             return (ls * m).sum() / jnp.maximum(m.sum(), 1.0)
 
-    def step(tables, state: TrainState, seeds, labels, label_mask, key):
+    def qt_fused_train_step(tables, state: TrainState, seeds, labels,
+                            label_mask, key):
         indptr, indices, feat_tables = tables
         ks, kd = jax.random.split(key)
         n_id, n_mask, num, blocks, _ = run_pipeline(
@@ -87,17 +103,19 @@ def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
         x = _lookup_tables(feat_tables, n_id)
 
         def compute(params):
-            logits = apply_fn(params, x, blocks, train=True,
-                              rngs={"dropout": kd})
-            return loss_fn(logits, labels, label_mask)
+            with jax.named_scope(MODEL):
+                logits = apply_fn(params, x, blocks, train=True,
+                                  rngs={"dropout": kd})
+                return loss_fn(logits, labels, label_mask)
 
         loss, grads = jax.value_and_grad(compute)(state.params)
-        updates, opt_state = state.tx.update(grads, state.opt_state,
-                                             state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(OPTIMIZER):
+            updates, opt_state = state.tx.update(grads, state.opt_state,
+                                                 state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.tx), loss
 
-    return step
+    return qt_fused_train_step
 
 
 def make_scan_epoch(sampler: GraphSageSampler, feature: Feature,
@@ -114,7 +132,7 @@ def make_scan_epoch(sampler: GraphSageSampler, feature: Feature,
     tables = _tables(sampler, feature)
 
     @jax.jit
-    def scan(tables, state: TrainState, seeds, labels, key):
+    def qt_scan_epoch(tables, state: TrainState, seeds, labels, key):
         S, B = seeds.shape
         ones = jnp.ones((B,), bool)
 
@@ -126,8 +144,15 @@ def make_scan_epoch(sampler: GraphSageSampler, feature: Feature,
         state, losses = jax.lax.scan(body, state, (seeds, labels, keys))
         return state, losses
 
+    registered = False
+
     def epoch(state: TrainState, seeds, labels, key):
-        return scan(tables, state, seeds, labels, key)
+        nonlocal registered
+        args = (tables, state, seeds, labels, key)
+        if not registered:
+            registered = True
+            register_program(qt_scan_epoch, args)
+        return qt_scan_epoch(*args)
 
     return epoch
 
@@ -144,16 +169,24 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
     caps = tuple(sampler.frontier_caps)
 
     @jax.jit
-    def jitted(tables, params, seeds, key):
+    def qt_fused_eval(tables, params, seeds, key):
         indptr, indices, feat_tables = tables
         n_id, n_mask, num, blocks, _ = run_pipeline(
             dedup, indptr, indices, seeds, key, sizes, caps, gather_mode=gm,
             sample_rng=srng
         )
         x = _lookup_tables(feat_tables, n_id)
-        return apply_fn(params, x, blocks, train=False, rngs=None)
+        with jax.named_scope(MODEL):
+            return apply_fn(params, x, blocks, train=False, rngs=None)
+
+    registered = False
 
     def eval_fn(params, seeds, key):
-        return jitted(tables, params, seeds, key)
+        nonlocal registered
+        args = (tables, params, seeds, key)
+        if not registered:
+            registered = True
+            register_program(qt_fused_eval, args)
+        return qt_fused_eval(*args)
 
     return eval_fn

@@ -33,6 +33,7 @@ from . import telemetry
 from .ops.sample import (sample_neighbors, sample_neighbors_overlay,
                          sample_neighbors_weighted, row_cumsum_weights)
 from .ops.reindex import reindex
+from .telemetry.device_scopes import SAMPLER, sampler_hop
 from .utils.topology import CSRTopo
 
 __all__ = ["GraphSageSampler", "SampledBatch", "LayerBlock"]
@@ -113,43 +114,46 @@ def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
     tree-expansion semantics); validity masks carry through.  Exact-dedup
     per hop stays available via ``dedup="hop"`` for parity.
     """
-    B = seeds.shape[0]
-    frontier = seeds.astype(jnp.int32)
-    fmask = jnp.ones((B,), dtype=bool)
+    with jax.named_scope(SAMPLER):
+        B = seeds.shape[0]
+        frontier = seeds.astype(jnp.int32)
+        fmask = jnp.ones((B,), dtype=bool)
+        keys = jax.random.split(key, len(sizes))
     blocks = []
-    keys = jax.random.split(key, len(sizes))
     for l, k in enumerate(sizes):
-        if cum_weights is not None:
-            out = sample_neighbors_weighted(indptr, indices, cum_weights,
-                                            frontier, k, keys[l],
-                                            seed_mask=fmask,
-                                            sample_rng=sample_rng,
-                                            gather_mode=gather_mode)
-        else:
-            out = sample_neighbors(indptr, indices, frontier, k, keys[l],
-                                   seed_mask=fmask,
-                                   gather_mode=gather_mode,
-                                   sample_rng=sample_rng)
-        t = frontier.shape[0]
-        pos = (t + jnp.arange(t, dtype=jnp.int32)[:, None] * k
-               + jnp.arange(k, dtype=jnp.int32)[None, :])
-        blocks.append(
-            LayerBlock(
-                nbr_local=jnp.where(out.mask, pos, 0),
-                mask=out.mask,
-                num_targets=fmask.sum().astype(jnp.int32),
-                # None lets XLA DCE the eid computation entirely — an
-                # extra [T, k] int32 per hop is ~40% more sampler output
-                # HBM traffic, only worth it for edge-featured models
-                eid=out.eid if return_eid else None,
+        with jax.named_scope(sampler_hop(l + 1)):
+            if cum_weights is not None:
+                out = sample_neighbors_weighted(indptr, indices, cum_weights,
+                                                frontier, k, keys[l],
+                                                seed_mask=fmask,
+                                                sample_rng=sample_rng,
+                                                gather_mode=gather_mode)
+            else:
+                out = sample_neighbors(indptr, indices, frontier, k, keys[l],
+                                       seed_mask=fmask,
+                                       gather_mode=gather_mode,
+                                       sample_rng=sample_rng)
+            t = frontier.shape[0]
+            pos = (t + jnp.arange(t, dtype=jnp.int32)[:, None] * k
+                   + jnp.arange(k, dtype=jnp.int32)[None, :])
+            blocks.append(
+                LayerBlock(
+                    nbr_local=jnp.where(out.mask, pos, 0),
+                    mask=out.mask,
+                    num_targets=fmask.sum().astype(jnp.int32),
+                    # None lets XLA DCE the eid computation entirely — an
+                    # extra [T, k] int32 per hop is ~40% more sampler output
+                    # HBM traffic, only worth it for edge-featured models
+                    eid=out.eid if return_eid else None,
+                )
             )
-        )
-        frontier = jnp.concatenate(
-            [frontier, jnp.where(out.mask, out.nbrs, 0).reshape(-1)]
-        )
-        fmask = jnp.concatenate([fmask, out.mask.reshape(-1)])
-    num_nodes = fmask.sum().astype(jnp.int32)
-    drops = jnp.zeros((len(sizes),), jnp.int32)  # nothing ever dropped
+            frontier = jnp.concatenate(
+                [frontier, jnp.where(out.mask, out.nbrs, 0).reshape(-1)]
+            )
+            fmask = jnp.concatenate([fmask, out.mask.reshape(-1)])
+    with jax.named_scope(SAMPLER):
+        num_nodes = fmask.sum().astype(jnp.int32)
+        drops = jnp.zeros((len(sizes),), jnp.int32)  # nothing ever dropped
     return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops
 
 
@@ -167,35 +171,38 @@ def _sample_pipeline_overlay(indptr, indices, tomb, d_indptr, d_indices,
     identical to the frozen positional pipeline (the streaming tier's
     equivalence contract).
     """
-    B = seeds.shape[0]
-    frontier = seeds.astype(jnp.int32)
-    fmask = jnp.ones((B,), dtype=bool)
+    with jax.named_scope(SAMPLER):
+        B = seeds.shape[0]
+        frontier = seeds.astype(jnp.int32)
+        fmask = jnp.ones((B,), dtype=bool)
+        keys = jax.random.split(key, len(sizes))
     blocks = []
-    keys = jax.random.split(key, len(sizes))
     for l, k in enumerate(sizes):
-        out = sample_neighbors_overlay(
-            indptr, indices, tomb, d_indptr, d_indices, frontier, k,
-            keys[l], seed_mask=fmask, base_ts=base_ts, d_ts=d_ts,
-            window_lo=window_lo, window_hi=window_hi,
-            gather_mode=gather_mode, sample_rng=sample_rng,
-            windowed=windowed)
-        t = frontier.shape[0]
-        pos = (t + jnp.arange(t, dtype=jnp.int32)[:, None] * k
-               + jnp.arange(k, dtype=jnp.int32)[None, :])
-        blocks.append(
-            LayerBlock(
-                nbr_local=jnp.where(out.mask, pos, 0),
-                mask=out.mask,
-                num_targets=fmask.sum().astype(jnp.int32),
-                eid=out.eid if return_eid else None,
+        with jax.named_scope(sampler_hop(l + 1)):
+            out = sample_neighbors_overlay(
+                indptr, indices, tomb, d_indptr, d_indices, frontier, k,
+                keys[l], seed_mask=fmask, base_ts=base_ts, d_ts=d_ts,
+                window_lo=window_lo, window_hi=window_hi,
+                gather_mode=gather_mode, sample_rng=sample_rng,
+                windowed=windowed)
+            t = frontier.shape[0]
+            pos = (t + jnp.arange(t, dtype=jnp.int32)[:, None] * k
+                   + jnp.arange(k, dtype=jnp.int32)[None, :])
+            blocks.append(
+                LayerBlock(
+                    nbr_local=jnp.where(out.mask, pos, 0),
+                    mask=out.mask,
+                    num_targets=fmask.sum().astype(jnp.int32),
+                    eid=out.eid if return_eid else None,
+                )
             )
-        )
-        frontier = jnp.concatenate(
-            [frontier, jnp.where(out.mask, out.nbrs, 0).reshape(-1)]
-        )
-        fmask = jnp.concatenate([fmask, out.mask.reshape(-1)])
-    num_nodes = fmask.sum().astype(jnp.int32)
-    drops = jnp.zeros((len(sizes),), jnp.int32)
+            frontier = jnp.concatenate(
+                [frontier, jnp.where(out.mask, out.nbrs, 0).reshape(-1)]
+            )
+            fmask = jnp.concatenate([fmask, out.mask.reshape(-1)])
+    with jax.named_scope(SAMPLER):
+        num_nodes = fmask.sum().astype(jnp.int32)
+        drops = jnp.zeros((len(sizes),), jnp.int32)
     return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops
 
 
@@ -203,50 +210,55 @@ def _sample_pipeline(indptr, indices, seeds, key, sizes, caps,
                      gather_mode="xla", cum_weights=None,
                      return_eid=False, sample_rng="auto"):
     """Traced multi-hop pipeline: outward sampling with per-hop dedup."""
-    B = seeds.shape[0]
-    frontier = seeds.astype(jnp.int32)
-    fmask = jnp.ones((B,), dtype=bool)
+    with jax.named_scope(SAMPLER):
+        B = seeds.shape[0]
+        frontier = seeds.astype(jnp.int32)
+        fmask = jnp.ones((B,), dtype=bool)
+        keys = jax.random.split(key, len(sizes))
     blocks = []
     drops = []  # per-hop count of frontier nodes dropped by the cap
-    keys = jax.random.split(key, len(sizes))
     for l, (k, cap) in enumerate(zip(sizes, caps)):
-        if cum_weights is not None:
-            out = sample_neighbors_weighted(indptr, indices, cum_weights,
-                                            frontier, k, keys[l],
-                                            seed_mask=fmask,
-                                            sample_rng=sample_rng,
-                                            gather_mode=gather_mode)
-        else:
-            out = sample_neighbors(indptr, indices, frontier, k, keys[l],
-                                   seed_mask=fmask, gather_mode=gather_mode,
-                                   sample_rng=sample_rng)
-        r = reindex(frontier, out.nbrs, out.mask, seed_mask=fmask)
-        blocks.append(
-            LayerBlock(
-                nbr_local=r.local_nbrs,
-                mask=r.mask,
-                num_targets=fmask.sum().astype(jnp.int32),
-                eid=out.eid if return_eid else None,
+        with jax.named_scope(sampler_hop(l + 1)):
+            if cum_weights is not None:
+                out = sample_neighbors_weighted(indptr, indices, cum_weights,
+                                                frontier, k, keys[l],
+                                                seed_mask=fmask,
+                                                sample_rng=sample_rng,
+                                                gather_mode=gather_mode)
+            else:
+                out = sample_neighbors(indptr, indices, frontier, k, keys[l],
+                                       seed_mask=fmask,
+                                       gather_mode=gather_mode,
+                                       sample_rng=sample_rng)
+            r = reindex(frontier, out.nbrs, out.mask, seed_mask=fmask)
+            blocks.append(
+                LayerBlock(
+                    nbr_local=r.local_nbrs,
+                    mask=r.mask,
+                    num_targets=fmask.sum().astype(jnp.int32),
+                    eid=out.eid if return_eid else None,
+                )
             )
-        )
-        n_id, n_mask = r.n_id, r.n_id_mask
-        drop = jnp.int32(0)
-        if cap is not None and n_id.shape[0] > cap:
-            # Keep the prefix: seeds region is intact (caps must be >= T);
-            # dropped tail nodes get masked out of this layer's block.
-            drop = n_mask[cap:].sum().astype(jnp.int32)
-            n_id, n_mask = n_id[:cap], n_mask[:cap]
-            keep = blocks[-1].nbr_local < cap
-            blocks[-1] = blocks[-1]._replace(
-                mask=blocks[-1].mask & keep,
-                nbr_local=jnp.where(keep, blocks[-1].nbr_local, 0),
-                eid=(jnp.where(keep, blocks[-1].eid, jnp.int32(-1))
-                     if blocks[-1].eid is not None else None),
-            )
-        drops.append(drop)
-        frontier, fmask = n_id, n_mask
-    num_nodes = fmask.sum().astype(jnp.int32)
-    return frontier, fmask, num_nodes, tuple(blocks[::-1]), jnp.stack(drops)
+            n_id, n_mask = r.n_id, r.n_id_mask
+            drop = jnp.int32(0)
+            if cap is not None and n_id.shape[0] > cap:
+                # Keep the prefix: seeds region is intact (caps must be >= T);
+                # dropped tail nodes get masked out of this layer's block.
+                drop = n_mask[cap:].sum().astype(jnp.int32)
+                n_id, n_mask = n_id[:cap], n_mask[:cap]
+                keep = blocks[-1].nbr_local < cap
+                blocks[-1] = blocks[-1]._replace(
+                    mask=blocks[-1].mask & keep,
+                    nbr_local=jnp.where(keep, blocks[-1].nbr_local, 0),
+                    eid=(jnp.where(keep, blocks[-1].eid, jnp.int32(-1))
+                         if blocks[-1].eid is not None else None),
+                )
+            drops.append(drop)
+            frontier, fmask = n_id, n_mask
+    with jax.named_scope(SAMPLER):
+        num_nodes = fmask.sum().astype(jnp.int32)
+        drops = jnp.stack(drops)
+    return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops
 
 
 def _is_stream_graph(obj) -> bool:

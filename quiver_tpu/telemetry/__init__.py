@@ -31,6 +31,7 @@ import os
 from typing import Optional, Sequence
 
 from . import noop as _noop
+from .device_scopes import device_scopes, register_program
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        DEFAULT_TIME_BUCKETS, metric_key, parse_metric_key,
                        snapshot_delta, summarize_snapshot)
@@ -42,6 +43,7 @@ __all__ = [
     "snapshot_delta", "summarize_snapshot",
     "enabled", "set_enabled", "get_registry", "get_tracer",
     "counter", "gauge", "histogram", "span",
+    "device_scopes", "register_program",
     "snapshot", "merge", "reset",
 ]
 

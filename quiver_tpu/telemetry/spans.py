@@ -13,6 +13,12 @@ independent switches:
     ``chrome_trace()`` serializes as Chrome trace-event JSON
     (``{"traceEvents": [...]}``) loadable in Perfetto / chrome://tracing.
 
+Every span also enters a ``jax.profiler.TraceAnnotation("qt." + name)``:
+while a ``jax.profiler`` trace is being taken the host spans land on its
+``/host:CPU`` plane, on the device trace's clock and under the prefix of
+the device scopes (:mod:`.device_scopes`); with no trace running the
+annotation costs a few hundred nanoseconds.
+
 A word on async dispatch: like the old ``trace_scope``, a span around a
 jitted call measures **dispatch** unless you pass ``block=`` an array
 (or list of arrays) to ``block_until_ready`` before the span closes.
@@ -20,6 +26,7 @@ jitted call measures **dispatch** unless you pass ``block=`` an array
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -27,6 +34,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from . import timeline as _timeline
+from .device_scopes import PREFIX
 
 __all__ = ["SpanTracer", "Span"]
 
@@ -38,12 +46,21 @@ def _env_tracing() -> bool:
         "1", "true", "on", "yes")
 
 
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, looked up on first use: this
+    package imports no jax at module level."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
 class Span:
     """One ``with``-scope.  Created per call (only when telemetry is
     enabled); closing folds into the tracer's aggregate and, when
     tracing, appends an event record."""
 
-    __slots__ = ("_tracer", "name", "_block", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "_block", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, block=None):
         self._tracer = tracer
@@ -54,6 +71,8 @@ class Span:
         tls = self._tracer._tls
         self._depth = getattr(tls, "depth", 0)
         tls.depth = self._depth + 1
+        self._ann = _trace_annotation()(PREFIX + self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -63,6 +82,7 @@ class Span:
             for x in (blk if isinstance(blk, (list, tuple)) else (blk,)):
                 getattr(x, "block_until_ready", lambda: None)()
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self._tracer._tls.depth = self._depth
         self._tracer._close(self.name, self._t0, t1, self._depth)
         return False

@@ -292,6 +292,67 @@ def test_sage_train_step_compiles(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 12 << 30
 
 
+def test_fused_train_step_scopes_reach_the_tpu_text(one_chip):
+    """The fused step at a small graph, batch and fanout (seconds to
+    compile), parsed by the scope table's own parser: the module carries
+    its stable name and every ``fusion`` / ``custom-call`` of the entry
+    computation sits under a ``qt.`` scope, but for the listed few."""
+    import re
+    import types
+
+    from quiver_tpu.pipeline import _fused_train_impl
+    from quiver_tpu.telemetry.device_scopes import (instruction_key,
+                                                    parse_hlo_scopes)
+
+    nodes, edges, dim, B, sizes = 20_000, 200_000, 128, 64, (5, 4, 3)
+    model, apply_fn = _sage(64, 16, 3)
+    x, blocks = _sampled_shapes(nodes, edges, dim, B, sizes)
+    tx, state = _train_state(model, x, blocks)
+    # what the library resolves to on a TPU (PERF.md, PR 21), said here
+    # because ``GraphSageSampler`` would resolve for the CPU it runs on
+    sampler = types.SimpleNamespace(
+        sizes=sizes, gather_mode="lanes", sample_rng="hash", dedup="none",
+        frontier_caps=(None,) * len(sizes))
+    feature = types.SimpleNamespace(cache_count=nodes, node_count=nodes)
+    impl = _fused_train_impl(
+        sampler, feature,
+        lambda p, x, blocks, **kw: apply_fn(p, x.astype(jnp.float32),
+                                            blocks, **kw), None)
+    tables = (*_graph(one_chip, nodes, edges),
+              (_s(one_chip, (nodes, dim), jnp.bfloat16), None))
+    c = jax.jit(impl, donate_argnums=(1,)).lower(
+        tables, _on(one_chip, state), _s(one_chip, (B,)),
+        _s(one_chip, (B,)), _s(one_chip, (B,), jnp.bool_),
+        _key(one_chip)).compile()
+    text = c.as_text()
+    module, table = parse_hlo_scopes(text)
+    assert module == "jit_qt_fused_train_step"
+
+    # outside every scope, and why:
+    own_key_split = re.compile(       # ``ks, kd = jax.random.split(key)``
+        r"^jit\(qt_fused_train_step\)/(jit\(_threefry_split\)/|squeeze$)")
+    index_clamp = "gather"   # added by the compiler before a gather; keeps
+    #                          only the primitive's name
+    unnamed = []             # a dot rewritten into a convolution loses all
+    scopes = set()
+    entry = text[text.index("\nENTRY "):]
+    for line in entry.splitlines():
+        if not re.search(r"[})] (fusion|custom-call)\(", line):
+            continue
+        op = table.get(instruction_key(line))
+        if op is None:
+            unnamed.append(line[:120])
+        elif not (own_key_split.match(op) or op == index_clamp):
+            m = re.search(r"qt(\.\w+)+", op)
+            assert m, f"outside every qt. scope: {op}\n{line[:200]}"
+            scopes.add((m.group(0), "transpose(" in op))
+    assert len(unnamed) <= 2, unnamed
+    for scope in ("qt.sampler.hop1", "qt.sampler.hop2", "qt.sampler.hop3",
+                  "qt.feature.gather", "qt.model", "qt.optimizer"):
+        assert (scope, False) in scopes, (scope, sorted(scopes))
+    assert ("qt.model", True) in scopes
+
+
 @pytest.mark.parametrize("bucket", [8, 128, 2048])
 def test_serving_bucket_forward_compiles(one_chip, bucket):
     """One ``InferenceServer`` bucket at Reddit widths: sample [25,10] +
