@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..sampler import LayerBlock
+from .layers import sources
 
 __all__ = ["GCNConv", "GCN"]
 
@@ -30,7 +31,7 @@ class GCNConv(nn.Module):
         t = block.nbr_local.shape[0]
         w = nn.Dense(self.features, use_bias=True, dtype=self.dtype,
                      name="lin")(x)
-        w_src = jnp.take(w, block.nbr_local, axis=0)        # [T, k, F]
+        w_src = sources(w, block)                           # [T, k, F]
         m = block.mask.astype(x.dtype)[..., None]
         deg = block.mask.sum(axis=1).astype(x.dtype)        # [T]
         # self-loop-augmented normalization with sampled degrees

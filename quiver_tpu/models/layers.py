@@ -4,9 +4,12 @@ The reference delegates models to PyG (``SAGEConv``/``GATConv`` consuming
 ragged ``edge_index``); examples at
 ``/root/reference/examples/pyg/ogbn_products_sage_quiver.py:31-70``.  We
 keep the same math but consume quiver_tpu's dense ``[T, k]`` neighbor
-blocks: aggregation is a gather + masked mean / masked softmax — batched,
-static-shaped, fused by XLA into MXU-friendly matmuls, with no
-segment-scatter in sight.
+blocks: aggregation is a masked mean / masked softmax over each target's
+``[k, D]`` source rows — batched, static-shaped, fused by XLA into
+MXU-friendly matmuls, with no segment-scatter in sight.  :func:`sources`
+finds those rows: a slice of ``x`` when the block says it was built
+positionally (``LayerBlock.layout``), a gather through ``nbr_local``
+otherwise.
 """
 
 from __future__ import annotations
@@ -19,7 +22,27 @@ import jax.numpy as jnp
 
 from ..sampler import LayerBlock
 
-__all__ = ["SAGEConv", "GATConv"]
+__all__ = ["SAGEConv", "GATConv", "sources"]
+
+
+def sources(x: jax.Array, block: LayerBlock) -> jax.Array:
+    """``[T, k, ...]`` rows of ``x`` holding each target's sampled sources.
+
+    A positional block (``block.layout``, see :class:`LayerBlock`) keeps
+    target ``b``'s sources at rows ``T + b*k .. T + b*k + k - 1``, so they
+    are ``x[T:]`` viewed ``[T, k, ...]``: no gather forward, a pad (not a
+    scatter-add) backward.  Masked slots then read their own pad row
+    instead of row 0; every consumer multiplies them by a zero mask either
+    way.  Any other block is gathered through ``nbr_local``.
+    """
+    if block.layout is None:
+        return jnp.take(x, block.nbr_local, axis=0)
+    t, k = block.mask.shape
+    if x.shape[0] != t * (1 + k):
+        raise ValueError(
+            f"positional block with T={t} targets and k={k} expects "
+            f"x of length T*(1+k)={t * (1 + k)}, got {x.shape[0]}")
+    return x[t:].reshape(t, k, *x.shape[1:])
 
 
 class SAGEConv(nn.Module):
@@ -46,7 +69,7 @@ class SAGEConv(nn.Module):
     def __call__(self, x: jax.Array, block: LayerBlock,
                  edge_feat: Optional[jax.Array] = None) -> jax.Array:
         t = block.nbr_local.shape[0]
-        x_src = jnp.take(x, block.nbr_local, axis=0)        # [T, k, D]
+        x_src = sources(x, block)                           # [T, k, D]
         m = block.mask[..., None].astype(x.dtype)
         cnt = jnp.maximum(m.sum(axis=1), 1.0)               # [T, 1]
         mean_nbr = (x_src * m).sum(axis=1) / cnt            # [T, D]
@@ -81,7 +104,7 @@ class GATConv(nn.Module):
         w = nn.Dense(h * f, use_bias=False, dtype=self.dtype,
                      name="lin")(x)
         w = w.reshape(x.shape[0], h, f)
-        w_src = jnp.take(w, block.nbr_local, axis=0)         # [T, k, H, F]
+        w_src = sources(w, block)                            # [T, k, H, F]
         w_tgt = w[:t]                                        # [T, H, F]
         a_src = self.param("att_src", nn.initializers.glorot_uniform(),
                            (h, f))

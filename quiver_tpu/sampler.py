@@ -22,6 +22,7 @@ sit far below the no-dedup bound, so a cap ~2x the typical frontier loses
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple, Optional, Sequence, Tuple, Union
 
@@ -36,7 +37,19 @@ from .ops.reindex import reindex
 from .telemetry.device_scopes import SAMPLER, sampler_hop
 from .utils.topology import CSRTopo
 
-__all__ = ["GraphSageSampler", "SampledBatch", "LayerBlock"]
+__all__ = ["GraphSageSampler", "SampledBatch", "LayerBlock", "POSITIONAL"]
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class _Positional:
+    """Type of :data:`POSITIONAL`: a pytree node with no leaves (and, as
+    a field-less frozen dataclass, equal to and hashed like every other
+    instance), so the marker is part of a block's tree *structure* and
+    crosses ``jax.jit`` as the Python value it is, never as a tracer."""
+
+
+POSITIONAL = _Positional()
 
 
 class LayerBlock(NamedTuple):
@@ -45,12 +58,23 @@ class LayerBlock(NamedTuple):
     Targets are the first ``num_targets`` entries of the *previous* (inner)
     frontier; ``nbr_local[b, j]`` indexes into this layer's frontier
     (``n_id``) to find source nodes.
+
+    ``layout=POSITIONAL`` is the producer's static promise that this
+    layer's frontier has length ``T * (1 + k)`` and that
+    ``nbr_local[b, j] == T + b * k + j`` wherever ``mask[b, j]``: the
+    sources of target ``b`` are the contiguous frontier rows
+    ``T + b*k .. T + b*k + k - 1``, so a model reads them as a slice
+    (``models.layers.sources``) and no gather, nor its scatter-add
+    backward, is compiled.  ``None`` (the default: ``dedup="hop"``, the
+    host and dist samplers, hand-built blocks) promises nothing and
+    ``nbr_local`` is gathered through.
     """
 
     nbr_local: jax.Array   # [T, k] int32 indices into this layer's n_id
     mask: jax.Array        # [T, k] bool
     num_targets: jax.Array  # scalar int32 (valid targets; T is the pad)
     eid: Optional[jax.Array] = None  # [T, k] int32 global edge ids (-1 pad)
+    layout: Optional[_Positional] = None  # static, see above
 
 
 class SampledBatch(NamedTuple):
@@ -113,6 +137,15 @@ def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
     Duplicate nodes compute duplicate embeddings (= original GraphSAGE
     tree-expansion semantics); validity masks carry through.  Exact-dedup
     per hop stays available via ``dedup="hop"`` for parity.
+
+    Because that position is a function of ``(b, j)`` alone, every block
+    is marked ``layout=POSITIONAL`` and the convs read a target's sources
+    as the slice ``x[P_prev:]`` viewed ``[P_prev, k, D]`` instead of
+    gathering through ``nbr_local`` (a row-at-a-time gather whose
+    backward is a scatter-add; PERF.md, PR 27).  ``nbr_local`` is still
+    returned, value for value, for every consumer that indexes by it
+    (``to_pyg_adjs``, host checks); inside a fused program nothing reads
+    it and XLA drops its ``iota + where``.
     """
     with jax.named_scope(SAMPLER):
         B = seeds.shape[0]
@@ -145,6 +178,7 @@ def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
                     # extra [T, k] int32 per hop is ~40% more sampler output
                     # HBM traffic, only worth it for edge-featured models
                     eid=out.eid if return_eid else None,
+                    layout=POSITIONAL,
                 )
             )
             frontier = jnp.concatenate(
@@ -165,7 +199,8 @@ def _sample_pipeline_overlay(indptr, indices, tomb, d_indptr, d_indices,
     """Traced multi-hop pipeline over base CSR + delta overlay.
 
     Structurally identical to :func:`_sample_pipeline_nodedup` (same key
-    split, same positional relabel), with the one-hop op swapped for
+    split, same positional relabel and ``layout=POSITIONAL`` marker), with
+    the one-hop op swapped for
     :func:`~quiver_tpu.ops.sample.sample_neighbors_overlay` — so with an
     empty delta segment and no tombstones the outputs are bitwise
     identical to the frozen positional pipeline (the streaming tier's
@@ -194,6 +229,7 @@ def _sample_pipeline_overlay(indptr, indices, tomb, d_indptr, d_indices,
                     mask=out.mask,
                     num_targets=fmask.sum().astype(jnp.int32),
                     eid=out.eid if return_eid else None,
+                    layout=POSITIONAL,
                 )
             )
             frontier = jnp.concatenate(

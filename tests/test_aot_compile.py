@@ -351,6 +351,15 @@ def test_fused_train_step_scopes_reach_the_tpu_text(one_chip):
                   "qt.feature.gather", "qt.model", "qt.optimizer"):
         assert (scope, False) in scopes, (scope, sorted(scopes))
     assert ("qt.model", True) in scopes
+    # positional blocks: the convs read their sources as a slice, so no
+    # instruction of the flax module is a gather or a scatter (a block
+    # that lost its marker brings ``GraphSAGE/conv<i>/jit(_take)/gather``
+    # and its ``scatter-add`` back); the loss keeps its own label lookup
+    convs = {op for op in table.values() if "qt.model" in op
+             and "/GraphSAGE/conv" in op}
+    assert convs
+    assert not {op for op in convs
+                if re.search(r"/(gather|scatter(-add)?)$", op)}
 
 
 @pytest.mark.parametrize("bucket", [8, 128, 2048])

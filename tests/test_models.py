@@ -306,3 +306,88 @@ def test_bfloat16_models_train(small_graph, rng):
         upd, opt = tx.update(g, opt, params)
         params = optax.apply_updates(params, upd)
     assert float(loss_fn(params)) < l0
+
+
+# ---- positional blocks: sources read by slice, not by gather -------------
+def _ragged_positional_batch():
+    """Blocks from the positional sampler over a graph whose degrees run
+    0..6 against fanouts [5, 4]: zero-degree targets, under-fanout rows and
+    full rows all occur."""
+    from quiver_tpu import CSRTopo
+
+    n = 70
+    deg = np.arange(n) % 7
+    src = np.repeat(np.arange(n), deg)
+    dst = (src * 3 + np.concatenate([np.arange(d) for d in deg]) * 11) % n
+    topo = CSRTopo(edge_index=np.stack([src, dst]))
+    s = GraphSageSampler(topo, [5, 4], dedup="none", return_eid=True)
+    return s.sample(np.arange(28, dtype=np.int64), key=jax.random.PRNGKey(5))
+
+
+def _conv_case(name):
+    from quiver_tpu.models.gcn import GCNConv
+    from quiver_tpu.models import GATConv
+
+    return {
+        "sage": (SAGEConv(7), False),
+        "sage-edge_feat": (SAGEConv(7), True),
+        "gcn": (GCNConv(7), False),
+        "gat": (GATConv(4, heads=2), False),
+    }[name]
+
+
+@pytest.mark.parametrize("name", ["sage", "sage-edge_feat", "gcn", "gat"])
+def test_positional_sources_match_gather(name, rng):
+    """A conv over a POSITIONAL block (slice) gives what it gives with the
+    marker stripped (gather through nbr_local): output exactly, gradients
+    w.r.t. parameters and x to float32 rounding."""
+    from quiver_tpu.sampler import POSITIONAL
+
+    conv, with_edges = _conv_case(name)
+    batch = _ragged_positional_batch()
+    blk = batch.layers[0]
+    assert blk.layout is POSITIONAL
+    m = np.asarray(blk.mask)
+    cnt = m.sum(axis=1)
+    assert (cnt == 0).any() and ((cnt > 0) & (cnt < m.shape[1])).any() \
+        and (cnt == m.shape[1]).any()
+    x = jnp.asarray(rng.normal(size=(batch.n_id.shape[0], 6)), jnp.float32)
+    extra = ()
+    if with_edges:
+        extra = (jnp.asarray(rng.normal(size=m.shape + (3,)), jnp.float32),)
+    params = conv.init(jax.random.PRNGKey(0), x, blk, *extra)
+    w = jnp.asarray(rng.normal(size=conv.apply(params, x, blk,
+                                               *extra).shape), jnp.float32)
+
+    def run(block):
+        def f(p, xx):
+            out = conv.apply(p, xx, block, *extra)
+            return (out * w).sum(), out
+        (_, out), grads = jax.jit(
+            jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(params, x)
+        return out, grads
+
+    out_s, grads_s = run(blk)
+    out_g, grads_g = run(blk._replace(layout=None))
+    np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_g))
+    for a, b in zip(jax.tree.leaves(grads_s), jax.tree.leaves(grads_g)):
+        scale = float(np.abs(np.asarray(b)).max())
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6 * scale)
+
+
+def test_positional_block_rejects_wrong_length_at_trace_time(rng):
+    batch = _ragged_positional_batch()
+    blk = batch.layers[0]
+    t, k = blk.mask.shape
+    conv = SAGEConv(7)
+    x = jnp.zeros((batch.n_id.shape[0], 6), jnp.float32)
+    params = conv.init(jax.random.PRNGKey(0), x, blk)
+    short = jax.ShapeDtypeStruct((x.shape[0] - 1, 6), jnp.float32)
+    with pytest.raises(ValueError) as e:
+        jax.eval_shape(lambda xx: conv.apply(params, xx, blk), short)
+    assert str(t * (1 + k)) in str(e.value)
+    assert str(x.shape[0] - 1) in str(e.value)
+    # the same x is fine for the gather form: nothing is promised there
+    jax.eval_shape(lambda xx: conv.apply(params, xx,
+                                         blk._replace(layout=None)), short)

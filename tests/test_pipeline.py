@@ -131,6 +131,94 @@ def test_fused_step_with_ici_sharded_feature(setup):
     assert np.isfinite(float(loss))
 
 
+def _model_primitives(jaxpr, inside=False):
+    """Names of the primitives traced under the ``qt.model`` scope
+    (forward, and backward as ``transpose(jvp(qt.model))``), through every
+    nested jaxpr."""
+    names = []
+    for eqn in jaxpr.eqns:
+        here = inside or "qt.model" in str(eqn.source_info.name_stack)
+        if here:
+            names.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names += _model_primitives(sub, here)
+    return names
+
+
+def _onehot_loss(logits, labels, mask):
+    # the default loss picks each label's logit with a gather of its own;
+    # this one has none, so any gather under qt.model is a conv's
+    ls = -(jax.nn.one_hot(labels, logits.shape[-1])
+           * jax.nn.log_softmax(logits)).sum(-1)
+    return (ls * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
+@pytest.mark.parametrize("dedup,gathers", [("none", False), ("hop", True)])
+def test_fused_step_model_gathers_follow_the_block_layout(setup, dedup,
+                                                          gathers):
+    """Positional blocks (dedup='none'): the model's value_and_grad holds
+    no gather and no scatter.  Reindexed blocks (dedup='hop'): both are
+    there as before."""
+    from quiver_tpu.pipeline import _fused_train_impl, _tables
+
+    topo, feature, _, _, _ = setup
+    model = GraphSAGE(hidden=32, out_dim=4, num_layers=2, dropout=0.5)
+    sampler = GraphSageSampler(topo, [5, 5], dedup=dedup)
+    impl = _fused_train_impl(
+        sampler, feature,
+        lambda p, x, blocks, train=False, rngs=None: model.apply(
+            p, x, blocks, train=train, rngs=rngs), _onehot_loss)
+    B = 32
+    seeds = jnp.arange(B, dtype=jnp.int32)
+    b0 = sampler.sample(np.arange(B, dtype=np.int64))
+    params = model.init(jax.random.PRNGKey(0), feature[b0.n_id], b0.layers)
+    state = TrainState.create(params, optax.adam(1e-2))
+    jaxpr = jax.make_jaxpr(impl)(
+        _tables(sampler, feature), state, seeds, seeds % 4,
+        jnp.ones((B,), bool), jax.random.PRNGKey(1))
+    names = _model_primitives(jaxpr.jaxpr)
+    assert "dot_general" in names  # the scope was found
+    found = {n for n in names if "gather" in n or "scatter" in n}
+    if gathers:
+        assert "gather" in found and "scatter-add" in found, found
+    else:
+        assert not found, found
+
+
+def test_fused_step_traces_once(setup):
+    """The layout marker is static structure, not a value: a second and a
+    third call of the fused step trace nothing."""
+    from quiver_tpu.sampler import POSITIONAL
+
+    topo, feature, _, model, comm = setup
+    sampler = GraphSageSampler(topo, [5, 5], dedup="none")
+    traces = []
+
+    def apply_fn(p, x, blocks, train=False, rngs=None):
+        traces.append(blocks[0].layout)
+        return model.apply(p, x, blocks, train=train, rngs=rngs)
+
+    tx = optax.adam(1e-2)
+    B = 32
+    b0 = sampler.sample(np.arange(B, dtype=np.int64))
+    params = model.init(jax.random.PRNGKey(0), feature[b0.n_id], b0.layers)
+    state = TrainState.create(params, tx)
+    step = make_fused_train_step(sampler, feature, apply_fn, tx)
+    ones = jnp.ones((B,), bool)
+    rng = np.random.default_rng(4)
+    for i in range(3):
+        seeds = jnp.asarray(rng.integers(0, topo.node_count, B), jnp.int32)
+        labels = jnp.asarray(np.asarray(comm)[np.asarray(seeds)])
+        state, loss = step(state, seeds, labels, ones, jax.random.PRNGKey(i))
+        if i == 0:
+            first = len(traces)
+    assert first >= 1 and traces == [POSITIONAL] * first
+    assert np.isfinite(float(loss))
+
+
 def test_prefetcher_early_abandonment_does_not_leak_worker():
     """Breaking out of a Prefetcher mid-iteration must stop the worker
     thread (pre-fix: it blocked forever on the full bounded queue)."""

@@ -178,3 +178,53 @@ def test_sampling_is_deterministic_per_key(small_graph):
     for l1, l2 in zip(b1.layers, b2.layers):
         np.testing.assert_array_equal(np.asarray(l1.mask),
                                       np.asarray(l2.mask))
+
+
+def _blocks_of(producer, topo):
+    """Layer blocks of one sample from each kind of producer."""
+    import jax.numpy as jnp
+
+    from quiver_tpu.sampler import LayerBlock
+
+    seeds = np.arange(16, dtype=np.int64)
+    key = jax.random.PRNGKey(3)
+    if producer == "overlay":
+        from quiver_tpu.stream import StreamingGraph
+
+        g = StreamingGraph(topo)
+        try:
+            return GraphSageSampler(g, [4, 3]).sample(seeds, key=key).layers
+        finally:
+            g.close()
+    if producer == "dist":
+        from quiver_tpu.dist.sampler import DistGraphSampler
+        from quiver_tpu.utils.mesh import make_mesh
+
+        s = DistGraphSampler(topo, make_mesh(("data",)), sizes=[4, 3])
+        return s.sample(np.tile(seeds, (8, 1)), key=7)[3]
+    if producer == "hand-built":
+        return (LayerBlock(jnp.zeros((4, 2), jnp.int32),
+                           jnp.ones((4, 2), bool), jnp.int32(4)),)
+    kw = dict(mode="CPU") if producer == "cpu" else dict(dedup=producer)
+    return GraphSageSampler(topo, [4, 3], **kw).sample(seeds, key=key).layers
+
+
+@pytest.mark.parametrize("producer,positional", [
+    ("none", True), ("overlay", True), ("hop", False), ("cpu", False),
+    ("dist", False), ("hand-built", False)])
+def test_positional_marker_is_static(small_graph, producer, positional):
+    """Only the two positional pipelines mark their blocks, and the marker
+    crosses the sampler's jit as a Python value: part of the tree's
+    structure, never a leaf."""
+    from quiver_tpu.sampler import POSITIONAL
+
+    for blk in _blocks_of(producer, small_graph):
+        assert blk.layout is (POSITIONAL if positional else None)
+        assert not isinstance(blk.layout, jax.Array)
+        assert len(jax.tree.leaves(blk)) == 3
+        if positional:
+            t, k = blk.mask.shape
+            pos = t + np.arange(t)[:, None] * k + np.arange(k)[None, :]
+            m = np.asarray(blk.mask)
+            np.testing.assert_array_equal(np.asarray(blk.nbr_local),
+                                          np.where(m, pos, 0))
