@@ -1,4 +1,6 @@
-"""Everything a cell is fed, as a function of ``--seed`` alone.
+"""The generators any graph deployment needs (CSR, table, labels, epoch
+order), each a function of ``--seed`` alone; a reference's ``make_data``
+puts them together with what is its model's own (the weights).
 
 The graph generator is ``quiver_tpu/utils/synthetic.synthetic_csr`` copied
 (lognormal degree skew, uniform endpoints), with one change: every seed
@@ -92,31 +94,6 @@ def features(nodes, dim, seed, dtype="float32"):
 def labels(nodes, classes, seed):
     return np.random.default_rng(seed + 2).integers(
         0, classes, nodes).astype(np.int32)
-
-
-def sage_params(dims, seed):
-    """GraphSAGE weights in the tree ``flax`` reads them from
-    (``params/conv<i>/lin_self/{kernel,bias}``, ``lin_nbr/kernel``):
-    kernels normal / sqrt(fan_in), biases small and not zero so that no
-    leaf's gradient is hidden behind a zero."""
-    rng = np.random.default_rng(seed + 3)
-    convs = {}
-    for i, (d_in, d_out) in enumerate(zip(dims[:-1], dims[1:])):
-        def kernel():
-            return (rng.standard_normal((d_in, d_out)) /
-                    np.sqrt(d_in)).astype(np.float32)
-        convs[f"conv{i}"] = {
-            "lin_self": {"kernel": kernel(),
-                         "bias": (0.01 * rng.standard_normal(d_out))
-                         .astype(np.float32)},
-            "lin_nbr": {"kernel": kernel()},
-        }
-    return {"params": convs}
-
-
-def model_dims(cfg):
-    return ([cfg["feature_dim"]] + [cfg["hidden"]] * (cfg["num_layers"] - 1)
-            + [cfg["classes"]])
 
 
 def train_order(cfg, seed, epochs):

@@ -1,35 +1,32 @@
-"""The one file of the benchmark that touches the system under test.
+"""The program ``sage_fused`` (a configuration names it under
+``"program"``).  ``programs/`` is where the benchmark touches the system
+under test.
 
 Builds, from a configuration and the seeded host data, what a user of
 quiver_tpu builds (copied from ``chip_smoke.train_phase``): ``CSRTopo`` ->
 ``GraphSageSampler`` (no mode kwargs, so the library picks what it picks
 on this device) -> ``Feature`` all in HBM ->
-``pipeline.make_fused_train_step``.  Everything else under ``cellbench/``
-imports nothing of ``quiver_tpu``.
+``pipeline.make_fused_train_step``, all on one device: the first of those
+the cell was given.  Everything else under ``cellbench/`` imports nothing
+of ``quiver_tpu`` (but ``scope_split.py``, a table of names, and
+``compile_cache.py``, a path).
 """
 
 import numpy as np
 
 
-def cache_dir():
-    """The compile cache goes where the program's own
-    ``utils/compile_cache`` puts it: ``$JAX_COMPILATION_CACHE_DIR`` or
-    ``<checkout>/.jax_cache``, a fixed path inside the checkout."""
-    from quiver_tpu.utils import compile_cache
-
-    return compile_cache.enable()
-
-
 class Program:
     """Graph, features and model of one configuration on the device."""
 
-    def __init__(self, cfg, data, control=False, fault=None):
+    def __init__(self, cfg, data, devices, control=False, fault=None):
         import jax
         import jax.numpy as jnp
 
         from quiver_tpu import CSRTopo, Feature, GraphSageSampler
         from quiver_tpu.models import GraphSAGE
 
+        # a one-device program: ``devices[0]`` is JAX's default device,
+        # where the library puts what it is not told to put elsewhere
         self.cfg = cfg
         # ``fault``: break the timed path underneath the harness, for
         # cellbench/tests/test_faults.py: "stale_state", "half_batch".

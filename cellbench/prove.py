@@ -8,12 +8,13 @@ cache after the first): for each seed a short window of the cell, then
                     the configuration states: the lower readings;
   * ``stand_in``  - the reference one precision lower, in the program's
                     place: the control;
-  * ``faults``    - (training) the reference with one fault planted, in
-                    the program's place;
+  * ``faults``    - the reference with one fault planted, in the
+                    program's place, for each fault the cell's kind
+                    names (``FAULTS`` of ``kinds/<kind>.py``);
 
 and, with ``--control-seeds n``, for the first n seeds the program itself
-with its own lower-precision path switched on
-(``GraphSAGE(dtype=bfloat16)``) against the same reference.  Not part of a
+with its own lower-precision path switched on (``Program(control=True)``)
+against the same reference.  Not part of a
 benchmark run; PERF.md quotes what it printed.
 """
 
@@ -51,11 +52,12 @@ def main(argv=None):
                    "stand_in": out["numbers_fn"](
                        out["replayed"], stated,
                        stand_in=cfg["precision"]["control"])}
-            if traffic["kind"] == "train":
+            faults = run.load_named("kinds", traffic["kind"]).FAULTS
+            if faults:
                 row["faults"] = {
                     fault: out["numbers_fn"](out["replayed"], stated,
                                              fault=fault)
-                    for fault in ("half_batch", "stale_state")}
+                    for fault in faults}
             del out
             gc.collect()
             if i < args.control_seeds:

@@ -1,5 +1,7 @@
-"""The plain reference: host CSR, host table, and GraphSAGE with its loss,
-gradients and Adam in straightforward ``jax.numpy``.
+"""The plain reference ``sage`` (a configuration names it under
+``"reference"``): the seeded data of a GraphSAGE deployment (host CSR, host
+table, labels, weights), and GraphSAGE with its loss, gradients and Adam in
+straightforward ``jax.numpy``.
 
 It imports nothing of ``quiver_tpu`` and is handed nothing the program has
 made except the one thing that cannot be predicted: which neighbours a
@@ -24,6 +26,48 @@ matrix product does with its float32 operands:
 import functools
 
 import numpy as np
+
+import datagen
+
+
+# --------------------------------------------------- the data, from the seed
+def model_dims(cfg):
+    return ([cfg["feature_dim"]] + [cfg["hidden"]] * (cfg["num_layers"] - 1)
+            + [cfg["classes"]])
+
+
+def sage_params(dims, seed):
+    """GraphSAGE weights in the tree ``flax`` reads them from
+    (``params/conv<i>/lin_self/{kernel,bias}``, ``lin_nbr/kernel``):
+    kernels normal / sqrt(fan_in), biases small and not zero so that no
+    leaf's gradient is hidden behind a zero."""
+    rng = np.random.default_rng(seed + 3)
+    convs = {}
+    for i, (d_in, d_out) in enumerate(zip(dims[:-1], dims[1:])):
+        def kernel():
+            return (rng.standard_normal((d_in, d_out)) /
+                    np.sqrt(d_in)).astype(np.float32)
+        convs[f"conv{i}"] = {
+            "lin_self": {"kernel": kernel(),
+                         "bias": (0.01 * rng.standard_normal(d_out))
+                         .astype(np.float32)},
+            "lin_nbr": {"kernel": kernel()},
+        }
+    return {"params": convs}
+
+
+def make_data(cfg, seed):
+    """Graph, table, labels and weights, all from the seed.  Runs on the
+    host while JAX reaches the chip, so nothing at this file's top level
+    imports jax."""
+    indptr, indices = datagen.csr(cfg["nodes"], cfg["edges"], seed)
+    return {
+        "indptr": indptr, "indices": indices,
+        "features": datagen.features(cfg["nodes"], cfg["feature_dim"], seed,
+                                     cfg["feature_dtype"]),
+        "labels": datagen.labels(cfg["nodes"], cfg["classes"], seed),
+        "params": sage_params(model_dims(cfg), seed),
+    }
 
 
 # ------------------------------------------------------------ the sampler

@@ -6,10 +6,14 @@ data from the seed, build the program, warm it up (all of that is
 ``setup_s``), measure for ``--seconds``, compare what the timed path
 produced with the plain reference, and print one JSON line.
 
-Which cells, configurations, traffic mixes and per-layer metrics exist is
-data: ``BENCHMARK.json`` names them, ``cellbench/configs/<file>``,
-``cellbench/traffic/<mix>.json`` and ``cellbench/metrics/<name>.py`` hold
-them, and this file finds them by name.
+Everything that tells one deployment from another is a file that this one
+finds by a name the cell's data gives (``load_named``): ``BENCHMARK.json``
+names the cell's configuration file and its traffic mix
+(``traffic/<mix>.json``) and the per-layer metrics (``metrics/<name>.py``);
+the configuration names its program (``programs/<name>.py``), its plain
+reference with the seeded data (``references/<name>.py``) and its work
+model (``work/<name>.py``); the traffic file names its kind
+(``kinds/<kind>.py``).  PERF.md section 4 says what each has to expose.
 
 It needs a TPU and exits 2 without one, before building anything.
 ``--rehearse`` runs the same code at a toy size on whatever JAX finds, for
@@ -25,6 +29,7 @@ import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
@@ -42,6 +47,31 @@ def load_json(path):
         return json.load(f)
 
 
+def find_file(folder, name, ext):
+    """``cellbench/<folder>/<name><ext>``; a name with no file exits with
+    the names that have one."""
+    path = os.path.join(HERE, folder, name + ext)
+    if not os.path.isfile(path):
+        there = sorted(f[:-len(ext)] for f in os.listdir(
+            os.path.join(HERE, folder)) if f.endswith(ext))
+        raise SystemExit(f"no cellbench/{folder}/{name}{ext}; there are "
+                         f"{there}")
+    return path
+
+
+def load_named(folder, name):
+    """The module ``cellbench/<folder>/<name>.py``: a program, a kind, a
+    reference, a work model or a per-layer reader.  Once per process."""
+    modname = "cb_" + folder + "_" + re.sub(r"\W", "_", name)
+    if modname not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            modname, find_file(folder, name, ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[modname] = mod
+    return sys.modules[modname]
+
+
 def find_cell(name):
     bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -51,22 +81,8 @@ def find_cell(name):
     cell = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     cfg = load_json(os.path.join(ROOT, conf["file"]))
-    traffic = load_json(os.path.join(HERE, "traffic",
-                                     cell["traffic"] + ".json"))
+    traffic = load_json(find_file("traffic", cell["traffic"], ".json"))
     return bench, cell, cfg, traffic
-
-
-def make_data(cfg, seed):
-    import datagen
-
-    indptr, indices = datagen.csr(cfg["nodes"], cfg["edges"], seed)
-    return {
-        "indptr": indptr, "indices": indices,
-        "features": datagen.features(cfg["nodes"], cfg["feature_dim"], seed,
-                                     cfg["feature_dtype"]),
-        "labels": datagen.labels(cfg["nodes"], cfg["classes"], seed),
-        "params": datagen.sage_params(datagen.model_dims(cfg), seed),
-    }
 
 
 def limits_of(cfg, cell):
@@ -92,12 +108,7 @@ def read_layer_metrics(bench, cell, ctx):
     for m in bench["per_layer"]:
         if cell["name"] not in m.get("workloads", [cell["name"]]):
             continue
-        path = os.path.join(HERE, "metrics", m["name"] + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "cb_metric_" + m["name"].replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        value = mod.read(ctx)
+        value = load_named("metrics", m["name"]).read(ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
@@ -107,6 +118,20 @@ def rehearsal_size(cfg, traffic):
     """Cut a cell to the toy size its files give under ``rehearsal``."""
     cfg.update(cfg["rehearsal"])
     traffic.update(traffic["rehearsal"])
+
+
+def parts_of(cfg, traffic):
+    """The four files that make the cell's deployment, by the names its
+    data gives."""
+    return {"program": load_named("programs", cfg["program"]),
+            "reference": load_named("references", cfg["reference"]),
+            "work": load_named("work", cfg["work"]),
+            "kind": load_named("kinds", traffic["kind"])}
+
+
+def make_data(cfg, seed):
+    """The cell's data, all from the seed, as its reference makes it."""
+    return load_named("references", cfg["reference"]).make_data(cfg, seed)
 
 
 def run_cell(cell, cfg, traffic, seed, seconds, trace, control=False,
@@ -119,21 +144,25 @@ def run_cell(cell, cfg, traffic, seed, seconds, trace, control=False,
     import jax
 
     import cells
-    import program
+    import compile_cache
     import trace_reduce
     from watch import CompileWatch
 
-    devices = jax.devices()
+    found = jax.devices()
+    devices = found[:cell["chips"]]
     dev = devices[0]
     watch = CompileWatch()
-    cache = program.cache_dir()
-    log(f"device {dev.platform} {dev.device_kind} x {len(devices)}; "
-        f"compile cache at {cache}")
+    cache = compile_cache.cache_dir()
+    log(f"device {dev.platform} {dev.device_kind} x {len(found)}, the cell "
+        f"takes {len(devices)}; compile cache at {cache}")
+    parts = parts_of(cfg, traffic)
+    ref = parts["reference"]
     t = time.perf_counter()
-    data = make_data(cfg, seed) if data is None else data.result()
+    data = ref.make_data(cfg, seed) if data is None else data.result()
     log(f"data ready after {time.perf_counter() - t:.1f} s more")
     t = time.perf_counter()
-    prog = program.Program(cfg, data, control=control, fault=fault)
+    prog = parts["program"].Program(cfg, data, devices, control=control,
+                                    fault=fault)
     log(f"program built in {time.perf_counter() - t:.1f} s: "
         f"{prog.resolved()}")
     trace_dir = os.path.join(HERE, ".trace")
@@ -141,19 +170,25 @@ def run_cell(cell, cfg, traffic, seed, seconds, trace, control=False,
         shutil.rmtree(trace_dir, ignore_errors=True)
     tracer = cells.Tracer(trace, trace_dir,
                           min(seconds, traffic["traced_seconds"]))
-    end_to_end, facts, (replay, numbers) = cells.KINDS[traffic["kind"]](
-        prog, cfg, traffic, data, seed, seconds, tracer, watch)
+    end_to_end, facts, (replay, numbers) = parts["kind"].run(
+        prog, ref, cfg, traffic, data, seed, seconds, tracer, watch)
     end_to_end["setup_s"] = facts["t_setup_end"] - T_START
     log(f"window closed: {watch.compiles} programs built in all, "
         f"{watch.compile_s:.1f} s in the compiler, cache hits "
         f"{watch.cache_hits} misses {watch.cache_misses}; "
         f"{facts['window_compiles']} inside the window")
 
-    stats = dev.memory_stats() or {}
-    log(f"memory_stats: {stats}")
+    # the peak on the fullest of the cell's devices, and each beside it
+    peak_bytes = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        log(f"memory_stats of device {d.id}: {stats}")
+        peak_bytes.append(stats.get("peak_bytes_in_use"))
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices),
-              "memory_peak_bytes": stats.get("peak_bytes_in_use")}
+              "count": len(found), "chips": len(devices),
+              "memory_peak_bytes": max(
+                  (p for p in peak_bytes if p is not None), default=None),
+              "memory_peak_bytes_per_device": peak_bytes}
     red = None
     if trace:
         red = trace_reduce.reduce_trace(trace_dir)
@@ -164,10 +199,16 @@ def run_cell(cell, cfg, traffic, seed, seconds, trace, control=False,
         if red is not None:
             device["busy_s"] = red["busy_s"]
             device["window_s"] = red["window_s"]
+            device["busy_s_per_device"] = red["busy_s_per_device"]
+            device["idle_gaps_of_device"] = red["idle_gaps_device"]
             log("programs launched in the traced window: " + ", ".join(
                 f"{name} x {fam['launches']} ({fam['seconds']:.3f} s)"
                 for name, fam in sorted(red["modules"].items(),
                                         key=lambda kv: -kv[1]["seconds"])))
+            log("host spans in the traced window: " + (", ".join(
+                f"{name} x {sp['count']} ({sp['seconds']:.3f} s, "
+                f"{sp['idle_overlap_s']:.3f} s of it device idle)"
+                for name, sp in sorted(red["host_spans"].items())) or "none"))
 
     t = time.perf_counter()
     replayed = replay()
@@ -196,6 +237,7 @@ def main(argv=None):
     bench, cell, cfg, traffic = find_cell(args.workload)
     if args.rehearse:
         rehearsal_size(cfg, traffic)
+    parts = parts_of(cfg, traffic)    # a name with no file exits here
 
     # the data is made on the host while JAX reaches the chip; a daemon
     # thread, so that a refusal below exits at once
@@ -212,19 +254,17 @@ def main(argv=None):
     import jax
 
     devices = jax.devices()
-    if not args.rehearse:
-        if devices[0].platform != "tpu":
-            log(f"cellbench: needs a TPU, JAX found "
-                f"{devices[0].platform!r}; nothing was built and there is "
-                f"no result")
-            return 2
-        if len(devices) < cell["chips"]:
-            log(f"cellbench: {cell['name']} needs {cell['chips']} chips, "
-                f"JAX found {len(devices)}")
-            return 2
+    if not args.rehearse and devices[0].platform != "tpu":
+        log(f"cellbench: needs a TPU, JAX found {devices[0].platform!r}; "
+            f"nothing was built and there is no result")
+        return 2
+    if len(devices) < cell["chips"]:
+        log(f"cellbench: {cell['name']} needs {cell['chips']} chips, JAX "
+            f"found {len(devices)}")
+        return 2
 
+    import peaks
     import trace_reduce
-    import workmodel
 
     out = run_cell(cell, cfg, traffic, args.seed, args.seconds, args.trace,
                    keep_trace=args.keep_trace, data=data)
@@ -237,8 +277,8 @@ def main(argv=None):
     else:
         if args.trace:
             ctx = {"facts": facts, "trace": out["trace"], "cfg": cfg,
-                   "traffic": traffic, "work": workmodel,
-                   "peak": workmodel.peaks(out["device"]["kind"]),
+                   "traffic": traffic, "work": parts["work"],
+                   "peak": peaks.peaks(out["device"]["kind"]),
                    "end_to_end": out["end_to_end"]}
             metrics = read_layer_metrics(bench, cell, ctx)
         else:

@@ -14,7 +14,7 @@ is the first ``qt.<layer>[.<part>]`` of its ``op_name``, and it ran in the
 backward pass when the ``op_name`` contains ``transpose(`` (JAX's name for
 the transposed half of ``value_and_grad``).
 
-After ``program.py`` this is the second file of ``cellbench/`` that
+After ``programs/`` this is the second place under ``cellbench/`` that
 imports from ``quiver_tpu``.  What it reads is a table of names, not code
 under test: every second it sums is the trace's.  On a program without
 the table (a parent commit of the PR that brought it) every reader over

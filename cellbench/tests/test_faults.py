@@ -1,8 +1,9 @@
 """The harness has to SEE a broken timed path.  Each test drives a whole
-run at the toy size with one fault planted underneath (in
-``program.Program``, behind the harness) and sees ``correct`` come out
+run at the toy size with one fault planted underneath (in the cell's
+``programs/<name>.py``, behind the harness) and sees ``correct`` come out
 false under the cell's own limits; the sound run beside it comes out
-true.  The faults a training cell can have: a step that returns its state
+true.  Which faults a cell can have its kind says (``FAULTS`` of
+``kinds/<kind>.py``).  Those a training cell can have: a step that returns its state
 unchanged; half of the batch left out, the mean taken over the rest.  (No
 cell spans chips, so there is no exchange to leave out; no cell serves, so
 there is no answer to alter.)
@@ -15,7 +16,16 @@ import pytest
 
 from conftest import cells, rehearsal_cell
 
-TRAIN = cells()
+CELLS = cells()
+
+
+def faults():
+    """Every cell beside each fault that its kind names."""
+    import run
+
+    return [(name, fault) for name in CELLS
+            for fault in run.load_named(
+                "kinds", run.find_cell(name)[3]["kind"]).FAULTS]
 
 
 def drive(name, fault=None, seconds=1.0):
@@ -28,22 +38,24 @@ def drive(name, fault=None, seconds=1.0):
     return out, limits, cfg
 
 
-@pytest.mark.parametrize("cell", TRAIN)
+@pytest.mark.parametrize("cell", CELLS)
 def test_sound_run_is_correct_and_control_is_not(cell):
     import run
 
     out, limits, cfg = drive(cell)
     compared, correct = run.compare(out["numbers"], limits)
     assert correct, compared
-    control = out["numbers_fn"](out["replayed"], "bf16_operands",
+    # against the precision the cell states at its own size, the narrower
+    # gap: on the chip the reference runs at that one
+    stated = run.find_cell(cell)[2]["precision"]["matmul"]
+    control = out["numbers_fn"](out["replayed"], stated,
                                 stand_in=cfg["precision"]["control"])
     compared, correct = run.compare(control, limits)
     assert not correct, compared
 
 
-@pytest.mark.parametrize("cell", TRAIN)
-@pytest.mark.parametrize("fault", ["stale_state", "half_batch"])
-def test_broken_training_step_is_not_correct(cell, fault):
+@pytest.mark.parametrize("cell,fault", faults())
+def test_broken_timed_path_is_not_correct(cell, fault):
     import run
 
     out, limits, _ = drive(cell, fault=fault)

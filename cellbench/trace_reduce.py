@@ -8,10 +8,15 @@ device planes are named ``/device:TPU:<n>``; the line ``XLA Ops`` holds one
 event per operation that ran on the core, and ``XLA Modules`` one event per
 launch of a compiled program (named ``jit_<function>(<fingerprint>)``).
 Host threads are lines of the plane ``/host:CPU``; the benchmark's own
-spans (``jax.profiler.TraceAnnotation`` with names that start ``cb.``) are
-events there, on the same clock as the device's.
+spans (``jax.profiler.TraceAnnotation`` with names that start ``cb.``) and
+the program's (``quiver_tpu.telemetry.span``: names that start ``qt.``)
+are events there, on the same clock as the device's.  The ``cb.`` spans
+alone decide the window's ends and name the idle gaps; both kinds are
+summed by name under ``host_spans``, for a per-layer reader to turn a cold
+fetch or a server's device thread into a metric.
 """
 
+import bisect
 import glob
 import os
 import re
@@ -21,6 +26,7 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "cb."
+HOST_PREFIXES = (SPAN_PREFIX, "qt.")   # the host events that are kept
 ATTRIBUTED_GAPS = 200      # the longest idle gaps are named, the rest summed
 
 
@@ -61,6 +67,24 @@ def gaps(intervals, lo, hi):
     return [(s, e) for s, e in out if e > s]
 
 
+def overlap_with(stretches):
+    """``f(s, e)``: how much of ``[s, e]`` lies inside ``stretches``,
+    which are disjoint and in order of time (as ``gaps`` gives them)."""
+    starts = [s for s, _ in stretches]
+    before = [0.0]                  # before[k]: length of the first k
+    for s, e in stretches:
+        before.append(before[-1] + (e - s))
+
+    def upto(x):
+        i = bisect.bisect_right(starts, x)
+        if i == 0:
+            return 0.0
+        s, e = stretches[i - 1]
+        return before[i - 1] + min(x, e) - s
+
+    return lambda s, e: upto(e) - upto(s)
+
+
 def module_family(name):
     """``jit_step(1234567)`` -> ``jit_step``: one program under whatever
     fingerprint this build gave it."""
@@ -73,12 +97,16 @@ def reduce_planes(planes, window=None):
     to cut to; by default from the first device event to the last.
 
     Returns a dict: ``devices`` (count), ``window_s``, ``busy_s`` (mean over
-    devices of the union of operation intervals), ``ops`` (name -> seconds,
-    mean over devices), ``modules`` (family -> {"launches", "seconds"},
-    summed over devices), ``idle_gaps`` ([(what, seconds)], longest
-    first, of device 0, named by the ``cb.`` span that covers most of each).
+    devices of the union of operation intervals), ``busy_s_per_device``
+    (device number -> that union), ``ops`` (name -> seconds, mean over
+    devices), ``modules`` (family -> {"launches", "seconds"}, summed over
+    devices), ``idle_gaps`` ([(what, seconds)], longest first, of the
+    device ``idle_gaps_device``, the lowest-numbered, named by the ``cb.``
+    span that covers most of each), ``host_spans`` (name of a ``cb.`` or
+    ``qt.`` host span -> {"count", "seconds", "idle_overlap_s"} inside the
+    window, the last being the seconds in which that device ran nothing).
     """
-    dev_ops, dev_mods, spans = {}, {}, []
+    dev_ops, dev_mods, host = {}, {}, []
     for pname, lines in planes:
         m = DEVICE_PLANE.match(pname)
         for lname, events in lines:
@@ -87,8 +115,9 @@ def reduce_planes(planes, window=None):
             elif m and lname == MODULES_LINE:
                 dev_mods.setdefault(int(m.group(1)), []).extend(events)
             elif pname == HOST_PLANE:
-                spans.extend(e for e in events
-                             if e[0].startswith(SPAN_PREFIX))
+                host.extend(e for e in events
+                            if e[0].startswith(HOST_PREFIXES))
+    spans = [e for e in host if e[0].startswith(SPAN_PREFIX)]
     if not dev_ops:
         return None
     if window is None:
@@ -110,24 +139,23 @@ def reduce_planes(planes, window=None):
                 yield name, s, e
 
     n = len(dev_ops)
-    busy, ops, modules = 0.0, {}, {}
-    first_intervals = None
+    busy, ops, modules = {}, {}, {}
+    gap_device = min(dev_ops)
     for dev in sorted(dev_ops):
         intervals = []
         for name, s, e in cut(dev_ops[dev]):
             intervals.append((s, e))
             ops[name] = ops.get(name, 0.0) + (e - s) * 1e-9 / n
-        busy += union_seconds(intervals) * 1e-9 / n
-        if first_intervals is None:
-            first_intervals = intervals
+        busy[dev] = union_seconds(intervals) * 1e-9
+        if dev == gap_device:
+            in_time = gaps(intervals, lo, hi)
         for name, s, e in cut(dev_mods.get(dev, [])):
             fam = modules.setdefault(module_family(name),
                                      {"launches": 0, "seconds": 0.0})
             fam["launches"] += 1
             fam["seconds"] += (e - s) * 1e-9
     idle = []
-    every_gap = sorted(gaps(first_intervals, lo, hi),
-                       key=lambda g: g[0] - g[1])
+    every_gap = sorted(in_time, key=lambda g: g[0] - g[1])
     rest = sum(e - s for s, e in every_gap[ATTRIBUTED_GAPS:])
     for s, e in every_gap[:ATTRIBUTED_GAPS]:
         best, cover = "unattributed", 0.0
@@ -138,13 +166,22 @@ def reduce_planes(planes, window=None):
         idle.append((best, (e - s) * 1e-9))
     if rest:
         idle.append(("shorter_gaps_together", rest * 1e-9))
-    return {"devices": n, "window_s": (hi - lo) * 1e-9, "busy_s": busy,
-            "ops": ops, "modules": modules, "idle_gaps": idle}
+    idle_in, host_spans = overlap_with(in_time), {}
+    for name, s, e in cut(host):
+        sp = host_spans.setdefault(
+            name, {"count": 0, "seconds": 0.0, "idle_overlap_s": 0.0})
+        sp["count"] += 1
+        sp["seconds"] += (e - s) * 1e-9
+        sp["idle_overlap_s"] += idle_in(s, e) * 1e-9
+    return {"devices": n, "window_s": (hi - lo) * 1e-9,
+            "busy_s": sum(busy.values()) / n, "busy_s_per_device": busy,
+            "ops": ops, "modules": modules, "idle_gaps": idle,
+            "idle_gaps_device": gap_device, "host_spans": host_spans}
 
 
 def read_xplane(path):
     """The planes of one ``.xplane.pb`` in ``reduce_planes``' form.  Only
-    device planes and the host's ``cb.`` spans are kept."""
+    device planes and the host's ``cb.`` and ``qt.`` spans are kept."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(path)
@@ -159,7 +196,7 @@ def read_xplane(path):
                 continue
             events = [(e.name, float(e.start_ns), float(e.duration_ns))
                       for e in line.events
-                      if is_dev or e.name.startswith(SPAN_PREFIX)]
+                      if is_dev or e.name.startswith(HOST_PREFIXES)]
             if events:
                 lines.append((line.name, events))
         planes.append((plane.name, lines))

@@ -1,21 +1,13 @@
-"""What one batch NEEDS, from shapes alone: the FLOPs of GraphSAGE's
+"""The work model ``sage`` (a configuration names it under ``"work"``).
+What one batch NEEDS, from shapes alone: the FLOPs of GraphSAGE's
 forward and backward passes, and the least bytes a sample + gather + conv
 step has to move.  Never what an implementation happens to move, so a PR
 that replaces a kernel is read on the same yardstick."""
 
-import json
-import os
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def peaks(device_kind):
-    with open(os.path.join(HERE, "peaks.json")) as f:
-        table = json.load(f)["devices"]
-    if device_kind not in table:
-        raise KeyError(f"no peaks for device kind {device_kind!r}: add it "
-                       f"to cellbench/peaks.json with its source")
-    return table[device_kind]
+def model_dims(cfg):
+    return ([cfg["feature_dim"]] + [cfg["hidden"]] * (cfg["num_layers"] - 1)
+            + [cfg["classes"]])
 
 
 def frontier(batch, fanout):
@@ -27,12 +19,10 @@ def frontier(batch, fanout):
     return t
 
 
-def sage_flops(batch, cfg, backward):
+def step_flops(batch, cfg, backward):
     """FLOPs of GraphSAGE on one batch: two products per layer and the
     neighbour sums; backward adds the weight gradients and, past the first
     layer (the features are not trained), the input gradients."""
-    from datagen import model_dims
-
     dims = model_dims(cfg)
     t = frontier(batch, cfg["fanout"])
     n = len(cfg["fanout"])
@@ -51,8 +41,6 @@ def step_bytes(batch, cfg, peak, backward):
     """Least HBM bytes of one sample + gather + conv step: one transaction
     per draw, every gathered row once, every layer's output written and
     read once per pass, the weights (and Adam's state) once."""
-    from datagen import model_dims
-
     dims = model_dims(cfg)
     t = frontier(batch, cfg["fanout"])
     n = len(cfg["fanout"])
@@ -69,6 +57,6 @@ def step_bytes(batch, cfg, peak, backward):
 def least_step_seconds(batch, cfg, peak, backward):
     """The roofline: the larger of FLOPs over peak FLOP/s and bytes over
     peak bytes/s.  Returns ``(seconds, which_bound)``."""
-    f = sage_flops(batch, cfg, backward) / peak["flops_per_s"]
+    f = step_flops(batch, cfg, backward) / peak["flops_per_s"]
     b = step_bytes(batch, cfg, peak, backward) / peak["hbm_bytes_per_s"]
     return max(f, b), ("flops" if f >= b else "bytes")
