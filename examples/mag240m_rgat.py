@@ -1,9 +1,15 @@
 """Heterogeneous R-GAT training — mag240m-class schema.
 
-TPU-native counterpart of the reference's mag240m benchmark
-(``/root/reference/benchmarks/ogbn-mag240m/``): paper/author/institution
-graph, hetero neighbor sampling, R-GAT.  Synthetic schema-compatible data
-unless the real dataset is wired in.
+Of the two R-GAT forms in ``quiver_tpu/models/rgat.py`` this shows the
+repo's OWN one: ``HeteroGraphSageSampler`` (per-relation blocks and
+fanouts, a feature store per node type) -> ``models.RGAT``, the
+two-stage loop.  The model the reference's mag240m benchmark
+(``/root/reference/benchmarks/ogbn-mag240m/``, OGB-LSC's ``rgnn.py
+--model rgat``) actually trains is the HOMOGENISED form, ``models.RGNN``:
+one id space through ``GraphSageSampler`` -> ``Feature`` ->
+``pipeline.make_fused_train_step``; ``cellbench/programs/rgat_fused.py``
+builds it and ``tests/test_rgnn.py`` holds it to its plain reference.
+Synthetic schema-compatible data unless the real dataset is wired in.
 """
 
 import os

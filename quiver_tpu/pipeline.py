@@ -21,7 +21,7 @@ import optax
 
 from .feature import Feature, _lookup_tables
 from .sampler import GraphSageSampler, run_pipeline
-from .parallel.train import TrainState
+from .parallel.train import Frontier, TrainState, call_model
 from .telemetry.device_scopes import MODEL, OPTIMIZER, register_program
 
 __all__ = ["make_fused_train_step", "make_fused_eval_fn"]
@@ -40,7 +40,13 @@ def make_fused_train_step(sampler: GraphSageSampler, feature: Feature,
                           tx: optax.GradientTransformation,
                           loss_fn: Optional[Callable] = None):
     """Build ``(state, seeds, labels, label_mask, key) -> (state, loss)``
-    with sampling and feature gather inside the jit."""
+    with sampling and feature gather inside the jit.
+
+    ``apply_fn(params, x, blocks, train=, rngs=)`` is a model over rows
+    and blocks alone.  One that also has a ``frontier`` parameter (a typed
+    model, a model with batch statistics) is handed the sampled frontier
+    and ``state.model_state`` and returns ``(logits, model_state)``:
+    :func:`quiver_tpu.parallel.train.call_model`."""
     impl = _fused_train_impl(sampler, feature, apply_fn, loss_fn)
     tables = _tables(sampler, feature)
     jitted = jax.jit(impl, donate_argnums=(1,))
@@ -104,16 +110,18 @@ def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
 
         def compute(params):
             with jax.named_scope(MODEL):
-                logits = apply_fn(params, x, blocks, train=True,
-                                  rngs={"dropout": kd})
-                return loss_fn(logits, labels, label_mask)
+                logits, model_state = call_model(
+                    apply_fn, params, x, blocks, Frontier(n_id, n_mask),
+                    state.model_state, True, {"dropout": kd})
+                return loss_fn(logits, labels, label_mask), model_state
 
-        loss, grads = jax.value_and_grad(compute)(state.params)
+        (loss, model_state), grads = jax.value_and_grad(
+            compute, has_aux=True)(state.params)
         with jax.named_scope(OPTIMIZER):
             updates, opt_state = state.tx.update(grads, state.opt_state,
                                                  state.params)
             params = optax.apply_updates(state.params, updates)
-        return TrainState(params, opt_state, state.tx), loss
+        return TrainState(params, opt_state, state.tx, model_state), loss
 
     return qt_fused_train_step
 
@@ -159,7 +167,9 @@ def make_scan_epoch(sampler: GraphSageSampler, feature: Feature,
 
 def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
                        apply_fn: Callable):
-    """``(params, seeds, key) -> logits`` with sampling inside the jit."""
+    """``(params, seeds, key, model_state=None) -> logits`` with sampling
+    inside the jit; ``model_state`` is for an ``apply_fn`` that asks for
+    the frontier (``call_model``) and is read, never written."""
     _check(feature)
     tables = _tables(sampler, feature)
     sizes = tuple(sampler.sizes)
@@ -169,7 +179,7 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
     caps = tuple(sampler.frontier_caps)
 
     @jax.jit
-    def qt_fused_eval(tables, params, seeds, key):
+    def qt_fused_eval(tables, params, seeds, key, model_state):
         indptr, indices, feat_tables = tables
         n_id, n_mask, num, blocks, _ = run_pipeline(
             dedup, indptr, indices, seeds, key, sizes, caps, gather_mode=gm,
@@ -177,13 +187,17 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
         )
         x = _lookup_tables(feat_tables, n_id)
         with jax.named_scope(MODEL):
-            return apply_fn(params, x, blocks, train=False, rngs=None)
+            logits, _ = call_model(apply_fn, params, x, blocks,
+                                   Frontier(n_id, n_mask), model_state,
+                                   False, None)
+            return logits
 
     registered = False
 
-    def eval_fn(params, seeds, key):
+    def eval_fn(params, seeds, key, model_state=None):
         nonlocal registered
-        args = (tables, params, seeds, key)
+        args = (tables, params, seeds, key,
+                {} if model_state is None else model_state)
         if not registered:
             registered = True
             register_program(qt_fused_eval, args)

@@ -42,14 +42,20 @@ import time
 from collections import Counter
 from typing import Dict, Optional, Tuple
 
-__all__ = ["PREFIX", "SAMPLER", "FEATURE_GATHER", "MODEL", "OPTIMIZER",
-           "sampler_hop", "register_program", "device_scopes",
-           "parse_hlo_scopes", "instruction_key", "scoped"]
+__all__ = ["PREFIX", "SAMPLER", "FEATURE_GATHER", "MODEL", "MODEL_PROJECT",
+           "MODEL_ATTENTION", "OPTIMIZER", "sampler_hop",
+           "register_program", "device_scopes", "parse_hlo_scopes",
+           "instruction_key", "scoped"]
 
 PREFIX = "qt."
 SAMPLER = PREFIX + "sampler"
 FEATURE_GATHER = PREFIX + "feature.gather"
 MODEL = PREFIX + "model"
+# parts of a typed model (``models.rgat.RGNN``), nested under ``qt.model``:
+# the per-relation projections of sources and targets with the skip, and
+# the scores, the relation's softmax and the weighted sum
+MODEL_PROJECT = MODEL + ".project"
+MODEL_ATTENTION = MODEL + ".attention"
 OPTIMIZER = PREFIX + "optimizer"
 
 
@@ -66,6 +72,37 @@ _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _CALLS = re.compile(r"[, ]calls=(%?[\w.\-]+)")
 _COMMENT = re.compile(r"/\*.*?\*/")
+_OPERAND = re.compile(r"%[\w.\-]+")
+# the TPU compiler turns ``lax.ragged_dot`` into kernels of its own and
+# gives them these names in place of the traced ``op_name``
+_RENAMED = "ragged-dot-"
+
+
+def _split(line: str) -> Optional[Tuple[str, str, str]]:
+    """``(name, result shape, what follows it)`` of an HLO instruction
+    line; None for a line that is no instruction."""
+    m = _NAME.match(line)
+    if m is None:
+        return None
+    rest = line[m.end():]
+    if rest.startswith("("):        # a tuple: to its closing parenthesis
+        end = _closing(rest)
+        if end is None:
+            return None
+        shape = rest[:end + 1]
+    else:
+        shape = rest.split(" ", 1)[0]
+    return m.group(1), shape, rest[len(shape):]
+
+
+def _closing(text: str) -> Optional[int]:
+    """Where the parenthesis that ``text`` opens with closes."""
+    depth = 0
+    for i, ch in enumerate(text):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return i
+    return None
 
 
 def instruction_key(line: str) -> Optional[str]:
@@ -73,22 +110,19 @@ def instruction_key(line: str) -> Optional[str]:
     opcode, the part a line of ``as_text()`` and the name of a trace event
     have in common (the text prints operands bare, the trace with their
     shapes).  None for a line that is no instruction."""
-    m = _NAME.match(line)
-    if m is None:
+    parts = _split(line)
+    if parts is None:
         return None
-    rest = line[m.end():]
-    if rest.startswith("("):        # a tuple: to its closing parenthesis
-        depth = 0
-        for i, ch in enumerate(rest):
-            depth += (ch == "(") - (ch == ")")
-            if depth == 0:
-                break
-        else:
-            return None
-        shape = rest[:i + 1]
-    else:
-        shape = rest.split(" ", 1)[0]
-    return f"{m.group(1)} = {_COMMENT.sub('', shape)}"
+    return f"{parts[0]} = {_COMMENT.sub('', parts[1])}"
+
+
+def _operands(line: str) -> list:
+    """The names an instruction line reads: what stands between its
+    opcode's parentheses (the text prints operands bare)."""
+    after = _split(line)[2]
+    start = after.find("(")
+    end = _closing(after[start:]) if start >= 0 else None
+    return [] if end is None else _OPERAND.findall(after[start:start + end])
 
 
 def parse_hlo_scopes(text: str) -> Tuple[Optional[str], Dict[str, str]]:
@@ -96,10 +130,13 @@ def parse_hlo_scopes(text: str) -> Tuple[Optional[str], Dict[str, str]]:
     text.  A fusion takes the ``op_name`` of its own metadata and, where
     it has none, the commonest among the instructions of the computation
     it calls; instructions with neither (``bitcast``, ``copy-done``,
-    ``get-tuple-element``) are left out."""
+    ``get-tuple-element``) are left out.  A kernel the compiler renamed
+    (``ragged-dot-*``) has lost its scopes: it takes those of what it
+    reads, of a backward operand where it has one (:func:`_adopt`)."""
     module, table = None, {}
     inside: Dict[str, Counter] = {}     # computation -> its op_names
     orphans = []                        # (instruction, called computation)
+    operands: Dict[str, tuple] = {}     # instruction name -> (key, operands)
     comp = None
     for line in text.splitlines():
         key = instruction_key(line)
@@ -111,6 +148,7 @@ def parse_hlo_scopes(text: str) -> Tuple[Optional[str], Dict[str, str]]:
                 m = _MODULE.match(line)
                 module = m.group(1) if m else None
             continue
+        operands[key.split(" = ", 1)[0]] = (key, _operands(line))
         m = _OP_NAME.search(line)
         if m is not None:
             table[key] = m.group(1)
@@ -123,7 +161,35 @@ def parse_hlo_scopes(text: str) -> Tuple[Optional[str], Dict[str, str]]:
         names = inside.get(called)
         if names:
             table[key] = names.most_common(1)[0][0]
+    table.update({key: _adopt(key.split(" = ", 1)[0], op, table, operands)
+                  for key, op in table.items() if op.startswith(_RENAMED)})
     return module, table
+
+
+def _adopt(name: str, op: str, table: Dict[str, str],
+           operands: Dict[str, tuple], depth: int = 8) -> str:
+    """``<scopes of an operand>/<op>`` for a renamed kernel.  Each operand
+    answers with its own ``op_name`` or, where it has none (``bitcast``,
+    ``copy``, ``get-tuple-element``, async slices), with those of its own
+    operands, up to ``depth`` steps.  Of the answers under a ``qt.`` scope
+    the first that ran in the backward pass (``transpose(``) wins, else
+    the first; with none, ``op`` stays as the compiler wrote it."""
+
+    def answers(n, left):
+        for o in operands.get(n, (None, ()))[1]:
+            key = operands.get(o, (None,))[0]
+            named = table.get(key)
+            if named is None:
+                if key is not None and left > 1:
+                    yield from answers(o, left - 1)
+            elif PREFIX in named and not named.startswith(_RENAMED):
+                yield named
+
+    found = list(answers(name, depth))
+    if not found:
+        return op
+    best = next((f for f in found if "transpose(" in f), found[0])
+    return best.rsplit("/", 1)[0] + "/" + op
 
 
 def scoped(table: Dict[str, str]) -> int:

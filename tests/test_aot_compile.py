@@ -292,38 +292,72 @@ def test_sage_train_step_compiles(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 12 << 30
 
 
+def _tpu_sampler(sizes):
+    """What the library resolves to on a TPU (PERF.md, PR 21), said here
+    because ``GraphSageSampler`` would resolve for the CPU it runs on."""
+    import types
+
+    return types.SimpleNamespace(
+        sizes=sizes, gather_mode="lanes", sample_rng="hash", dedup="none",
+        frontier_caps=(None,) * len(sizes))
+
+
+def _small_fused_sage_step(one_chip):
+    """The fused SAGE step at a small graph, batch and fanout (seconds to
+    compile), lowered for the described chip."""
+    import types
+
+    from quiver_tpu.pipeline import _fused_train_impl
+
+    nodes, edges, dim, B, sizes = 20_000, 200_000, 128, 64, (5, 4, 3)
+    model, apply_fn = _sage(64, 16, 3)
+    x, blocks = _sampled_shapes(nodes, edges, dim, B, sizes)
+    tx, state = _train_state(model, x, blocks)
+    feature = types.SimpleNamespace(cache_count=nodes, node_count=nodes)
+    impl = _fused_train_impl(
+        _tpu_sampler(sizes), feature,
+        lambda p, x, blocks, **kw: apply_fn(p, x.astype(jnp.float32),
+                                            blocks, **kw), None)
+    tables = (*_graph(one_chip, nodes, edges),
+              (_s(one_chip, (nodes, dim), jnp.bfloat16), None))
+    return jax.jit(impl, donate_argnums=(1,)).lower(
+        tables, _on(one_chip, state), _s(one_chip, (B,)),
+        _s(one_chip, (B,)), _s(one_chip, (B,), jnp.bool_),
+        _key(one_chip))
+
+
+# sha256 of ``_small_fused_sage_step(...).as_text()`` at commit e4950b4
+# (PR 28), before ``TrainState`` had a slot for model state and the fused
+# step a frontier to hand over
+SAGE_STEP_BEFORE_MODEL_STATE = "f33332031cfac31e037a8c56a00d8e0102b244201ab4318ff8b5c5924ea6342a"
+
+
+def test_fused_sage_step_lowers_as_before_model_state(one_chip):
+    """A model that asks for neither frontier nor state (GraphSAGE) gets
+    the program it always got: ``TrainState.model_state`` is a pytree with
+    no leaf and ``call_model`` calls such an ``apply_fn`` as ever, so the
+    lowered text (arguments, instructions, donation) is to the letter the
+    one recorded before they existed - the same compile-cache entry, the
+    same numbers for ``papers100m-sage.train-fused``.  A PR that MEANS to
+    change the SAGE step's program records the new text's hash here."""
+    import hashlib
+
+    text = _small_fused_sage_step(one_chip).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        SAGE_STEP_BEFORE_MODEL_STATE
+
+
 def test_fused_train_step_scopes_reach_the_tpu_text(one_chip):
     """The fused step at a small graph, batch and fanout (seconds to
     compile), parsed by the scope table's own parser: the module carries
     its stable name and every ``fusion`` / ``custom-call`` of the entry
     computation sits under a ``qt.`` scope, but for the listed few."""
     import re
-    import types
 
-    from quiver_tpu.pipeline import _fused_train_impl
     from quiver_tpu.telemetry.device_scopes import (instruction_key,
                                                     parse_hlo_scopes)
 
-    nodes, edges, dim, B, sizes = 20_000, 200_000, 128, 64, (5, 4, 3)
-    model, apply_fn = _sage(64, 16, 3)
-    x, blocks = _sampled_shapes(nodes, edges, dim, B, sizes)
-    tx, state = _train_state(model, x, blocks)
-    # what the library resolves to on a TPU (PERF.md, PR 21), said here
-    # because ``GraphSageSampler`` would resolve for the CPU it runs on
-    sampler = types.SimpleNamespace(
-        sizes=sizes, gather_mode="lanes", sample_rng="hash", dedup="none",
-        frontier_caps=(None,) * len(sizes))
-    feature = types.SimpleNamespace(cache_count=nodes, node_count=nodes)
-    impl = _fused_train_impl(
-        sampler, feature,
-        lambda p, x, blocks, **kw: apply_fn(p, x.astype(jnp.float32),
-                                            blocks, **kw), None)
-    tables = (*_graph(one_chip, nodes, edges),
-              (_s(one_chip, (nodes, dim), jnp.bfloat16), None))
-    c = jax.jit(impl, donate_argnums=(1,)).lower(
-        tables, _on(one_chip, state), _s(one_chip, (B,)),
-        _s(one_chip, (B,)), _s(one_chip, (B,), jnp.bool_),
-        _key(one_chip)).compile()
+    c = _small_fused_sage_step(one_chip).compile()
     text = c.as_text()
     module, table = parse_hlo_scopes(text)
     assert module == "jit_qt_fused_train_step"
@@ -360,6 +394,70 @@ def test_fused_train_step_scopes_reach_the_tpu_text(one_chip):
     assert convs
     assert not {op for op in convs
                 if re.search(r"/(gather|scatter(-add)?)$", op)}
+
+
+MAG_SHARE = (1_902_369, 1_912_236, 401)     # papers, authors, institutions
+MAG_EDGES, MAG_DIM, MAG_CLASSES = 54_023_314, 768, 153
+MAG_RELATION_OF = ((0, 2, -1), (1, -1, 3), (-1, 4, -1))
+
+
+def test_typed_fused_step_groups_its_projections(one_chip):
+    """The published R-GAT (``models.RGNN``) through the fused step, at
+    the MAG240M share's tables and published widths (768 float16 rows, 2 x
+    1024, 4 heads, 5 relations, fanout [25, 15]) with 64 seeds where the
+    cell has 1,024 (a quarter of a minute where the cell's program takes
+    three and a half).  The chip's compiler turns ``lax.ragged_dot`` into
+    grouped kernels (``ragged-dot-*`` custom calls) for the forward
+    product and for both gradients, so the program's FLOPs are one product
+    per source, not one per relation; the scope table gives each kernel
+    back the ``qt.model.project`` scope the compiler's renaming took, in
+    the pass it ran in."""
+    import numpy as np
+    import optax
+
+    from quiver_tpu.models import RGNN, rgnn_apply_fn
+    from quiver_tpu.parallel import TrainState
+    from quiver_tpu.pipeline import _fused_train_impl
+    from quiver_tpu.sampler import run_pipeline
+    from quiver_tpu.telemetry.device_scopes import parse_hlo_scopes
+    import types
+
+    offsets = tuple(int(v) for v in np.cumsum((0,) + MAG_SHARE))
+    nodes, B, sizes = offsets[-1], 64, (25, 15)
+    model = RGNN(hidden=1024, out_dim=MAG_CLASSES, num_relations=5,
+                 type_offsets=offsets, relation_of=MAG_RELATION_OF)
+    indptr, indices = _graph(None, nodes, MAG_EDGES)
+    n_id, n_mask, _, blocks, _ = jax.eval_shape(
+        lambda ip, ix, s, k: run_pipeline(
+            "none", ip, ix, s, k, sizes, (None,) * 2, gather_mode="lanes",
+            sample_rng="hash"),
+        indptr, indices, _s(None, (B,)), jax.random.key(0))
+    x = _s(None, (n_id.shape[0], MAG_DIM), jnp.float32)
+    v = jax.eval_shape(model.init, jax.random.key(1), x, blocks, n_id,
+                       n_mask)
+    tx = optax.adam(1e-3)
+    state = jax.eval_shape(
+        lambda p, ms: TrainState.create(p, tx, ms),
+        {"params": v["params"]}, {"batch_stats": v["batch_stats"]})
+    feature = types.SimpleNamespace(cache_count=nodes, node_count=nodes)
+    impl = _fused_train_impl(_tpu_sampler(sizes), feature,
+                             rgnn_apply_fn(model), None)
+    tables = (*_graph(one_chip, nodes, MAG_EDGES),
+              (_s(one_chip, (nodes, MAG_DIM), jnp.float16), None))
+    c = jax.jit(impl, donate_argnums=(1,)).lower(
+        tables, _on(one_chip, state), _s(one_chip, (B,)),
+        _s(one_chip, (B,)), _s(one_chip, (B,), jnp.bool_),
+        _key(one_chip)).compile()
+    _, table = parse_hlo_scopes(c.as_text())
+    kernels = {k: op for k, op in table.items()
+               if k.startswith("%ragged-dot-none")}
+    # conv0: forward, dW (the features are not trained); conv1: forward,
+    # dW and dx
+    assert len(kernels) == 5, sorted(kernels)
+    assert all("qt.model.project" in op for op in kernels.values()), kernels
+    assert sum("transpose(" in op for op in kernels.values()) == 3, kernels
+    # the float16 table stays float16, row-major, and is gathered as it is
+    assert "f16[3815006,768]{1,0" in c.as_text()
 
 
 @pytest.mark.parametrize("bucket", [8, 128, 2048])

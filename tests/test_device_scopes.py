@@ -318,6 +318,48 @@ def test_parser_on_a_tpu_text():
     assert scoped(table) == len(table) - 1
 
 
+# cut from a v5e compile of ``models.RGNN``'s grouped projection: the
+# compiler writes ``lax.ragged_dot`` as kernels of its own and names them
+# itself, so the traced op_name, scopes and all, is gone from them
+RENAMED_TEXT = '''\
+HloModule jit_qt_fused_train_step, is_scheduled=true
+
+ENTRY %main.9 (x.1: f32[512,64], w.1: f32[5,64,128], gs.1: s32[5]) -> f32[5,64,128] {
+  %x.1 = f32[512,64]{1,0:T(8,128)} parameter(0), metadata={op_name="x"}
+  %w.1 = f32[5,64,128]{2,1,0:T(8,128)} parameter(1), metadata={op_name="w"}
+  %fusion.2 = f32[512,64]{1,0:T(8,128)} fusion(%x.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(qt_fused_train_step)/jvp(qt.model)/RGNN/conv0/qt.model.project/jit(_take)/gather"}
+  %fusion.3 = s32[5]{0:T(128)} fusion(%gs.1), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(qt_fused_train_step)/jvp(qt.model)/RGNN/conv0/qt.model.project/jit(bincount)/scatter-add"}
+  %bitcast.4 = s32[5]{0:T(128)} bitcast(%fusion.3)
+  %ragged-dot-metadata = (s32[6]{0:T(128)}, s32[8]{0:T(128)}) custom-call(%bitcast.4), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %get-tuple-element = s32[6]{0:T(128)} get-tuple-element(%ragged-dot-metadata), index=0
+  %ragged-dot-none.1 = f32[512,128]{1,0:T(8,128)} custom-call(%get-tuple-element, %fusion.2, %w.1), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %fusion.5 = f32[512,128]{1,0:T(8,128)} fusion(%ragged-dot-none.1), kind=kLoop, calls=%fused_computation.5, metadata={op_name="jit(qt_fused_train_step)/transpose(jvp(qt.model))/RGNN/conv0/qt.model.project/jit(_take)/gather"}
+  %copy.6 = f32[512,128]{1,0:T(8,128)} copy(%fusion.5)
+  ROOT %ragged-dot-none = f32[5,64,128]{2,1,0:T(8,128)} custom-call(%get-tuple-element, %fusion.2, %copy.6), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %ragged-dot-none.7 = f32[512,128]{1,0:T(8,128)} custom-call(%x.1, %w.1), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+}
+'''
+
+
+def test_a_kernel_the_compiler_renamed_takes_its_operands_scopes():
+    _, table = parse_hlo_scopes(RENAMED_TEXT)
+    scope = "jit(qt_fused_train_step)/%s/RGNN/conv0/qt.model.project/"
+    # forward: what it reads was made in the forward pass
+    assert table["%ragged-dot-none.1 = f32[512,128]{1,0:T(8,128)}"] == (
+        scope % "jvp(qt.model)" + "jit(_take)/ragged-dot-none")
+    # backward: one operand is a cotangent (found through a ``copy``),
+    # and that one wins over the forward operand before it
+    assert table["%ragged-dot-none = f32[5,64,128]{2,1,0:T(8,128)}"] == (
+        scope % "transpose(jvp(qt.model))" + "jit(_take)/ragged-dot-none")
+    # the kernel that prepares the groups, through a ``bitcast``
+    assert table["%ragged-dot-metadata = (s32[6]{0:T(128)}, "
+                 "s32[8]{0:T(128)})"] == (
+        scope % "jvp(qt.model)" + "jit(bincount)/ragged-dot-metadata")
+    # nothing scoped among its operands: left as the compiler wrote it
+    assert table["%ragged-dot-none.7 = f32[512,128]{1,0:T(8,128)}"] == (
+        "ragged-dot-none")
+
+
 def test_a_text_without_scope_names_is_stale():
     _, table = parse_hlo_scopes(STALE_TEXT)
     assert table and scoped(table) == 0

@@ -134,11 +134,13 @@ class TestMembership:
         assert [r.replica_id for r in d.replicas()] == ["r0"]
 
     def test_freshness_window(self, tmp_path):
-        d = MembershipDirectory(tmp_path, heartbeat_timeout_s=0.05)
+        # a window wide enough for a worker that is held up between the
+        # write and the read (50 ms was not, under six loaded workers)
+        d = MembershipDirectory(tmp_path, heartbeat_timeout_s=0.5)
         d.announce(ReplicaInfo("r0", state="serving"))
         assert [r.replica_id for r in d.replicas(fresh_only=True)] \
             == ["r0"]
-        time.sleep(0.1)
+        time.sleep(0.7)
         assert d.replicas(fresh_only=True) == []
         # stale records remain visible to operators
         assert [r.replica_id for r in d.replicas()] == ["r0"]
