@@ -571,11 +571,18 @@ def resolve_gather_mode(gather_mode: str,
     """Map ``"auto"`` to the backend-measured best element-gather mode.
 
     Resolution order: explicit kwarg > ``QUIVER_TPU_GATHER_MODE`` env /
-    tuned file > backend default.  Backend default: ``"lanes"``
-    (row-gather + VPU lane select) on accelerators, on the expectation
-    that XLA's 1-D scalar gather serializes there; plain ``"xla"`` take
-    on CPU.  The ranking of the modes on the chip at a real graph size:
-    not measured (ROADMAP D10) — the default is a choice, not a result.
+    tuned file > backend default.  Backend default: on accelerators the
+    window fetch ``"blocked:U"`` at ``ops.blockgather.DEFAULT_U`` (one
+    block of U 128-lane rows of ``indices`` per target serves its k
+    draws; the scattered ``indptr`` reads ride ``lanes``, row-gather +
+    VPU lane select); plain ``"xla"`` take on CPU.  Measured, PR 31, on
+    one TPU v5e in both cells of ``BENCHMARK.json`` against ``lanes``,
+    the default until then: 21,203 -> 28,570 seeds/s in
+    ``papers100m-sage.train-fused`` (the hops 21.0 -> 8.6 ms of the
+    step) and 10,486 -> 11,074 in ``mag240m-rgat.train-fused-typed``
+    (6.6 -> 1.4 ms), every draw the same to the bit (PERF.md section
+    6).  ``pwindow:2`` against it, once, in the first cell: 0.3 ms a
+    step slower; ``lanes_fused``: not measured (ROADMAP S3).
 
     ``sample_rng`` (the caller's RAW kwarg): when ``auto`` resolution
     lands on the Pallas ``pwindow`` kernel (hash-RNG-only) but the user
@@ -594,8 +601,12 @@ def resolve_gather_mode(gather_mode: str,
     else:
         import jax
 
-        resolved = "lanes" if jax.default_backend() not in ("cpu",) \
-            else "xla"
+        if jax.default_backend() in ("cpu",):
+            resolved = "xla"
+        else:
+            from .ops.blockgather import DEFAULT_U
+
+            resolved = f"blocked:{DEFAULT_U}"
     if resolved.startswith("pwindow") and sample_rng == "key":
         resolved = "blocked" + resolved[len("pwindow"):]
     return resolved

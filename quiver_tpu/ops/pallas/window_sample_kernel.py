@@ -158,7 +158,7 @@ def pallas_window_sample(table2d: jax.Array, start: jax.Array,
     [B] int32 window starts/lengths; ``key``: PRNG key (hash-folded).
     Rows where ``deg == 0`` return garbage (callers mask via counts).
     """
-    from ..blockgather import _fit_split
+    from ..blockgather import _compact, _fit_split
     from ..fastgather import element_gather
     from ..sample import (_fold_key_words, _hash_uniform,
                           _stratified_positions)
@@ -181,8 +181,7 @@ def pallas_window_sample(table2d: jax.Array, start: jax.Array,
 
     kpad = -(-k // 8) * 8  # next multiple of 8 (>= 8 for k >= 1)
     k0, k1 = _fold_key_words(key)
-    r0, _fits, nfall, S, seed_of_slot, valid = _fit_split(
-        start, deg, U, B, fallback_frac)
+    r0, fits, nfall, S = _fit_split(start, deg, U, B, fallback_frac)
     r0c = jnp.clip(r0, 0, R - U)
     off = start - (r0c << 7)
 
@@ -220,6 +219,7 @@ def pallas_window_sample(table2d: jax.Array, start: jax.Array,
             interpret=interpret,
         )(r0c_p, kw, deg_p, off_p, table2d)
         vals = out[:B, :k]
+        seed_of_slot, valid = _compact(fits, S)
         # non-fitting seeds: identical draws via the XLA formula, gathered
         # per element on the compacted slots (same policy as blockgather)
         u_all = _hash_uniform(key, (B, k))

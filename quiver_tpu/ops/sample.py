@@ -42,6 +42,9 @@ class SampleOut(NamedTuple):
     mask: jax.Array   # [B, k] bool
     counts: jax.Array  # [B] int32 = min(degree, k), 0 for invalid seeds
     eid: Optional[jax.Array] = None  # [B, k] int32 global edge positions
+    # scalar int32, ``blocked`` window modes only: targets whose window
+    # did not fit (``ops.blockgather.blocked_window_gather``)
+    nfall: Optional[jax.Array] = None
 
 
 # counter-hash constants — single source for the XLA path AND the fused
@@ -146,8 +149,8 @@ def _gather(table: jax.Array, idx: jax.Array, mode: str) -> jax.Array:
     'blocked*'/'pwindow*' apply only to the per-seed WINDOW gathers inside
     the samplers (``ops.blockgather`` / the fused Pallas window kernel);
     scattered [B] element gathers (the indptr reads) ride the lanes path
-    under them (per-element DMA of indptr rows against the lanes path
-    on the chip: not measured)."""
+    under them (two adjacent entries per target, themselves a window of
+    two: ROADMAP S3)."""
     if mode.startswith("blocked") or mode.startswith("pwindow"):
         mode = "lanes"
     if mode in ("lanes", "lanes_fused"):
@@ -220,6 +223,7 @@ def sample_neighbors(
 
     mask = j < counts[:, None]
     idx = start[:, None] + pos
+    nfall = None
     if gather_mode.startswith("pwindow"):
         # fully-fused Pallas hop: PRNG + positions + window DMA + select
         # in one kernel — pos above survives only as the eid formula
@@ -259,7 +263,7 @@ def sample_neighbors(
             f"blocked gather needs a 128-multiple indices table, got "
             f"{indices.shape[0]} — pad with ops.fastgather.pad_table_128"
         )
-        nbrs = blocked_window_gather(
+        nbrs, nfall = blocked_window_gather(
             indices.reshape(-1, 128), start, deg, pos,
             U=parse_blocked(gather_mode))
     else:
@@ -270,7 +274,8 @@ def sample_neighbors(
     # purpose (quiver.cu.hpp eid); PyG's Adj e_id slot can be filled from
     # this instead of the reference's empty tensor (sage_sampler.py:143).
     eid = jnp.where(mask, idx, jnp.int32(-1))
-    return SampleOut(nbrs=nbrs, mask=mask, counts=counts, eid=eid)
+    return SampleOut(nbrs=nbrs, mask=mask, counts=counts, eid=eid,
+                     nfall=nfall)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "gather_mode",
@@ -423,6 +428,7 @@ def sample_neighbors_weighted(
     )
     u = _uniform(key, (B, k), sample_rng) * total[:, None]
 
+    nfall = None
     if gather_mode.startswith("blocked"):
         # CDF inversion AND the neighbor reads both live in the seed's
         # contiguous window: one block gather + one VPU pass replaces the
@@ -442,8 +448,9 @@ def sample_neighbors_weighted(
         posl = jnp.where(deg[:, None] <= k, j, posl)
         posl = jnp.minimum(posl, jnp.maximum(deg[:, None] - 1, 0))
         pos = start[:, None] + posl
-        nbrs = blocked_window_gather(indices.reshape(-1, 128), start, deg,
-                                     jnp.where(mask, posl, 0), U=U)
+        nbrs, nfall = blocked_window_gather(
+            indices.reshape(-1, 128), start, deg, jnp.where(mask, posl, 0),
+            U=U)
     else:
         # binary search for first position p in [start, end) with cw[p] > u
         lo = jnp.broadcast_to(start[:, None], (B, k))
@@ -466,7 +473,8 @@ def sample_neighbors_weighted(
         nbrs = _gather(indices, jnp.where(mask, pos, 0), gather_mode)
     nbrs = jnp.where(mask, nbrs, jnp.int32(-1))
     eid = jnp.where(mask, pos, jnp.int32(-1))
-    return SampleOut(nbrs=nbrs, mask=mask, counts=counts, eid=eid)
+    return SampleOut(nbrs=nbrs, mask=mask, counts=counts, eid=eid,
+                     nfall=nfall)
 
 
 def row_cumsum_weights(indptr, weights):

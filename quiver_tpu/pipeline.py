@@ -102,7 +102,7 @@ def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
                             label_mask, key):
         indptr, indices, feat_tables = tables
         ks, kd = jax.random.split(key)
-        n_id, n_mask, num, blocks, _ = run_pipeline(
+        n_id, n_mask, num, blocks, _, _ = run_pipeline(
             dedup, indptr, indices, seeds, ks, sizes, caps, gather_mode=gm,
             sample_rng=srng
         )
@@ -181,7 +181,7 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
     @jax.jit
     def qt_fused_eval(tables, params, seeds, key, model_state):
         indptr, indices, feat_tables = tables
-        n_id, n_mask, num, blocks, _ = run_pipeline(
+        n_id, n_mask, num, blocks, _, _ = run_pipeline(
             dedup, indptr, indices, seeds, key, sizes, caps, gather_mode=gm,
             sample_rng=srng
         )

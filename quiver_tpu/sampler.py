@@ -33,6 +33,7 @@ import numpy as np
 from . import telemetry
 from .ops.sample import (sample_neighbors, sample_neighbors_overlay,
                          sample_neighbors_weighted, row_cumsum_weights)
+from .ops.blockgather import NO_WINDOW, fallback_slots
 from .ops.reindex import reindex
 from .telemetry.device_scopes import SAMPLER, sampler_hop
 from .utils.topology import CSRTopo
@@ -88,6 +89,10 @@ class SampledBatch(NamedTuple):
     # sampler-level last_drops is unreliable under prefetching)
     version: Optional[int] = None  # streaming: the graph version this
     # batch sampled (the snapshot's), None on frozen-CSR samplers
+    window_misses: Optional[jax.Array] = None  # [L] per-hop count of
+    # targets whose CSR window did not fit the ``blocked`` modes' block
+    # (window_stats(batch) reads it; ops.blockgather.NO_WINDOW where the
+    # hop has no window route)
 
     def to_pyg_adjs(self):
         """Ragged ``(n_id, batch_size, [Adj])`` view, PyG-compatible.
@@ -119,6 +124,17 @@ class SampledBatch(NamedTuple):
             adjs.append((edge_index, e_id, (n_src, t)))
             n_src = t  # this layer's targets = next (inner) layer's sources
         return (np.asarray(self.n_id), self.batch_size, adjs)  # quiverlint: sync-ok[PyG export boundary]
+
+
+def _hop_targets(layers):
+    """Targets of each hop, hop 1 first, from a batch's blocks."""
+    return [blk.mask.shape[0] for blk in layers[::-1]]
+
+
+def _window_misses(nfalls):
+    """[L] int32 from each hop's ``SampleOut.nfall``."""
+    return jnp.stack([jnp.int32(NO_WINDOW) if n is None else n
+                      for n in nfalls])
 
 
 def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
@@ -153,6 +169,7 @@ def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
         fmask = jnp.ones((B,), dtype=bool)
         keys = jax.random.split(key, len(sizes))
     blocks = []
+    nfalls = []
     for l, k in enumerate(sizes):
         with jax.named_scope(sampler_hop(l + 1)):
             if cum_weights is not None:
@@ -166,6 +183,7 @@ def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
                                        seed_mask=fmask,
                                        gather_mode=gather_mode,
                                        sample_rng=sample_rng)
+            nfalls.append(out.nfall)
             t = frontier.shape[0]
             pos = (t + jnp.arange(t, dtype=jnp.int32)[:, None] * k
                    + jnp.arange(k, dtype=jnp.int32)[None, :])
@@ -188,7 +206,8 @@ def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
     with jax.named_scope(SAMPLER):
         num_nodes = fmask.sum().astype(jnp.int32)
         drops = jnp.zeros((len(sizes),), jnp.int32)  # nothing ever dropped
-    return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops
+        misses = _window_misses(nfalls)
+    return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops, misses
 
 
 def _sample_pipeline_overlay(indptr, indices, tomb, d_indptr, d_indices,
@@ -239,7 +258,9 @@ def _sample_pipeline_overlay(indptr, indices, tomb, d_indptr, d_indices,
     with jax.named_scope(SAMPLER):
         num_nodes = fmask.sum().astype(jnp.int32)
         drops = jnp.zeros((len(sizes),), jnp.int32)
-    return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops
+        # the overlay op fetches per draw under every mode
+        misses = _window_misses([None] * len(sizes))
+    return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops, misses
 
 
 def _sample_pipeline(indptr, indices, seeds, key, sizes, caps,
@@ -253,6 +274,7 @@ def _sample_pipeline(indptr, indices, seeds, key, sizes, caps,
         keys = jax.random.split(key, len(sizes))
     blocks = []
     drops = []  # per-hop count of frontier nodes dropped by the cap
+    nfalls = []
     for l, (k, cap) in enumerate(zip(sizes, caps)):
         with jax.named_scope(sampler_hop(l + 1)):
             if cum_weights is not None:
@@ -266,6 +288,7 @@ def _sample_pipeline(indptr, indices, seeds, key, sizes, caps,
                                        seed_mask=fmask,
                                        gather_mode=gather_mode,
                                        sample_rng=sample_rng)
+            nfalls.append(out.nfall)
             r = reindex(frontier, out.nbrs, out.mask, seed_mask=fmask)
             blocks.append(
                 LayerBlock(
@@ -294,7 +317,8 @@ def _sample_pipeline(indptr, indices, seeds, key, sizes, caps,
     with jax.named_scope(SAMPLER):
         num_nodes = fmask.sum().astype(jnp.int32)
         drops = jnp.stack(drops)
-    return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops
+        misses = _window_misses(nfalls)
+    return frontier, fmask, num_nodes, tuple(blocks[::-1]), drops, misses
 
 
 def _is_stream_graph(obj) -> bool:
@@ -629,16 +653,16 @@ class GraphSageSampler:
             from .utils.rng import make_key
 
             key = make_key(np.random.randint(0, 2**31 - 1))
-        n_id, n_mask, num_nodes, blocks, drops = fn(seeds, key)
-        # [L] per-hop frontier-cap drop counts (always 0 without caps);
-        # kept on device until someone asks via overflow_stats() — the
-        # drop counter is incremented there, at materialization, so the
-        # hot loop never pays a device sync for accounting
-        self.last_drops = drops
-        self._drops_recorded = False
+        n_id, n_mask, num_nodes, blocks, drops, misses = fn(seeds, key)
+        # [L] per-hop frontier-cap drop counts (always 0 without caps)
+        # and window misses; kept on device until someone asks via
+        # overflow_stats() / window_stats() — the counters are
+        # incremented there, at materialization, so the hot loop never
+        # pays a device sync for accounting
+        self._keep_counts(drops, misses, blocks)
         return SampledBatch(
             n_id=n_id, n_id_mask=n_mask, num_nodes=num_nodes,
-            batch_size=B, layers=blocks, drops=drops,
+            batch_size=B, layers=blocks, drops=drops, window_misses=misses,
         )
 
     def _sample_stream(self, input_nodes, key, time_window) -> SampledBatch:
@@ -678,17 +702,25 @@ class GraphSageSampler:
             window_hi = jnp.int32(hi)
         else:
             window_lo = window_hi = None
-        n_id, n_mask, num_nodes, blocks, drops = fn(
+        n_id, n_mask, num_nodes, blocks, drops, misses = fn(
             snap.indptr, snap.indices, snap.tomb, snap.d_indptr,
             snap.d_indices, snap.base_ts, snap.d_ts, seeds, key,
             window_lo, window_hi)
-        self.last_drops = drops
-        self._drops_recorded = False
+        self._keep_counts(drops, misses, blocks)
         return SampledBatch(
             n_id=n_id, n_id_mask=n_mask, num_nodes=num_nodes,
             batch_size=B, layers=blocks, drops=drops,
-            version=snap.version,
+            version=snap.version, window_misses=misses,
         )
+
+    def _keep_counts(self, drops, misses, blocks):
+        """The newest call's device-side counts, for ``overflow_stats()``
+        and ``window_stats()`` (the arrays and the hops' target counts,
+        not the batch: that would pin its frontier)."""
+        self.last_drops = drops
+        self._drops_recorded = False
+        self._last_window = (misses, _hop_targets(blocks))
+        self._window_recorded = False
 
     def overflow_stats(self, batch: Optional[SampledBatch] = None):
         """[L] per-hop counts of frontier nodes dropped by ``frontier_caps``.
@@ -717,6 +749,47 @@ class GraphSageSampler:
                 telemetry.counter("sampler_frontier_drops_total",
                                   mode=self.mode.lower()).inc(total)
         return arr
+
+    def window_stats(self, batch: Optional[SampledBatch] = None):
+        """Per hop, how the ``blocked`` window mode fetched the draws:
+        a list of ``{"window", "fallback", "classic"}`` - targets served
+        by their covering block, targets compacted into the per-draw
+        fallback, and whether the WHOLE hop took the per-draw path (more
+        misses than fallback slots, a hop with ``k <= U``, or a mode with
+        no window route; ``window`` and ``fallback`` are then 0).
+
+        ``batch`` / no ``batch`` as :meth:`overflow_stats`; only the
+        sampler-level form feeds the registry, once per ``sample`` call.
+        None before any TPU-mode call.
+        """
+        if batch is not None:
+            misses, hop_targets = (batch.window_misses,
+                                   _hop_targets(batch.layers))
+        else:
+            misses, hop_targets = getattr(self, "_last_window", (None, ()))
+        if misses is None:
+            return None
+        # the deliberate materialization point for the misses
+        misses = np.asarray(misses)
+        stats = []
+        for miss, targets in zip(misses.tolist(), hop_targets):
+            classic = miss == NO_WINDOW or miss > fallback_slots(targets)
+            stats.append({"window": 0 if classic else targets - miss,
+                          "fallback": 0 if classic else miss,
+                          "classic": bool(classic)})
+        if batch is None and not getattr(self, "_window_recorded", True):
+            self._window_recorded = True
+            mode = self.mode.lower()
+            fallen = float(sum(s["fallback"] for s in stats))
+            if fallen:
+                telemetry.counter("sampler_window_fallback_targets_total",
+                                  mode=mode).inc(fallen)
+            wholesale = sum(m > 0 for m, s in zip(misses, stats)
+                            if s["classic"])
+            if wholesale:
+                telemetry.counter("sampler_window_classic_hops_total",
+                                  mode=mode).inc(float(wholesale))
+        return stats
 
     def _sample_uva(self, input_nodes, key) -> SampledBatch:
         """Hot/cold big-graph sampling (``quiver_tpu.uva``): HBM-budgeted

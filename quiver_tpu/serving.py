@@ -582,7 +582,7 @@ class InferenceServer:
             @jax.jit
             def fn(tables, params, seeds, key):
                 indptr, indices, cw, feat_tables = tables
-                n_id, _, _, blocks, _ = run_pipeline(
+                n_id, _, _, blocks, _, _ = run_pipeline(
                     dedup, indptr, indices, seeds, key, sizes, caps,
                     gather_mode=gm, cum_weights=cw, sample_rng=srng)
                 x = _lookup_tables(feat_tables, n_id)

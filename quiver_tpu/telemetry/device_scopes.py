@@ -24,6 +24,12 @@ compiled text.  This module keeps that text reachable:
 Under ``jax.value_and_grad`` the forward pass of a scope reads
 ``jvp(qt.model)`` and the backward pass ``transpose(jvp(qt.model))``.
 
+A ``conditional`` or a ``while`` is an event of the trace too, and it lasts
+as long as the operations of the branch or body it ran, which the trace
+lists besides, each under the scope it was traced in.  Summed by layer the
+two would count that time twice, so the table puts the wrapper under a
+scope of its own, ``qt.flow``, in front of the name it was traced under.
+
 The persistent cache's key strips debug information, scope names among
 it: a program whose scopes were renamed but whose instructions were not
 is a cache HIT and its text still carries the old names.  A text with no
@@ -43,7 +49,7 @@ from collections import Counter
 from typing import Dict, Optional, Tuple
 
 __all__ = ["PREFIX", "SAMPLER", "FEATURE_GATHER", "MODEL", "MODEL_PROJECT",
-           "MODEL_ATTENTION", "OPTIMIZER", "sampler_hop",
+           "MODEL_ATTENTION", "OPTIMIZER", "FLOW", "sampler_hop",
            "register_program", "device_scopes", "parse_hlo_scopes",
            "instruction_key", "scoped"]
 
@@ -57,6 +63,9 @@ MODEL = PREFIX + "model"
 MODEL_PROJECT = MODEL + ".project"
 MODEL_ATTENTION = MODEL + ".attention"
 OPTIMIZER = PREFIX + "optimizer"
+# an instruction that runs whole computations (the ``conditional`` a
+# ``lax.cond`` becomes, a ``while``): no layer's own time, see above
+FLOW = PREFIX + "flow"
 
 
 def sampler_hop(n: int) -> str:
@@ -71,6 +80,8 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+) \(.*\{\s*$")
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _CALLS = re.compile(r"[, ]calls=(%?[\w.\-]+)")
+_RUNS = re.compile(
+    r"[, ](?:branch_computations|true_computation|condition|body)=")
 _COMMENT = re.compile(r"/\*.*?\*/")
 _OPERAND = re.compile(r"%[\w.\-]+")
 # the TPU compiler turns ``lax.ragged_dot`` into kernels of its own and
@@ -130,7 +141,9 @@ def parse_hlo_scopes(text: str) -> Tuple[Optional[str], Dict[str, str]]:
     text.  A fusion takes the ``op_name`` of its own metadata and, where
     it has none, the commonest among the instructions of the computation
     it calls; instructions with neither (``bitcast``, ``copy-done``,
-    ``get-tuple-element``) are left out.  A kernel the compiler renamed
+    ``get-tuple-element``) are left out.  A ``conditional`` or ``while``
+    goes under ``qt.flow/``: its event spans those of the computation it
+    ran, which carry their own names.  A kernel the compiler renamed
     (``ragged-dot-*``) has lost its scopes: it takes those of what it
     reads, of a backward operand where it has one (:func:`_adopt`)."""
     module, table = None, {}
@@ -150,7 +163,9 @@ def parse_hlo_scopes(text: str) -> Tuple[Optional[str], Dict[str, str]]:
             continue
         operands[key.split(" = ", 1)[0]] = (key, _operands(line))
         m = _OP_NAME.search(line)
-        if m is not None:
+        if _RUNS.search(line):
+            table[key] = f"{FLOW}/{m.group(1) if m else ''}"
+        elif m is not None:
             table[key] = m.group(1)
             inside.setdefault(comp, Counter())[m.group(1)] += 1
         else:

@@ -28,8 +28,9 @@ REDDIT_NODES, REDDIT_EDGES = 232_965, 114_615_892
 REDDIT_DIM, REDDIT_CLASSES, REDDIT_FANOUT = 602, 41, (25, 10)
 
 # frontier a hop samples FROM and its fanout, dedup="none" (the frontier
-# grows by (1 + k) per hop): B=1024 [15,10,5]
-HOPS = [(1024, 15), (16_384, 10), (180_224, 5)]
+# grows by (1 + k) per hop): B=1024 [15,10,5], whose last hop is the
+# SAGE cell's dear shape, and [25,15]'s last hop, the typed cell's
+HOPS = [(1024, 15), (16_384, 10), (180_224, 5), (26_624, 15)]
 WIDTHS = [100, 128, 602]
 
 
@@ -90,19 +91,31 @@ def _key(sharding):
 
 
 # --------------------------------------------------------------- sampling
+# what ``config.resolve_gather_mode("auto")`` gives on a TPU (asserted in
+# tests/test_config_resolution.py; said here because code that resolves
+# in this process resolves for the CPU it runs on)
+TPU_GATHER_MODE = "blocked:2"
+
+
 @pytest.mark.parametrize("B,k", HOPS)
 def test_default_tpu_hop_compiles(one_chip, B, k):
-    """What ``config.resolve_*`` pick on a TPU: lanes gather + hash RNG."""
+    """What ``config.resolve_*`` pick on a TPU: the window fetch (two
+    rows of ``indices`` per target, the per-draw path in the other branch
+    of a ``conditional``) + hash RNG."""
     from quiver_tpu.ops.sample import sample_neighbors
 
     indptr, indices = _graph(one_chip, PRODUCTS_NODES, PRODUCTS_EDGES)
     c = _compile(
         lambda ip, ix, s, kk, m: sample_neighbors(
-            ip, ix, s, k, kk, seed_mask=m, gather_mode="lanes",
+            ip, ix, s, k, kk, seed_mask=m, gather_mode=TPU_GATHER_MODE,
             sample_rng="hash"),
         indptr, indices, _s(one_chip, (B,)), _key(one_chip),
         _s(one_chip, (B,), jnp.bool_))
-    assert "tpu_custom_call" not in c.as_text()   # pure XLA by design
+    text = c.as_text()
+    assert "tpu_custom_call" not in text   # pure XLA by design
+    assert " conditional(" in text         # k > U: the hop is routed
+    # the window branch asks for a row a target, twice; the other for k
+    assert f"s32[{B},128]" in text and f"s32[{B * k},128]" in text
 
 
 @pytest.mark.parametrize("B,k,U", [(1024, 15, 3), (300, 5, 2), (64, 8, 1),
@@ -256,10 +269,10 @@ def _sampled_shapes(nodes, edges, dim, B, sizes):
 
     indptr = jax.ShapeDtypeStruct((_pad128(nodes + 1),), jnp.int32)
     indices = jax.ShapeDtypeStruct((_pad128(edges),), jnp.int32)
-    n_id, _, _, blocks, _ = jax.eval_shape(
+    n_id, _, _, blocks, _, _ = jax.eval_shape(
         lambda ip, ix, s, k: run_pipeline(
             "none", ip, ix, s, k, tuple(sizes), (None,) * len(sizes),
-            gather_mode="lanes", sample_rng="hash"),
+            gather_mode=TPU_GATHER_MODE, sample_rng="hash"),
         indptr, indices, jax.ShapeDtypeStruct((B,), jnp.int32),
         jax.random.key(0))
     return jax.ShapeDtypeStruct((n_id.shape[0], dim), jnp.float32), blocks
@@ -293,13 +306,14 @@ def test_sage_train_step_compiles(one_chip):
 
 
 def _tpu_sampler(sizes):
-    """What the library resolves to on a TPU (PERF.md, PR 21), said here
-    because ``GraphSageSampler`` would resolve for the CPU it runs on."""
+    """What the library resolves to on a TPU (PERF.md, PR 21 and PR 31),
+    said here because ``GraphSageSampler`` would resolve for the CPU it
+    runs on."""
     import types
 
     return types.SimpleNamespace(
-        sizes=sizes, gather_mode="lanes", sample_rng="hash", dedup="none",
-        frontier_caps=(None,) * len(sizes))
+        sizes=sizes, gather_mode=TPU_GATHER_MODE, sample_rng="hash",
+        dedup="none", frontier_caps=(None,) * len(sizes))
 
 
 def _small_fused_sage_step(one_chip):
@@ -326,10 +340,14 @@ def _small_fused_sage_step(one_chip):
         _key(one_chip))
 
 
-# sha256 of ``_small_fused_sage_step(...).as_text()`` at commit e4950b4
-# (PR 28), before ``TrainState`` had a slot for model state and the fused
-# step a frontier to hand over
-SAGE_STEP_BEFORE_MODEL_STATE = "f33332031cfac31e037a8c56a00d8e0102b244201ab4318ff8b5c5924ea6342a"
+# sha256 of ``_small_fused_sage_step(...).as_text()``.  It stood at
+# f33332031cfac31e037a8c56a00d8e0102b244201ab4318ff8b5c5924ea6342a from
+# commit e4950b4 (PR 28), before ``TrainState`` had a slot for model state
+# and the fused step a frontier to hand over, through d90ff1a (PR 30).
+# Re-recorded on PR 31's tree (the commit after 78c559f), which MEANS to
+# change the step: its hops fetch a two-row window per target
+# (``TPU_GATHER_MODE``) where they fetched a row per draw
+SAGE_STEP_BEFORE_MODEL_STATE = "13b12aa3dff04b751e6615a9002ac9aff735aec8affd487987d33e7af1ee1f6a"
 
 
 def test_fused_sage_step_lowers_as_before_model_state(one_chip):
@@ -337,9 +355,9 @@ def test_fused_sage_step_lowers_as_before_model_state(one_chip):
     the program it always got: ``TrainState.model_state`` is a pytree with
     no leaf and ``call_model`` calls such an ``apply_fn`` as ever, so the
     lowered text (arguments, instructions, donation) is to the letter the
-    one recorded before they existed - the same compile-cache entry, the
-    same numbers for ``papers100m-sage.train-fused``.  A PR that MEANS to
-    change the SAGE step's program records the new text's hash here."""
+    one recorded - the same compile-cache entry, the same numbers for
+    ``papers100m-sage.train-fused``.  A PR that MEANS to change the SAGE
+    step's program records the new text's hash here, as PR 31 did."""
     import hashlib
 
     text = _small_fused_sage_step(one_chip).as_text()
@@ -427,9 +445,10 @@ def test_typed_fused_step_groups_its_projections(one_chip):
     model = RGNN(hidden=1024, out_dim=MAG_CLASSES, num_relations=5,
                  type_offsets=offsets, relation_of=MAG_RELATION_OF)
     indptr, indices = _graph(None, nodes, MAG_EDGES)
-    n_id, n_mask, _, blocks, _ = jax.eval_shape(
+    n_id, n_mask, _, blocks, _, _ = jax.eval_shape(
         lambda ip, ix, s, k: run_pipeline(
-            "none", ip, ix, s, k, sizes, (None,) * 2, gather_mode="lanes",
+            "none", ip, ix, s, k, sizes, (None,) * 2,
+            gather_mode=TPU_GATHER_MODE,
             sample_rng="hash"),
         indptr, indices, _s(None, (B,)), jax.random.key(0))
     x = _s(None, (n_id.shape[0], MAG_DIM), jnp.float32)
@@ -474,9 +493,9 @@ def test_serving_bucket_forward_compiles(one_chip, bucket):
 
     def forward(tables, params, seeds, key):
         indptr, indices, feat_tables = tables
-        n_id, _, _, blocks, _ = run_pipeline(
+        n_id, _, _, blocks, _, _ = run_pipeline(
             "none", indptr, indices, seeds, key, REDDIT_FANOUT,
-            (None, None), gather_mode="lanes", sample_rng="hash")
+            (None, None), gather_mode=TPU_GATHER_MODE, sample_rng="hash")
         return apply_fn(params, _lookup_tables(feat_tables, n_id), blocks)
 
     tables = (*_graph(one_chip, REDDIT_NODES, REDDIT_EDGES),

@@ -2,9 +2,10 @@
 
 Explicit kwarg > env (QUIVER_TPU_*) / tuned file > backend default.
 Backend default on CPU (the test backend): gather_mode="xla",
-sample_rng="key".  The accelerator branch ("lanes"/"hash") can't
-execute here (chip_smoke.py prints what it resolves there); the precedence
-logic it shares is what's under test.
+sample_rng="key".  The accelerator branch can't execute here
+(chip_smoke.py prints what it resolves there), so its default is stated
+with the backend's name patched; the precedence logic it shares is
+what's under test.
 
 All env mutation goes through ``monkeypatch`` so it is restored even on
 assertion failure — the round-3 hand-rolled save/restore leaked
@@ -44,6 +45,23 @@ def test_explicit_wins():
 def test_backend_default_cpu():
     assert resolve_gather_mode("auto") == "xla"
     assert resolve_sample_rng("auto") == "key"
+
+
+def test_backend_default_accelerator(monkeypatch):
+    """On a TPU ``auto`` is the window fetch at the chip's block width
+    (PERF.md, PR 31), spelt in the ``blocked:U`` grammar, with the hash
+    uniforms; the environment still overrides it."""
+    import jax
+
+    from quiver_tpu.ops.blockgather import DEFAULT_U, parse_blocked
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mode = resolve_gather_mode("auto")
+    assert mode == "blocked:2" and parse_blocked(mode) == DEFAULT_U
+    assert resolve_sample_rng("auto", mode) == "hash"
+    monkeypatch.setenv("QUIVER_TPU_GATHER_MODE", "lanes")
+    qconfig._config = None
+    assert resolve_gather_mode("auto") == "lanes"
 
 
 def test_env_overrides_auto(monkeypatch):

@@ -21,7 +21,7 @@ from quiver_tpu.pipeline import (make_fused_eval_fn, make_fused_train_step,
                                  make_scan_epoch)
 from quiver_tpu.telemetry import noop
 from quiver_tpu.telemetry.device_scopes import (
-    FEATURE_GATHER, MODEL, OPTIMIZER, SAMPLER, instruction_key,
+    FEATURE_GATHER, FLOW, MODEL, OPTIMIZER, SAMPLER, instruction_key,
     parse_hlo_scopes, register_program, sampler_hop, scoped)
 from quiver_tpu.utils.synthetic import community_graph
 
@@ -358,6 +358,37 @@ def test_a_kernel_the_compiler_renamed_takes_its_operands_scopes():
     # nothing scoped among its operands: left as the compiler wrote it
     assert table["%ragged-dot-none.7 = f32[512,128]{1,0:T(8,128)}"] == (
         "ragged-dot-none")
+
+
+# cut from a v5e compile of a ``blocked:2`` hop: the ``lax.cond`` between
+# the window fetch and the per-draw fetch, and a ``while``
+FLOW_TEXT = '''\
+HloModule jit_qt_fused_train_step, is_scheduled=true
+
+%region_2.12 (arg_tuple.1: (s32[1024,5], s32[4096,128])) -> (s32[1024,5]) {
+  %fusion.14 = s32[1024,128]{1,0:T(8,128)} fusion(%get-tuple-element.88, %get-tuple-element.212), kind=kCustom, calls=%fused_computation.14, metadata={op_name="jit(qt_fused_train_step)/qt.sampler.hop3/jit(sample_neighbors)/cond/branch_1_fun/gather"}
+}
+
+ENTRY %main.9 (t.1: s32[4096,128]) -> s32[1024,5] {
+  %conditional.2 = (s32[1024,5]{0,1:T(8,128)}) conditional(%convert_element_type.29, %tuple.28, %tuple.29), branch_computations={%region_5.26, %region_2.12}, metadata={op_name="jit(qt_fused_train_step)/qt.sampler.hop3/jit(sample_neighbors)/cond" stack_frame_id=20}
+  %while.3 = (s32[]{:T(128)}, f32[8]{0:T(256)}) while(%tuple.30), condition=%cond.4, body=%body.5
+}
+'''
+
+
+def test_a_conditional_is_no_layers_own_time():
+    """Its event spans its branch's events, which the trace lists too: the
+    table keeps it out of every layer, under ``qt.flow``, and the branch's
+    operations under the scope they were traced in."""
+    _, table = parse_hlo_scopes(FLOW_TEXT)
+    assert table["%conditional.2 = (s32[1024,5]{0,1:T(8,128)})"] == (
+        "qt.flow/jit(qt_fused_train_step)/qt.sampler.hop3/"
+        "jit(sample_neighbors)/cond")
+    assert table["%while.3 = (s32[]{:T(128)}, f32[8]{0:T(256)})"] == (
+        FLOW + "/")
+    assert "/qt.sampler.hop3/" in table[
+        "%fusion.14 = s32[1024,128]{1,0:T(8,128)}"]
+    assert scoped(table) == 3
 
 
 def test_a_text_without_scope_names_is_stale():

@@ -65,11 +65,13 @@ def test_blocked_op_exact_with_fallback_and_overflow(U, frac):
     table = rng.integers(0, 1 << 30, total + pad).astype(np.int32)
     start = np.concatenate([[0], np.cumsum(deg)[:-1]]).astype(np.int32)
     pos = (rng.random((B, k)) * deg[:, None]).astype(np.int32)
-    got = np.asarray(blocked_window_gather(
+    got, nfall = blocked_window_gather(
         jnp.asarray(table).reshape(-1, 128), jnp.asarray(start),
-        jnp.asarray(deg), jnp.asarray(pos), U=U, fallback_frac=frac))
+        jnp.asarray(deg), jnp.asarray(pos), U=U, fallback_frac=frac)
     want = table[start[:, None] + pos]
-    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    spans = ((start + np.maximum(deg - 1, 0)) >> 7) - (start >> 7)
+    assert int(nfall) == int((spans >= U).sum())
 
 
 def test_blocked_weighted_equals_xla(small_graph):
@@ -171,3 +173,165 @@ def test_pwindow_requires_hash_rng(small_graph):
     with pytest.raises(ValueError, match="hash"):
         GraphSageSampler(small_graph, [4], gather_mode="pwindow",
                          sample_rng="key").sample(np.arange(8))
+
+
+# ---- the chip's default: the window fetch at the shipped block width and
+# fallback share (ops.blockgather.DEFAULT_U / FALLBACK_FRAC; PERF.md, PR 31)
+HEAVY = {"none": 0, "some": 5, "many": 40}   # targets over the window
+
+
+def _windowed_graph(heavy, n=256, seed=3):
+    """``n`` nodes of degree 0..60 (a window of at most 129 entries always
+    fits two rows), ``heavy`` of them of degree 300..900 (never fits), a
+    few of degree 0; neighbours uniform."""
+    from quiver_tpu import CSRTopo
+
+    rng = np.random.default_rng(seed)
+    deg = rng.integers(1, 60, n)
+    deg[rng.choice(n, 12, replace=False)] = 0
+    over = rng.choice(n, heavy, replace=False)
+    deg[over] = rng.integers(300, 900, heavy)
+    indptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int64)
+    indices = rng.integers(0, n, int(indptr[-1])).astype(np.int32)
+    return CSRTopo(indptr=indptr, indices=indices), deg
+
+
+def _spans(topo, ids, live, U):
+    ip = np.asarray(topo.indptr)
+    start, deg = ip[ids], np.where(live, ip[ids + 1] - ip[ids], 0)
+    return (((start + np.maximum(deg - 1, 0)) >> 7) - (start >> 7)) >= U
+
+
+@pytest.mark.parametrize("heavy", list(HEAVY))
+def test_default_window_op_equals_xla(heavy):
+    """One hop at the shipped constants, every field to the bit: no target,
+    some targets (the compacted fallback) and more than the fallback's
+    slots (the whole hop per draw) over the window; dead targets."""
+    import jax.numpy as jnp
+
+    from quiver_tpu.ops.blockgather import (DEFAULT_U, FALLBACK_FRAC,
+                                            fallback_slots)
+    from quiver_tpu.ops.sample import sample_neighbors
+
+    assert (DEFAULT_U, FALLBACK_FRAC) == (2, 1 / 32)
+    topo, deg = _windowed_graph(HEAVY[heavy])
+    indptr, indices = topo.to_device()
+    n = len(deg)
+    seeds = jnp.arange(n, dtype=jnp.int32)
+    live = np.arange(n) % 7 != 3
+    key = jax.random.PRNGKey(4)
+    outs = [sample_neighbors(indptr, indices, seeds, 5, key,
+                             seed_mask=jnp.asarray(live), gather_mode=gm,
+                             sample_rng="hash")
+            for gm in ("xla", "blocked")]
+    for field in ("nbrs", "mask", "counts", "eid"):
+        np.testing.assert_array_equal(np.asarray(getattr(outs[0], field)),
+                                      np.asarray(getattr(outs[1], field)))
+    assert outs[0].nfall is None
+    misses = int(_spans(topo, np.arange(n), live, DEFAULT_U).sum())
+    assert int(outs[1].nfall) == misses
+    S = fallback_slots(n)
+    assert S == 8
+    assert {"none": misses == 0, "some": 0 < misses <= S,
+            "many": misses > S}[heavy]
+
+
+@pytest.mark.parametrize("heavy", list(HEAVY))
+def test_default_window_sampler_equals_xla_and_counts(heavy):
+    """Through ``GraphSageSampler`` with ``return_eid``: the batch is the
+    xla one to the bit, ``window_stats`` says per hop who was served how,
+    and ``overflow_stats`` reads as before."""
+    from quiver_tpu import GraphSageSampler
+    from quiver_tpu.ops.blockgather import DEFAULT_U, fallback_slots
+
+    topo, deg = _windowed_graph(HEAVY[heavy])
+    seeds = np.arange(64, dtype=np.int64)
+    key = jax.random.PRNGKey(5)
+    sizes = [5, 3, 2]
+    kw = dict(sample_rng="hash", dedup="none", return_eid=True)
+    b_x = GraphSageSampler(topo, sizes, gather_mode="xla",
+                           **kw).sample(seeds, key=key)
+    s_b = GraphSageSampler(topo, sizes, gather_mode=f"blocked:{DEFAULT_U}",
+                           **kw)
+    b_b = s_b.sample(seeds, key=key)
+    np.testing.assert_array_equal(np.asarray(b_x.n_id), np.asarray(b_b.n_id))
+    np.testing.assert_array_equal(np.asarray(b_x.n_id_mask),
+                                  np.asarray(b_b.n_id_mask))
+    for lx, lb in zip(b_x.layers, b_b.layers):
+        for field in ("nbr_local", "mask", "eid"):
+            np.testing.assert_array_equal(np.asarray(getattr(lx, field)),
+                                          np.asarray(getattr(lb, field)))
+
+    stats = s_b.window_stats(b_b)
+    assert stats == s_b.window_stats()
+    assert len(stats) == 3
+    # hop 3 has k = 2 <= U: no window route, the per-draw path alone
+    assert stats[2] == {"window": 0, "fallback": 0, "classic": True}
+    n_id, n_mask = np.asarray(b_b.n_id), np.asarray(b_b.n_id_mask)
+    for hop, targets in ((0, 64), (1, 64 * 6)):
+        miss = int(_spans(topo, n_id[:targets], n_mask[:targets],
+                          DEFAULT_U).sum())
+        if miss > fallback_slots(targets):
+            want = {"window": 0, "fallback": 0, "classic": True}
+        else:
+            want = {"window": targets - miss, "fallback": miss,
+                    "classic": False}
+        assert stats[hop] == want, (hop, miss)
+    if heavy == "none":
+        assert not any(s["fallback"] or s["classic"] for s in stats[:2])
+    else:
+        assert any(s["fallback"] or s["classic"] for s in stats[:2])
+    # modes with no window route report none; the frontier-cap drops
+    # keep their meaning under every mode
+    assert all(s == {"window": 0, "fallback": 0, "classic": True}
+               for s in GraphSageSampler(topo, sizes, gather_mode="xla",
+                                         **kw).window_stats(b_x))
+    np.testing.assert_array_equal(s_b.overflow_stats(b_b), [0, 0, 0])
+    np.testing.assert_array_equal(s_b.overflow_stats(), [0, 0, 0])
+
+
+def test_window_counters_reach_the_registry():
+    """The sampler-level ``window_stats()`` counts fallback targets and
+    wholesale per-draw hops into the registry once per ``sample`` call,
+    as ``overflow_stats()`` does the frontier-cap drops."""
+    from quiver_tpu import GraphSageSampler, telemetry
+
+    topo, _ = _windowed_graph(HEAVY["many"])
+    s = GraphSageSampler(topo, [5, 3], gather_mode="blocked",
+                         sample_rng="hash", dedup="none")
+
+    def read(name):
+        return telemetry.counter(name, mode="tpu").value
+
+    names = ("sampler_window_fallback_targets_total",
+             "sampler_window_classic_hops_total")
+    before = [read(n) for n in names]
+    assert s.window_stats() is None
+    s.sample(np.arange(64, dtype=np.int64), key=jax.random.PRNGKey(5))
+    stats = s.window_stats()
+    after = [read(n) for n in names]
+    assert after[0] - before[0] == sum(h["fallback"] for h in stats)
+    assert after[1] - before[1] == sum(h["classic"] for h in stats) > 0
+    s.window_stats()                       # a second read counts nothing
+    assert [read(n) for n in names] == after
+
+
+def test_hop_within_block_width_lowers_without_cond():
+    """k <= U: a window of U rows saves nothing over k per-draw rows, so
+    the hop lowers to the per-draw path alone; k > U is routed."""
+    import jax.numpy as jnp
+
+    from quiver_tpu.ops.sample import sample_neighbors
+
+    topo, deg = _windowed_graph(0)
+    indptr, indices = topo.to_device()
+    seeds = jnp.arange(64, dtype=jnp.int32)
+
+    def lowered(k):
+        return sample_neighbors.lower(
+            indptr, indices, seeds, k, jax.random.PRNGKey(0),
+            gather_mode="blocked:2", sample_rng="hash").as_text()
+
+    assert "stablehlo.case" not in lowered(2)
+    assert "stablehlo.sort" not in lowered(2)
+    assert "stablehlo.case" in lowered(3)
