@@ -28,11 +28,6 @@ BASELINE_FEATURE_GBS = 14.82  # docs/Introduction_en.md:95
 BASELINE_EPOCH_S = 11.1       # docs/Introduction_en.md:146 (1-GPU quiver)
 BASELINE_REDDIT_SEPS = 33.15e6  # docs/Introduction_en.md:43 ([25,10] UVA)
 
-GATHER_MODES_VERSION = 4  # bump when the gather-mode set changes
-PROBE_MODES = ("pwindow:2", "pwindow:3", "pwindow:4",
-               "pallas", "blocked:2", "blocked:3", "blocked:4", "lanes",
-               "lanes_fused", "xla")
-
 PRODUCTS_NODES, PRODUCTS_EDGES = 2_449_029, 123_718_280
 PRODUCTS_TRAIN = 196_615      # ogbn-products train split size
 FANOUT = [15, 10, 5]
@@ -149,147 +144,6 @@ def build_graph(n_nodes, n_edges, seed=0):
 
 
 # ---------------------------------------------------------------- sampling
-def probe_sampler(topo, gather_mode, sizes, probe_b, sample_rng="auto"):
-    """Compile + steady-time ONE sampler config on ``topo``; returns
-    ms/batch or raises what the compiler raised.
-
-    Runs in THIS process: the chip belongs to one process at a time, so
-    a child that needs it fails or hangs while the parent holds it.
-    Shared by ``pick_gather_mode`` and ``benchmarks/autotune.py``.
-    """
-    from quiver_tpu import GraphSageSampler
-
-    s = GraphSageSampler(topo, list(sizes), gather_mode=gather_mode,
-                         sample_rng=sample_rng, dedup="none")
-    seeds = np.random.default_rng(1).integers(
-        0, topo.node_count, probe_b).astype(np.int32)
-    s.sample(seeds, key=_mk(0)).n_id.block_until_ready()
-    t0 = time.perf_counter()
-    for r in range(3):
-        s.sample(seeds, key=_mk(1 + r)).n_id.block_until_ready()
-    return (time.perf_counter() - t0) / 3 * 1e3
-
-
-def _tuned_path(path=None):
-    return path or os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        ".quiver_tpu_tuned.json")
-
-
-def merge_tuned(updates: dict, backend: str, path=None):
-    """MERGE measured winners into the tuned file — never whole-file
-    rewrite: the gather probe and the dedup A/B run at different points
-    of a window and each must not erase the other's key (or autotune's
-    sample_rng).  The file is per-backend ("backends" map, v2) so a CPU
-    rehearsal's probe can never delete TPU-measured evidence either;
-    legacy flat v1 files are upgraded in place."""
-    tuned_path = _tuned_path(path)
-    backends = {}
-    try:
-        loaded = json.load(open(tuned_path))
-        if isinstance(loaded, dict):
-            if isinstance(loaded.get("backends"), dict):
-                backends = loaded["backends"]
-            elif loaded.get("backend"):  # v1 flat: file under its tag
-                b1 = loaded.pop("backend")
-                backends = {b1: loaded}
-    except Exception:
-        pass
-    entry = backends.get(backend)
-    if not isinstance(entry, dict):
-        entry = {}
-    entry.update(updates)
-    backends[backend] = entry
-    try:
-        with open(tuned_path, "w") as fh:
-            json.dump({"backends": backends}, fh, indent=2)
-    except Exception as e:  # pragma: no cover
-        log(f"could not write tuned file: {e}")
-    return entry
-
-
-def read_tuned(backend: str, path=None) -> dict:
-    """This backend's tuned entry (v2 per-backend or legacy flat v1);
-    {} when absent/unreadable."""
-    try:
-        loaded = json.load(open(_tuned_path(path)))
-        if isinstance(loaded.get("backends"), dict):
-            entry = loaded["backends"].get(backend)
-            return entry if isinstance(entry, dict) else {}
-        if loaded.get("backend") == backend:
-            return loaded
-    except Exception:
-        pass
-    return {}
-
-
-def persist_dedup_winner(sections, backend, path=None):
-    """Flip the library's dedup default to whatever the ON-CHIP e2e A/B
-    measured (a CPU rehearsal once inverted the sampling-microbenchmark
-    ranking end to end, so the decision must ride the full-pipeline
-    measurement; on the chip: not measured).  Writes
-    ``dedup`` into the tuned file the config auto-loads
-    (``resolve_dedup``); never persists CPU evidence."""
-    e2e = sections.get("e2e") or {}
-    hop = sections.get("e2e_dedup_hop") or {}
-    if (backend == "cpu" or "source" in e2e or "source" in hop
-            or not e2e.get("ms_per_step") or not hop.get("ms_per_step")
-            # both halves must ride the SAME, KNOWN gather mode (a
-            # section without the stamp must not slip through as
-            # None == None)
-            or not e2e.get("gather_mode") or not hop.get("gather_mode")
-            or e2e["gather_mode"] != hop["gather_mode"]):
-        return None
-    winner = "hop" if hop["ms_per_step"] < e2e["ms_per_step"] else "none"
-    merge_tuned(
-        {"dedup": winner,
-         "dedup_evidence": {"e2e_none_ms": e2e["ms_per_step"],
-                            "e2e_hop_ms": hop["ms_per_step"]}},
-        backend, path)
-    log(f"dedup default -> {winner} (e2e A/B: none "
-        f"{e2e['ms_per_step']} vs hop {hop['ms_per_step']} ms/step, "
-        f"persisted to tuned file)")
-    return winner
-
-
-def pick_gather_mode(topo, batch_size, sizes):
-    """Pick the element-gather mode: tuned file if probed before on this
-    backend, else probe each mode at a small batch, in this process, on
-    the graph already on the device, and persist the winner.  A mode the
-    compiler refuses is logged and left out."""
-    import jax
-
-    tuned = read_tuned(jax.default_backend())
-    # a tuned file from before the current mode set must re-probe:
-    # round 3 added "blocked", which a pinned "lanes" would otherwise
-    # shadow forever
-    if (tuned.get("gather_mode")
-            and tuned.get("modes_version") == GATHER_MODES_VERSION):
-        log(f"gather_mode={tuned['gather_mode']} (tuned file)")
-        return tuned["gather_mode"]
-
-    probe_b = min(256, batch_size)
-    results = {}
-    for gm in PROBE_MODES:
-        try:
-            results[gm] = probe_sampler(topo, gm, sizes, probe_b)
-        except Exception as e:  # noqa: BLE001 — a refused mode is a result
-            log(f"gather_mode={gm}: refused ({type(e).__name__}: "
-                f"{str(e)[:300]})")
-            continue
-        log(f"gather_mode={gm}: {results[gm]:.1f} ms/batch (B={probe_b})")
-    if not results:
-        raise RuntimeError("every gather mode failed its probe")
-    best_mode = min(results, key=results.get)
-    log(f"selected gather_mode={best_mode}")
-    # persist for future sessions (config auto-loads this); merge so the
-    # dedup winner / autotune rng written earlier survive
-    merge_tuned({"gather_mode": best_mode,
-                 "modes_version": GATHER_MODES_VERSION},
-                jax.default_backend())
-    return best_mode
-
-
 def hop_caps(batch_size, sizes, frac=0.5):
     """Frontier caps for ``dedup="hop"``: each hop's unique set on
     power-law graphs sits well under the no-dedup bound (~35% at hop 3
@@ -1670,7 +1524,7 @@ def main():
     ap.add_argument("--ab-dedup", action="store_true",
                     help="also measure dedup='hop' for sampling + e2e")
     ap.add_argument("--gather-mode", default=None,
-                    help="skip the probe and use this mode")
+                    help="xla | blocked; default: the backend's")
     ap.add_argument("--trace", nargs="?", const="timeline_trace.json",
                     default=None, metavar="PATH",
                     help="run the compact cross-subsystem timeline "
@@ -1759,13 +1613,9 @@ def main():
     runner = _SectionRunner()
     sections = runner.sections  # live view: filled as we go
 
-    # ONE gather mode for the whole run, decided before any section: the
-    # forced one, the library default on a smoke run, else the probe's
-    # winner (in this process, on the uploaded graph)
-    if args.gather_mode or args.small or "sampling" not in want:
-        gm = args.gather_mode or resolve_gather_mode("auto")
-    else:
-        gm = pick_gather_mode(topo, batches[0], FANOUT)
+    # ONE gather path for the whole run: the forced one, else what the
+    # library resolves for this backend
+    gm = args.gather_mode or resolve_gather_mode("auto")
 
     if "feature" in want:
         runner.run("feature", 600,
@@ -1791,8 +1641,6 @@ def main():
                        lambda: bench_e2e(topo, feat_dim, classes, B,
                                          e2e_steps, dedup="hop",
                                          gather_mode=gm))
-            if not args.small:
-                persist_dedup_winner(sections, jax.default_backend())
 
         def _bf16():
             import jax.numpy as jnp

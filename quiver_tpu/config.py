@@ -27,14 +27,6 @@ def _env(name: str, default, cast=str):
 
 @dataclass
 class Config:
-    # sampler
-    gather_mode: str = field(
-        default_factory=lambda: _env("GATHER_MODE", "auto")
-    )
-    sample_rng: str = field(
-        default_factory=lambda: _env("SAMPLE_RNG", "auto")
-    )
-    dedup: str = field(default_factory=lambda: _env("DEDUP", "auto"))
     # feature store
     cache_policy: str = field(
         default_factory=lambda: _env("CACHE_POLICY", "device_replicate")
@@ -418,198 +410,69 @@ class Config:
 _config: Optional[Config] = None
 
 
-def _load_tuned(cfg: Config, path: Optional[str] = None):
-    """Fold in hardware-probed defaults (benchmarks/autotune.py), if any.
-    Explicit env vars still win."""
-    import json
-
-    if path is None:
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".quiver_tpu_tuned.json",
-        )
-    if not os.path.exists(path):
-        return
-    try:
-        tuned = json.load(open(path))
-        if not isinstance(tuned, dict):
-            return
-    except Exception:
-        return
-    # only apply results probed on THIS backend (a cpu-probed choice must
-    # not override the TPU default and vice versa).  v2 files keep one
-    # entry per backend under "backends" (bench.merge_tuned), so probing
-    # on one backend can never erase another's evidence; flat v1 files
-    # carry a single top-level "backend" tag.
-    try:
+def _accelerator(backend: Optional[str]) -> bool:
+    """Whether ``backend`` (``None``: the one JAX runs on) is not the
+    CPU.  The tests and ``__graft_entry__.dryrun_multichip`` pass
+    ``"tpu"`` to ask, with no chip attached, what a TPU resolves to."""
+    if backend is None:
         import jax
 
         backend = jax.default_backend()
-    except Exception:
-        return
-    if isinstance(tuned.get("backends"), dict):
-        tuned = tuned["backends"].get(backend)
-        if not isinstance(tuned, dict):
-            return
-    elif tuned.get("backend") != backend:
-        return
-    gm = tuned.get("gather_mode")
-    # a malformed tuned value ("blocked:0", "blockedx") is ignored like
-    # every other invalid tuned value, not deferred to crash in
-    # resolve_gather_mode later
-    if (cfg.gather_mode == "auto" and isinstance(gm, str)
-            and gm != "auto" and _is_valid_gather_mode(gm)):
-        cfg.gather_mode = gm
-    if (cfg.sample_rng == "auto"
-            and tuned.get("sample_rng") in ("key", "hash")):
-        cfg.sample_rng = tuned["sample_rng"]
-    if cfg.dedup == "auto" and tuned.get("dedup") in ("none", "hop"):
-        # written by bench.py's on-chip e2e none-vs-hop A/B — the
-        # full-pipeline measurement, not the sampling microbenchmark
-        # (the CPU dress rehearsal showed they can disagree)
-        cfg.dedup = tuned["dedup"]
+    return backend != "cpu"
+
+
+def resolve_gather_mode(gather_mode: str,
+                        backend: Optional[str] = None) -> str:
+    """The hop's gather path: an explicit ``"xla"`` or ``"blocked"``
+    wins, ``"auto"`` is the backend's.
+
+    ``"xla"``: ``jnp.take`` per draw, the CPU's path and the reference
+    the tests compare against.  ``"blocked"``: the window fetch of
+    ``ops.blockgather`` (two 128-lane rows of ``indices`` per target
+    serve its k draws; the ``[B]``-shaped ``indptr`` reads and the
+    fallback's draws go through row gather + VPU lane select), the
+    accelerator's path.  Measured on one TPU v5e in both cells of
+    ``BENCHMARK.json`` against a 512-B row per draw, every draw the same
+    to the bit (ledger, PR 31): 21,207 -> 28,517 seeds/s in
+    ``papers100m-sage.train-fused`` (the hops 21.0 -> 8.6 ms of the
+    step) and 10,486 -> 11,073 in ``mag240m-rgat.train-fused-typed``
+    (6.6 -> 1.4 ms).  The paths that lost (a Pallas twin of the window,
+    a DMA per draw, a Pallas lane select) are in the history at ab18fb6.
+    """
+    if gather_mode not in ("auto", "xla", "blocked"):
+        raise ValueError(
+            f"gather_mode must be auto | xla | blocked, got "
+            f"{gather_mode!r}")
+    if gather_mode != "auto":
+        return gather_mode
+    return "blocked" if _accelerator(backend) else "xla"
 
 
 def resolve_sample_rng(sample_rng: str,
-                       gather_mode: Optional[str] = None) -> str:
-    """Map ``"auto"`` to the backend-measured best uniform source.
-
-    Resolution order: explicit kwarg > gather-mode requirement >
-    ``QUIVER_TPU_SAMPLE_RNG`` env / tuned file > backend default.
-    Backend default: ``"hash"`` (counter-hash uniforms) on accelerators
-    and ``"key"`` (key-based ``jax.random.uniform``) on CPU, where
-    threefry is fast and tests want reproducible streams.  hash against
-    threefry / rbg on the chip at a real graph size: not measured
-    (ROADMAP D10) — the default is a choice, not a result.
-
-    ``gather_mode`` (the RESOLVED mode, if the caller has one): the
-    fused Pallas window kernel (``pwindow``) only supports the in-kernel
-    counter-hash, so ``auto`` resolves to ``"hash"`` under it regardless
-    of backend — an explicit ``"key"`` still reaches the op and raises
-    there (the user's choice is surfaced, not silently overridden).
-    """
+                       backend: Optional[str] = None) -> str:
+    """The hop's uniform source: an explicit ``"key"`` or ``"hash"``
+    wins, ``"auto"`` is the backend's: ``"hash"`` (counter-hash
+    uniforms) on an accelerator, ``"key"`` (``jax.random.uniform``) on
+    the CPU, where threefry is fast and tests want reproducible
+    streams.  hash against threefry / rbg on the chip at a real graph
+    size: not measured (ROADMAP D3) - the default is a choice, not a
+    result."""
     if sample_rng not in ("auto", "key", "hash"):
         raise ValueError(f"sample_rng must be auto|key|hash, got "
                          f"{sample_rng!r}")
     if sample_rng != "auto":
         return sample_rng
-    if gather_mode is not None and gather_mode.startswith("pwindow"):
-        cfg = get_config()
-        if cfg.sample_rng == "key":
-            # the pin came from QUIVER_TPU_SAMPLE_RNG / the tuned file,
-            # not an explicit kwarg (that returned above) — surface the
-            # override instead of silently ignoring the pin
-            import warnings
-
-            warnings.warn(
-                "sample_rng='key' pinned via env/tuned file is "
-                "overridden to 'hash': gather_mode='pwindow' fuses the "
-                "counter-hash RNG in-kernel. Pass sample_rng='key' "
-                "explicitly to get a hard error, or pick a "
-                "'blocked:U'/'lanes' gather mode to keep key-based "
-                "draws.", stacklevel=2)
-        return "hash"
-    cfg = get_config()
-    if cfg.sample_rng != "auto":
-        return resolve_sample_rng(cfg.sample_rng)  # validates env/tuned too
-    import jax
-
-    return "hash" if jax.default_backend() not in ("cpu",) else "key"
+    return "hash" if _accelerator(backend) else "key"
 
 
 def resolve_dedup(dedup: str) -> str:
-    """Map ``"auto"`` to the measured frontier-dedup default.
-
-    Resolution order: explicit kwarg > ``QUIVER_TPU_DEDUP`` env / tuned
-    file (written by bench.py's on-chip e2e none-vs-hop A/B) > "none"
-    (the positional-relabel hot path — round-2's sampling
-    microbenchmarks; the e2e A/B may overturn it, which is exactly what
-    the tuned overlay is for).
-    """
+    """The frontier's dedup: an explicit ``"none"`` or ``"hop"`` wins,
+    ``"auto"`` is ``"none"`` (the positional-relabel hot path) on every
+    backend.  ``"hop"`` against it end to end on the chip: not measured
+    (ROADMAP S2 (b)) - a choice, not a result."""
     if dedup not in ("auto", "none", "hop"):
         raise ValueError(f"dedup must be auto|none|hop, got {dedup!r}")
-    if dedup != "auto":
-        return dedup
-    cfg = get_config()
-    if cfg.dedup != "auto":
-        return resolve_dedup(cfg.dedup)
-    return "none"
-
-
-def _validate_gather_mode(gm) -> None:
-    """One validator shared by the tuned-file loader (which catches and
-    skips) and resolve_gather_mode (which lets it raise) — keeps
-    parse_blocked's specific diagnostics ("blocked:U needs U >= 1")
-    instead of a generic mode-list message."""
-    if gm in ("auto", "xla", "lanes", "lanes_fused", "pallas"):
-        return
-    if isinstance(gm, str) and gm.startswith("blocked"):
-        from .ops.blockgather import parse_blocked
-
-        parse_blocked(gm)
-        return
-    if isinstance(gm, str) and gm.startswith("pwindow"):
-        from .ops.pallas.window_sample_kernel import parse_pwindow
-
-        parse_pwindow(gm)
-        return
-    raise ValueError(
-        f"gather_mode must be one of (auto, xla, lanes, lanes_fused, "
-        f"pallas) or 'blocked[:U]' or 'pwindow[:U]', got {gm!r}")
-
-
-def _is_valid_gather_mode(gm) -> bool:
-    try:
-        _validate_gather_mode(gm)
-    except Exception:
-        return False
-    return True
-
-
-def resolve_gather_mode(gather_mode: str,
-                        sample_rng: Optional[str] = None) -> str:
-    """Map ``"auto"`` to the backend-measured best element-gather mode.
-
-    Resolution order: explicit kwarg > ``QUIVER_TPU_GATHER_MODE`` env /
-    tuned file > backend default.  Backend default: on accelerators the
-    window fetch ``"blocked:U"`` at ``ops.blockgather.DEFAULT_U`` (one
-    block of U 128-lane rows of ``indices`` per target serves its k
-    draws; the scattered ``indptr`` reads ride ``lanes``, row-gather +
-    VPU lane select); plain ``"xla"`` take on CPU.  Measured, PR 31, on
-    one TPU v5e in both cells of ``BENCHMARK.json`` against ``lanes``,
-    the default until then: 21,203 -> 28,570 seeds/s in
-    ``papers100m-sage.train-fused`` (the hops 21.0 -> 8.6 ms of the
-    step) and 10,486 -> 11,074 in ``mag240m-rgat.train-fused-typed``
-    (6.6 -> 1.4 ms), every draw the same to the bit (PERF.md section
-    6).  ``pwindow:2`` against it, once, in the first cell: 0.3 ms a
-    step slower; ``lanes_fused``: not measured (ROADMAP S3).
-
-    ``sample_rng`` (the caller's RAW kwarg): when ``auto`` resolution
-    lands on the Pallas ``pwindow`` kernel (hash-RNG-only) but the user
-    explicitly asked for ``sample_rng="key"``, the choice degrades to
-    the equivalent XLA ``blocked`` window mode instead of crashing a
-    config the user never chose.  An EXPLICIT ``gather_mode="pwindow"``
-    with ``"key"`` still raises at the op (the user's own combination is
-    surfaced, not rewritten).
-    """
-    _validate_gather_mode(gather_mode)
-    if gather_mode != "auto":
-        return gather_mode
-    cfg = get_config()
-    if cfg.gather_mode != "auto":
-        resolved = resolve_gather_mode(cfg.gather_mode)
-    else:
-        import jax
-
-        if jax.default_backend() in ("cpu",):
-            resolved = "xla"
-        else:
-            from .ops.blockgather import DEFAULT_U
-
-            resolved = f"blocked:{DEFAULT_U}"
-    if resolved.startswith("pwindow") and sample_rng == "key":
-        resolved = "blocked" + resolved[len("pwindow"):]
-    return resolved
+    return "none" if dedup == "auto" else dedup
 
 
 # config is frozen once per process, so anything read off it is
@@ -620,7 +483,6 @@ def get_config() -> Config:
     global _config
     if _config is None:
         _config = Config()
-        _load_tuned(_config)
         if _config.trace:
             from .utils import trace as _t
 

@@ -28,7 +28,8 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
                       steps: int = 1, classes: int = 8, hidden: int = 32,
                       lr: float = 3e-3, seed: int = 0,
                       learnable_labels: bool = True,
-                      hier: Optional[tuple] = None):
+                      hier: Optional[tuple] = None,
+                      gather_mode: str = "auto", sample_rng: str = "auto"):
     """Run ``steps`` DP training steps over an ``n_devices`` mesh.
 
     Returns a dict with per-step ``losses``, the sampler's summed overflow
@@ -42,7 +43,8 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
     ``hier=(n_hosts, hot_frac)`` swaps the flat DistFeature for the
     two-tier :class:`HierFeature` over a ``[n_hosts, n_devices/n_hosts]``
     DCN x ICI mesh (degree-ordered hot set); the result dict then also
-    carries summed ``dcn_crossings``.
+    carries summed ``dcn_crossings``.  ``gather_mode`` / ``sample_rng``
+    go to the sampler as they are.
     """
     import jax
     import jax.numpy as jnp
@@ -94,7 +96,9 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
         g2h = rng.integers(0, n_devices, topo.node_count).astype(np.int32)
         info = PartitionInfo(host=0, hosts=n_devices, global2host=g2h)
         dist_feat = DistFeature.from_global_feature(feat, mesh, info)
-    sampler = DistGraphSampler(topo, mesh, sizes=list(sizes))
+    sampler = DistGraphSampler(topo, mesh, sizes=list(sizes),
+                               gather_mode=gather_mode,
+                               sample_rng=sample_rng)
 
     model = GraphSAGE(hidden=hidden, out_dim=classes, num_layers=len(sizes),
                       dropout=0.0)

@@ -119,29 +119,15 @@ class DistGraphSampler:
         self.topo = topo
         self.mesh = mesh
         self.axis = axis
-        gm = resolve_gather_mode(gather_mode, sample_rng)
-        # rng resolves against the PRE-rewrite mode so auto still lands
-        # on "hash" under a pwindow pick — keeping the per-shard draws
-        # identical to the single-device pwindow stream
-        self.sample_rng = resolve_sample_rng(sample_rng, gm)
-        # pallas_call outputs need explicit vma annotations under
-        # shard_map (jax >= 0.8 check_vma); until the kernels carry
-        # them, every pallas-backed mode degrades to its XLA equivalent
-        # for the per-shard local sampling: pwindow -> blocked (same
-        # windows, same draws), pallas/lanes_fused -> lanes (same
-        # row-gather + lane select, XLA-composed)
-        if gm.startswith("pwindow"):
-            gm = "blocked" + gm[len("pwindow"):]
-        elif gm in ("pallas", "lanes_fused"):
-            gm = "lanes"
-        self.gather_mode = gm
+        self.gather_mode = resolve_gather_mode(gather_mode)
+        self.sample_rng = resolve_sample_rng(sample_rng)
         self.sizes = list(sizes)
         self.n = int(mesh.shape[axis])
         self.request_cap_frac = request_cap_frac
         row_starts, lips, lids = shard_csr_by_rows(topo, self.n)
         self.row_starts = jnp.asarray(row_starts, jnp.int32)
         # pad local shards to a common size, stack, shard over the mesh
-        # (round up to 128 so the lanes gather's 128-lane reshape covers
+        # (round up to 128 so the element gather's 128-lane reshape covers
         # the whole table — its tail truncation must never drop real rows)
         r128 = lambda v: -(-v // 128) * 128
         max_ip = r128(max(len(x) for x in lips))

@@ -143,8 +143,8 @@ class HeteroGraphSageSampler:
         self.topo = topo
         from .config import resolve_gather_mode, resolve_sample_rng
 
-        self.gather_mode = resolve_gather_mode(gather_mode, sample_rng)
-        self.sample_rng = resolve_sample_rng(sample_rng, self.gather_mode)
+        self.gather_mode = resolve_gather_mode(gather_mode)
+        self.sample_rng = resolve_sample_rng(sample_rng)
         if isinstance(sizes, (list, tuple)):
             self.hop_sizes = [self._norm(s) for s in sizes]
         else:

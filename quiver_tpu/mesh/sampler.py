@@ -54,7 +54,8 @@ class MeshSampler:
                  gather_mode: str = "xla", sample_rng: str = "auto"):
         import jax
 
-        from ..config import get_config, resolve_sample_rng
+        from ..config import (get_config, resolve_gather_mode,
+                              resolve_sample_rng)
 
         cfg = get_config()
         if n_shards is None:
@@ -66,23 +67,26 @@ class MeshSampler:
                 f"is off); got {self.n_shards}")
         self.mesh = mesh if mesh is not None else build_mesh(self.n_shards)
         self.axis = SHARD_AXIS
-        self.gather_mode = gather_mode
-        self.sample_rng = resolve_sample_rng(sample_rng, gather_mode)
+        self.gather_mode = resolve_gather_mode(gather_mode)
+        self.sample_rng = resolve_sample_rng(sample_rng)
         indptr = np.asarray(indptr, dtype=np.int32)
         indices = np.asarray(indices, dtype=np.int32)
         self.node_count = len(indptr) - 1
         self.rows_per_shard, self.ranges = shard_ranges(
             self.node_count, self.n_shards)
         # one pow2 edge bucket over the largest shard: uniform shapes ->
-        # ONE sampling executable reused by every shard
-        edge_pad = _pow2(max(
-            int(indptr[hi] - indptr[lo]) for lo, hi in self.ranges))
+        # ONE sampling executable reused by every shard.  Every table a
+        # multiple of 128, as ``CSRTopo.to_device`` pads: what the
+        # ``blocked`` gather path asks of a table
+        edge_pad = max(128, _pow2(max(
+            int(indptr[hi] - indptr[lo]) for lo, hi in self.ranges)))
+        ip_pad = -(-(self.rows_per_shard + 1) // 128) * 128
         # each shard's CSR (and its empty overlay) is COMMITTED to that
         # shard's device, so its hop runs there on data that never left
         self._devices = shard_devices(self.mesh)[0]
         self._indptr, self._indices, self._overlay = [], [], []
         for (lo, hi), dev in zip(self.ranges, self._devices):
-            lp = np.zeros(self.rows_per_shard + 1, dtype=np.int32)
+            lp = np.zeros(ip_pad, dtype=np.int32)
             lp[: hi - lo + 1] = indptr[lo:hi + 1] - indptr[lo]
             lp[hi - lo + 1:] = lp[hi - lo]      # pad rows: degree 0
             li = np.zeros(edge_pad, dtype=np.int32)
@@ -94,7 +98,7 @@ class MeshSampler:
             # sampler
             self._overlay.append(tuple(
                 jax.device_put(np.zeros(n, dtype=np.int32), dev)
-                for n in (edge_pad, self.rows_per_shard + 1, 8)))
+                for n in (edge_pad, ip_pad, 128)))
         self._sharding = row_shard(self.mesh)
         self._edge_base = np.asarray(
             [int(indptr[lo]) for lo, _ in self.ranges], dtype=np.int32)

@@ -4,7 +4,7 @@ of a target.
 The k draws of one target all read the same contiguous CSR window
 ``indices[start:end)`` (the reference's warp kernel exploits exactly this
 contiguity with warp-wide coalesced loads, ``cuda_random.cu.hpp:8-69``).
-The plain ``lanes`` mode ignores it: every draw pays an independent
+A per-draw ``element_gather`` ignores it: every draw pays an independent
 [128]-row fetch, 128x the payload per element, and on the chip a row
 gather is bound by the number of rows asked for, not by their bytes.
 Here a target whose window spans at most ``U`` 128-lane rows is served
@@ -49,34 +49,13 @@ import jax.numpy as jnp
 from .fastgather import LANES, element_gather
 
 __all__ = ["blocked_window_gather", "blocked_weighted_positions",
-           "fallback_slots", "parse_blocked", "NO_WINDOW"]
+           "fallback_slots", "NO_WINDOW"]
 
 DEFAULT_U = 2
 FALLBACK_FRAC = 1 / 32
 # what ``blocked_window_gather`` reports in place of a count for a hop
 # that has no window route at all (k <= U)
 NO_WINDOW = -1
-
-
-def parse_u_mode(mode: str, prefix: str, default: int = DEFAULT_U) -> int:
-    """Parse ``"<prefix>"`` -> ``default`` / ``"<prefix>:4"`` -> 4.
-    Anything else (e.g. the typo ``"blocked4"``) raises instead of
-    silently running with the default block width.  Shared by the
-    ``blocked`` (XLA) and ``pwindow`` (Pallas) window-gather modes."""
-    if mode == prefix:
-        return default
-    if mode.startswith(prefix + ":"):
-        u = int(mode.split(":", 1)[1])  # ValueError on a bad suffix
-        if u < 1:
-            raise ValueError(f"{prefix}:U needs U >= 1, got {mode!r}")
-        return u
-    raise ValueError(
-        f"{prefix} gather mode must be '{prefix}' or '{prefix}:U', got "
-        f"{mode!r}")
-
-
-def parse_blocked(mode: str) -> int:
-    return parse_u_mode(mode, "blocked")
 
 
 def fallback_slots(B: int, fallback_frac: float = FALLBACK_FRAC) -> int:

@@ -44,7 +44,7 @@ def pad_table_128(table, fill=None):
 
     ``fill=None`` zero-pads; otherwise pads with ``fill`` (e.g. the last
     cumulative weight so clipped probes read a harmless value).  The
-    lanes/pallas gather modes REQUIRE 128-multiple tables — ``_gather``
+    ``blocked`` gather path REQUIRES 128-multiple tables — ``_gather``
     rejects anything else rather than silently truncating.
     """
     n = table.shape[0]
@@ -57,34 +57,18 @@ def pad_table_128(table, fill=None):
     )
 
 
-def element_gather(table2d: jax.Array, idx: jax.Array,
-                   fused: bool = False) -> jax.Array:
+def element_gather(table2d: jax.Array, idx: jax.Array) -> jax.Array:
     """``table.reshape(-1)[idx]`` via row gather + lane select.
 
     Args:
       table2d: ``[rows, 128]`` (from :func:`prepare_table`).
       idx: any-shape int32 flat element indices (must be < rows*128).
-      fused: run the lane reduction as a Pallas kernel
-        (``ops.pallas.element_gather_kernel``) so the ``[M, 128]`` row
-        blocks stream through VMEM instead of landing in HBM.  Same
-        result; pick by benchmark.
     """
     shape = idx.shape
     flat = idx.reshape(-1)
     row = jax.lax.shift_right_logical(flat, 7)
     lane = jnp.bitwise_and(flat, LANES - 1)
     rows = jnp.take(table2d, row, axis=0)              # [M, 128] row gather
-    if fused:
-        from .pallas.element_gather_kernel import lane_select, BLK
-
-        m = flat.shape[0]
-        pad = (-m) % BLK
-        if pad:
-            rows = jnp.concatenate(
-                [rows, jnp.zeros((pad, LANES), rows.dtype)]
-            )
-            lane = jnp.concatenate([lane, jnp.zeros((pad,), lane.dtype)])
-        return lane_select(rows, lane)[:m].reshape(shape)
     onehot = (
         lane[:, None]
         == jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)

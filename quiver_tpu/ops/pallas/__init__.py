@@ -1,10 +1,6 @@
-"""Pallas TPU kernels (hot-path variants of the XLA ops).
+"""Pallas TPU kernels of the feature store (variants of its XLA gathers).
 
 * ``gather_kernel`` — DMA row gather over a budgeted feature table.
-* ``element_gather_kernel`` — per-element DMA gather (on-chip time: not
-  measured).
-* ``sample_gather_kernel`` / ``window_sample_kernel`` — fused PRNG +
-  per-seed window DMA + lane select for sampling.
 * ``page_gather_kernel`` — ragged whole-page gather for the paged
   feature store (``ops/paged.py``): pipelined page DMA, no pow2
   padding, one executable per batch size.

@@ -42,14 +42,12 @@ class SampleOut(NamedTuple):
     mask: jax.Array   # [B, k] bool
     counts: jax.Array  # [B] int32 = min(degree, k), 0 for invalid seeds
     eid: Optional[jax.Array] = None  # [B, k] int32 global edge positions
-    # scalar int32, ``blocked`` window modes only: targets whose window
-    # did not fit (``ops.blockgather.blocked_window_gather``)
+    # scalar int32, ``blocked`` only: targets whose window did not fit
+    # (``ops.blockgather.blocked_window_gather``)
     nfall: Optional[jax.Array] = None
 
 
-# counter-hash constants — single source for the XLA path AND the fused
-# Pallas window kernel, whose bitwise-identical-draws contract rests on
-# never letting these diverge (ops/pallas/window_sample_kernel.py)
+# counter-hash constants
 HASH_PHI = 0x9E3779B9    # Weyl increment (golden-ratio word)
 HASH_MUL1 = 0x85EBCA6B   # murmur3 finalizer multipliers
 HASH_MUL2 = 0xC2B2AE35
@@ -57,8 +55,7 @@ HASH_MUL2 = 0xC2B2AE35
 
 def _fmix32(x: jax.Array) -> jax.Array:
     """murmur3 32-bit finalizer: full avalanche (every input bit flips
-    every output bit with ~1/2 probability).  Plain jnp elementwise ops —
-    legal both under jit and inside a Pallas kernel body."""
+    every output bit with ~1/2 probability)."""
     x = (x ^ (x >> 16)) * jnp.uint32(HASH_MUL1)
     x = (x ^ (x >> 13)) * jnp.uint32(HASH_MUL2)
     return x ^ (x >> 16)
@@ -68,11 +65,7 @@ def _fold_key_words(key: jax.Array):
     """Fold arbitrary-width PRNG key data into two 32-bit words via a
     POSITION-SENSITIVE multiplicative chain (a plain XOR fold would
     collapse word permutations of 4-word keys — rbg impls — onto one
-    stream); threefry's two words enter order-distinguished too.
-
-    Shared by :func:`_hash_uniform` and the fused Pallas window-sampling
-    kernel (``ops/pallas/window_sample_kernel.py``), which reproduces the
-    same uniforms in-kernel."""
+    stream); threefry's two words enter order-distinguished too."""
     data = jax.random.key_data(key).astype(jnp.uint32).reshape(-1)
     k0 = jnp.uint32(0)
     k1 = jnp.uint32(HASH_PHI)
@@ -122,12 +115,7 @@ def _uniform(key, shape, impl: str):
 def _stratified_positions(u: jax.Array, deg: jax.Array, k: int) -> jax.Array:
     """In-window draw positions ``[B, k]`` from uniforms ``u`` — neighbor
     slot ``j`` draws from stratum ``[floor(j*deg/k), floor((j+1)*deg/k))``
-    (distinct windows for ``deg > k``, identity for ``deg <= k``).
-
-    Single source of truth for the position math: the XLA samplers and the
-    fused Pallas window kernel (which re-derives the same expressions
-    in-kernel, op for op, so its draws are bitwise identical) both follow
-    this formula."""
+    (distinct windows for ``deg > k``, identity for ``deg <= k``)."""
     j = jnp.arange(k, dtype=jnp.int32)[None, :]              # [1, k]
     degf = deg.astype(jnp.float32)[:, None]                  # [B, 1]
     # Stratum bounds computed in float to avoid an int64 multiply;
@@ -141,44 +129,30 @@ def _stratified_positions(u: jax.Array, deg: jax.Array, k: int) -> jax.Array:
 
 
 def _gather(table: jax.Array, idx: jax.Array, mode: str) -> jax.Array:
-    """Element gather dispatch: 'xla' = jnp.take (clipped); 'lanes' = the
-    row-gather + lane-select path (``ops.fastgather``) that sidesteps XLA's
-    serialized 1-D scalar gather on TPU.  Requires the table to be padded
-    to a multiple of 128 (``CSRTopo.to_device`` guarantees it).
+    """Element gather by the name ``config.resolve_gather_mode`` hands
+    out: ``"xla"`` = ``jnp.take`` (clipped); ``"blocked"`` = row gather +
+    lane select (``ops.fastgather``), which sidesteps XLA's serialized
+    1-D scalar gather on TPU and needs the table padded to a multiple of
+    128 (``CSRTopo.to_device`` guarantees it).  Under ``"blocked"`` only
+    the k draws of a target share a window (``ops.blockgather``); the
+    scattered [B] reads that come here (``indptr``: two adjacent entries
+    per target, themselves a window of two: ROADMAP S3) go per element."""
+    if mode == "xla":
+        return jnp.take(table, idx, mode="clip")
+    if mode != "blocked":
+        raise ValueError(
+            f"gather_mode must be xla | blocked at the op "
+            f"(config.resolve_gather_mode maps auto), got {mode!r}")
+    from .fastgather import element_gather
 
-    'blocked*'/'pwindow*' apply only to the per-seed WINDOW gathers inside
-    the samplers (``ops.blockgather`` / the fused Pallas window kernel);
-    scattered [B] element gathers (the indptr reads) ride the lanes path
-    under them (two adjacent entries per target, themselves a window of
-    two: ROADMAP S3)."""
-    if mode.startswith("blocked") or mode.startswith("pwindow"):
-        mode = "lanes"
-    if mode in ("lanes", "lanes_fused"):
-        from .fastgather import element_gather
-
-        assert table.shape[0] % 128 == 0, (
-            f"lanes gather needs a 128-multiple table, got "
-            f"{table.shape[0]} — pad with ops.fastgather.pad_table_128 "
-            f"(CSRTopo.to_device / the samplers do this for you)"
-        )
-        m = table.shape[0]
-        return element_gather(
-            table[:m].reshape(-1, 128),
-            jnp.clip(idx, 0, m - 1),
-            fused=(mode == "lanes_fused"),
-        )
-    if mode == "pallas":
-        from .pallas.sample_gather_kernel import pallas_element_gather
-
-        assert table.shape[0] % 128 == 0, (
-            f"pallas gather needs a 128-multiple table, got "
-            f"{table.shape[0]} — pad with ops.fastgather.pad_table_128"
-        )
-        m = table.shape[0]
-        return pallas_element_gather(
-            table[:m].reshape(-1, 128), jnp.clip(idx, 0, m - 1)
-        )
-    return jnp.take(table, idx, mode="clip")
+    assert table.shape[0] % 128 == 0, (
+        f"blocked gather needs a 128-multiple table, got "
+        f"{table.shape[0]} — pad with ops.fastgather.pad_table_128 "
+        f"(CSRTopo.to_device / the samplers do this for you)"
+    )
+    m = table.shape[0]
+    return element_gather(table[:m].reshape(-1, 128),
+                          jnp.clip(idx, 0, m - 1))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "gather_mode",
@@ -224,48 +198,15 @@ def sample_neighbors(
     mask = j < counts[:, None]
     idx = start[:, None] + pos
     nfall = None
-    if gather_mode.startswith("pwindow"):
-        # fully-fused Pallas hop: PRNG + positions + window DMA + select
-        # in one kernel — pos above survives only as the eid formula
-        # (dead-code-eliminated when eid is unused downstream)
-        from .pallas.window_sample_kernel import (pallas_window_sample,
-                                                  parse_pwindow)
-
-        backend = jax.default_backend()
-        if backend not in ("tpu", "cpu"):
-            # fail before Mosaic lowering produces an opaque XLA error —
-            # pwindow is TPU-only (CPU rides pallas interpret mode)
-            raise ValueError(
-                f"gather_mode='pwindow' needs backend 'tpu' (Mosaic "
-                f"kernel) or 'cpu' (interpret mode); running on "
-                f"{backend!r} — use the XLA 'blocked:U' window mode "
-                "there instead")
-        assert indices.shape[0] % 128 == 0, (
-            f"pwindow gather needs a 128-multiple indices table, got "
-            f"{indices.shape[0]} — pad with ops.fastgather.pad_table_128"
-        )
-        if sample_rng != "hash":
-            raise ValueError(
-                "gather_mode='pwindow' fuses the counter-hash RNG "
-                "in-kernel and requires sample_rng='hash' (the "
-                "accelerator default); got sample_rng="
-                f"{sample_rng!r}")
-        nbrs = pallas_window_sample(
-            indices.reshape(-1, 128), start, deg, key, k,
-            U=parse_pwindow(gather_mode),
-            # mosaic needs a real TPU; CPU runs ride interpret mode so
-            # rehearsals and the virtual-mesh dryrun execute the same code
-            interpret=jax.default_backend() == "cpu")
-    elif gather_mode.startswith("blocked"):
-        from .blockgather import blocked_window_gather, parse_blocked
+    if gather_mode == "blocked":
+        from .blockgather import blocked_window_gather
 
         assert indices.shape[0] % 128 == 0, (
             f"blocked gather needs a 128-multiple indices table, got "
             f"{indices.shape[0]} — pad with ops.fastgather.pad_table_128"
         )
         nbrs, nfall = blocked_window_gather(
-            indices.reshape(-1, 128), start, deg, pos,
-            U=parse_blocked(gather_mode))
+            indices.reshape(-1, 128), start, deg, pos)
     else:
         nbrs = _gather(indices, idx, gather_mode)
     nbrs = jnp.where(mask, nbrs, jnp.int32(-1))
@@ -429,37 +370,32 @@ def sample_neighbors_weighted(
     u = _uniform(key, (B, k), sample_rng) * total[:, None]
 
     nfall = None
-    if gather_mode.startswith("blocked"):
+    if gather_mode == "blocked":
         # CDF inversion AND the neighbor reads both live in the seed's
         # contiguous window: one block gather + one VPU pass replaces the
         # ``bits``-round binary search of element gathers (ops.blockgather)
         from .blockgather import (blocked_weighted_positions,
-                                  blocked_window_gather, parse_blocked)
+                                  blocked_window_gather)
 
         assert (cum_weights.shape[0] % 128 == 0
                 and indices.shape[0] % 128 == 0), (
             "blocked gather needs 128-multiple tables — pad with "
             "ops.fastgather.pad_table_128"
         )
-        U = parse_blocked(gather_mode)
         posl = blocked_weighted_positions(
-            cum_weights.reshape(-1, 128), start, deg, u, U=U, bits=bits)
+            cum_weights.reshape(-1, 128), start, deg, u, bits=bits)
         # deg <= k: take all neighbors once instead of resampling
         posl = jnp.where(deg[:, None] <= k, j, posl)
         posl = jnp.minimum(posl, jnp.maximum(deg[:, None] - 1, 0))
         pos = start[:, None] + posl
         nbrs, nfall = blocked_window_gather(
-            indices.reshape(-1, 128), start, deg, jnp.where(mask, posl, 0),
-            U=U)
+            indices.reshape(-1, 128), start, deg, jnp.where(mask, posl, 0))
     else:
         # binary search for first position p in [start, end) with cw[p] > u
         lo = jnp.broadcast_to(start[:, None], (B, k))
         hi = jnp.broadcast_to(end[:, None], (B, k))
 
         def step(_, lohi):
-            # the gather here runs ``bits`` times — with gather_mode="lanes"
-            # each round is a near-bandwidth row gather instead of XLA's
-            # serialized 1-D scalar gather (the dominant cost on TPU)
             lo, hi = lohi
             mid = (lo + hi) // 2
             cw = _gather(cum_weights, mid, gather_mode)

@@ -90,7 +90,7 @@ class SampledBatch(NamedTuple):
     version: Optional[int] = None  # streaming: the graph version this
     # batch sampled (the snapshot's), None on frozen-CSR samplers
     window_misses: Optional[jax.Array] = None  # [L] per-hop count of
-    # targets whose CSR window did not fit the ``blocked`` modes' block
+    # targets whose CSR window did not fit the ``blocked`` path's block
     # (window_stats(batch) reads it; ops.blockgather.NO_WINDOW where the
     # hop has no window route)
 
@@ -377,12 +377,12 @@ class GraphSageSampler:
       mode: ``"TPU"`` (jit, default) or ``"CPU"`` (native host sampler).
       frontier_caps: optional per-layer cap on the padded frontier size
         (see module docstring).  Only meaningful with ``dedup="hop"``.
-      dedup: ``"auto"`` (default — the measured library default:
-        ``config.resolve_dedup``, overridable by the tuned file written
-        from bench.py's on-chip e2e A/B), ``"none"`` (TPU hot path —
-        positional relabel, no sort; frontier may contain duplicate
-        nodes) or ``"hop"`` (reference-parity exact dedup each hop via
-        ``ops.reindex``).
+      dedup: ``"auto"`` (default: ``"none"``, ``config.resolve_dedup``),
+        ``"none"`` (TPU hot path — positional relabel, no sort; frontier
+        may contain duplicate nodes) or ``"hop"`` (reference-parity exact
+        dedup each hop via ``ops.reindex``).
+      gather_mode: ``"auto"`` (default: the backend's,
+        ``config.resolve_gather_mode``), ``"xla"`` or ``"blocked"``.
       edge_weights: optional ``[E]`` weights; hops then draw neighbors
         weight-proportionally WITH replacement
         (``ops.sample_neighbors_weighted``, reference weight_sample path).
@@ -409,27 +409,19 @@ class GraphSageSampler:
         # sample through the jitted overlay pipeline (TPU mode,
         # positional relabel, uniform draws only)
         is_stream = _is_stream_graph(csr_topo)
-        if is_stream:
-            if mode not in ("TPU",):
-                raise ValueError(
-                    f"StreamingGraph samples in TPU mode only, got "
-                    f"{mode!r} (compact to a frozen CSRTopo for "
-                    "CPU/UVA sampling)")
-            if dedup == "auto":
-                dedup = "none"
+        if is_stream and mode != "TPU":
+            raise ValueError(
+                f"StreamingGraph samples in TPU mode only, got "
+                f"{mode!r} (compact to a frozen CSRTopo for "
+                "CPU/UVA sampling)")
         if mode == "UVA" and uva_budget is None:
             mode = "TPU"  # whole graph fits the (unbounded) budget
         from .config import (resolve_dedup, resolve_gather_mode,
                              resolve_sample_rng)
 
-        if mode == "UVA" and dedup == "auto":
-            # UVA's hot/cold split rides the positional pipeline only;
-            # a tuned/env 'hop' winner must not crash it (an EXPLICIT
-            # dedup="hop" still hits the assert below)
-            dedup = "none"
         dedup = resolve_dedup(dedup)
-        self.gather_mode = resolve_gather_mode(gather_mode, sample_rng)
-        self.sample_rng = resolve_sample_rng(sample_rng, self.gather_mode)
+        self.gather_mode = resolve_gather_mode(gather_mode)
+        self.sample_rng = resolve_sample_rng(sample_rng)
         self.return_eid = return_eid
         self.csr_topo = csr_topo  # property setter: splits stream/frozen
         if is_stream:
@@ -482,7 +474,7 @@ class GraphSageSampler:
             from .ops.fastgather import pad_table_128
 
             # edge-value fill: clipped probes past E read a harmless
-            # value; the lanes/pallas gathers require 128-multiple tables
+            # value; the blocked gather path requires 128-multiple tables
             self._cum_weights = pad_table_128(
                 _jnp.asarray(cw), fill=float(cw[-1]) if len(cw) else None)
         if mode == "TPU":
@@ -751,12 +743,12 @@ class GraphSageSampler:
         return arr
 
     def window_stats(self, batch: Optional[SampledBatch] = None):
-        """Per hop, how the ``blocked`` window mode fetched the draws:
+        """Per hop, how the ``blocked`` path fetched the draws:
         a list of ``{"window", "fallback", "classic"}`` - targets served
         by their covering block, targets compacted into the per-draw
         fallback, and whether the WHOLE hop took the per-draw path (more
-        misses than fallback slots, a hop with ``k <= U``, or a mode with
-        no window route; ``window`` and ``fallback`` are then 0).
+        misses than fallback slots, a hop with ``k <= U``, or the ``xla``
+        path; ``window`` and ``fallback`` are then 0).
 
         ``batch`` / no ``batch`` as :meth:`overflow_stats`; only the
         sampler-level form feeds the registry, once per ``sample`` call.

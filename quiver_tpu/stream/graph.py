@@ -46,7 +46,7 @@ __all__ = ["StreamingGraph", "DeltaSnapshot"]
 
 
 def _pad128(a: np.ndarray) -> np.ndarray:
-    """Zero-pad to a multiple of 128, never empty (lanes-gather shape
+    """Zero-pad to a multiple of 128, never empty (element-gather shape
     contract, same as ``CSRTopo.to_device``)."""
     target = max(((len(a) + 127) // 128) * 128, 128)
     if target != len(a):

@@ -68,7 +68,7 @@ class UVAGraph:
             )
         edge_is_hot = np.repeat(hot_mask, deg)
         indices_hot = topo.indices[edge_is_hot].astype(np.int32)
-        # pad to a non-empty multiple of 128 (lanes/pallas gather modes;
+        # pad to a non-empty multiple of 128 (the blocked gather path;
         # empty tables break jnp.take even when fully masked)
         pad = (-len(indices_hot)) % 128 or (128 if not len(indices_hot)
                                             else 0)
@@ -76,7 +76,7 @@ class UVAGraph:
             indices_hot = np.concatenate(
                 [indices_hot, np.zeros(pad, np.int32)]
             )
-        # indptr needs the same 128 padding: the lanes gather truncates
+        # indptr needs the same 128 padding: the element gather truncates
         # the table to a 128 multiple and CLIPS indices — an unpadded
         # [n+1] indptr silently returns a wrong row's pointers for the
         # last (n+1) % 128 node ids

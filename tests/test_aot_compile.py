@@ -21,6 +21,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from quiver_tpu.config import (resolve_dedup, resolve_gather_mode,
+                               resolve_sample_rng)
+
 PRODUCTS_NODES, PRODUCTS_EDGES = 2_449_029, 123_718_280
 PRODUCTS_DIM, PRODUCTS_CLASSES = 100, 47
 FANOUT, BATCH = (15, 10, 5), 1024
@@ -91,10 +94,11 @@ def _key(sharding):
 
 
 # --------------------------------------------------------------- sampling
-# what ``config.resolve_gather_mode("auto")`` gives on a TPU (asserted in
-# tests/test_config_resolution.py; said here because code that resolves
-# in this process resolves for the CPU it runs on)
-TPU_GATHER_MODE = "blocked:2"
+# what the library resolves to on a TPU, asked of the resolvers by the
+# backend's name: code that resolves in this process with no name given
+# resolves for the CPU it runs on
+TPU_GATHER_MODE = resolve_gather_mode("auto", backend="tpu")
+TPU_SAMPLE_RNG = resolve_sample_rng("auto", backend="tpu")
 
 
 @pytest.mark.parametrize("B,k", HOPS)
@@ -108,7 +112,7 @@ def test_default_tpu_hop_compiles(one_chip, B, k):
     c = _compile(
         lambda ip, ix, s, kk, m: sample_neighbors(
             ip, ix, s, k, kk, seed_mask=m, gather_mode=TPU_GATHER_MODE,
-            sample_rng="hash"),
+            sample_rng=TPU_SAMPLE_RNG),
         indptr, indices, _s(one_chip, (B,)), _key(one_chip),
         _s(one_chip, (B,), jnp.bool_))
     text = c.as_text()
@@ -118,36 +122,53 @@ def test_default_tpu_hop_compiles(one_chip, B, k):
     assert f"s32[{B},128]" in text and f"s32[{B * k},128]" in text
 
 
-@pytest.mark.parametrize("B,k,U", [(1024, 15, 3), (300, 5, 2), (64, 8, 1),
-                                   (16_384, 10, 3), (180_224, 5, 3)])
-def test_window_sample_kernel_compiles(one_chip, B, k, U):
-    from quiver_tpu.ops.pallas.window_sample_kernel import (
-        pallas_window_sample)
+def _padded(one_chip, n, dtype=jnp.int32):
+    return _s(one_chip, (_pad128(n),), dtype)
 
-    table = _s(one_chip, (_pad128(PRODUCTS_EDGES) // 128, 128))
+
+def test_overlay_hop_compiles(one_chip):
+    """The streaming hop (base CSR + tombstones + delta CSR, time window
+    on) on the accelerator path, at the SAGE cell's second hop: every
+    read goes per element (row gather + lane select), pure XLA."""
+    from quiver_tpu.ops.sample import sample_neighbors_overlay
+
+    B, k, delta = 16_384, 10, 65_536
+    indptr, indices = _graph(one_chip, PRODUCTS_NODES, PRODUCTS_EDGES)
+    edges = _padded(one_chip, PRODUCTS_EDGES)
+    scalar = _s(one_chip, ())
     c = _compile(
-        lambda t, s, d, kk: pallas_window_sample(t, s, d, kk, k, U=U),
-        table, _s(one_chip, (B,)), _s(one_chip, (B,)), _key(one_chip))
-    assert "tpu_custom_call" in c.as_text()
+        lambda ip, ix, tomb, dip, dix, s, kk, m, bts, dts, lo, hi:
+        sample_neighbors_overlay(
+            ip, ix, tomb, dip, dix, s, k, kk, seed_mask=m, base_ts=bts,
+            d_ts=dts, window_lo=lo, window_hi=hi, windowed=True,
+            gather_mode=TPU_GATHER_MODE, sample_rng=TPU_SAMPLE_RNG),
+        indptr, indices, edges, indptr, _padded(one_chip, delta),
+        _s(one_chip, (B,)), _key(one_chip), _s(one_chip, (B,), jnp.bool_),
+        edges, _padded(one_chip, delta), scalar, scalar)
+    text = c.as_text()
+    assert "tpu_custom_call" not in text
+    assert f"s32[{B * k},128]" in text     # a 512-B row per draw
 
 
-@pytest.mark.parametrize("m", [4096, 1_081_344])
-def test_element_gather_kernel_compiles(one_chip, m):
-    from quiver_tpu.ops.pallas.sample_gather_kernel import (
-        pallas_element_gather)
+def test_weighted_hop_compiles(one_chip):
+    """The weight-proportional hop on the accelerator path, same shape:
+    the CDF inversion is one pass over each target's two-row block of
+    ``cum_weights`` and the draws come out of its block of ``indices``,
+    the per-draw search in the other branch of each ``conditional``."""
+    from quiver_tpu.ops.sample import sample_neighbors_weighted
 
-    table = _s(one_chip, (_pad128(PRODUCTS_EDGES) // 128, 128))
-    c = _compile(pallas_element_gather, table, _s(one_chip, (m,)))
-    assert "tpu_custom_call" in c.as_text()
-
-
-@pytest.mark.parametrize("m", [2048, 1_081_344])
-def test_lane_select_kernel_compiles(one_chip, m):
-    from quiver_tpu.ops.pallas.element_gather_kernel import BLK, lane_select
-
-    m = -(-m // BLK) * BLK
-    c = _compile(lane_select, _s(one_chip, (m, 128)), _s(one_chip, (m,)))
-    assert "tpu_custom_call" in c.as_text()
+    B, k = 16_384, 10
+    indptr, indices = _graph(one_chip, PRODUCTS_NODES, PRODUCTS_EDGES)
+    c = _compile(
+        lambda ip, ix, cw, s, kk, m: sample_neighbors_weighted(
+            ip, ix, cw, s, k, kk, seed_mask=m,
+            gather_mode=TPU_GATHER_MODE, sample_rng=TPU_SAMPLE_RNG),
+        indptr, indices, _padded(one_chip, PRODUCTS_EDGES, jnp.float32),
+        _s(one_chip, (B,)), _key(one_chip), _s(one_chip, (B,), jnp.bool_))
+    text = c.as_text()
+    assert "tpu_custom_call" not in text
+    assert text.count(" conditional(") >= 2
+    assert f"f32[{B},128]" in text and f"s32[{B},128]" in text
 
 
 # ---------------------------------------------------------------- features
@@ -272,7 +293,7 @@ def _sampled_shapes(nodes, edges, dim, B, sizes):
     n_id, _, _, blocks, _, _ = jax.eval_shape(
         lambda ip, ix, s, k: run_pipeline(
             "none", ip, ix, s, k, tuple(sizes), (None,) * len(sizes),
-            gather_mode=TPU_GATHER_MODE, sample_rng="hash"),
+            gather_mode=TPU_GATHER_MODE, sample_rng=TPU_SAMPLE_RNG),
         indptr, indices, jax.ShapeDtypeStruct((B,), jnp.int32),
         jax.random.key(0))
     return jax.ShapeDtypeStruct((n_id.shape[0], dim), jnp.float32), blocks
@@ -306,14 +327,14 @@ def test_sage_train_step_compiles(one_chip):
 
 
 def _tpu_sampler(sizes):
-    """What the library resolves to on a TPU (PERF.md, PR 21 and PR 31),
-    said here because ``GraphSageSampler`` would resolve for the CPU it
-    runs on."""
+    """What a fused program reads of a ``GraphSageSampler`` built on a
+    TPU (one built here would resolve for the CPU it runs on)."""
     import types
 
     return types.SimpleNamespace(
-        sizes=sizes, gather_mode=TPU_GATHER_MODE, sample_rng="hash",
-        dedup="none", frontier_caps=(None,) * len(sizes))
+        sizes=sizes, gather_mode=TPU_GATHER_MODE,
+        sample_rng=TPU_SAMPLE_RNG, dedup=resolve_dedup("auto"),
+        frontier_caps=(None,) * len(sizes))
 
 
 def _small_fused_sage_step(one_chip):
@@ -346,7 +367,8 @@ def _small_fused_sage_step(one_chip):
 # and the fused step a frontier to hand over, through d90ff1a (PR 30).
 # Re-recorded on PR 31's tree (the commit after 78c559f), which MEANS to
 # change the step: its hops fetch a two-row window per target
-# (``TPU_GATHER_MODE``) where they fetched a row per draw
+# (``TPU_GATHER_MODE``) where they fetched a row per draw.  PR 32 took the
+# other gather paths away and left it as it was
 SAGE_STEP_BEFORE_MODEL_STATE = "13b12aa3dff04b751e6615a9002ac9aff735aec8affd487987d33e7af1ee1f6a"
 
 
@@ -419,17 +441,12 @@ MAG_EDGES, MAG_DIM, MAG_CLASSES = 54_023_314, 768, 153
 MAG_RELATION_OF = ((0, 2, -1), (1, -1, 3), (-1, 4, -1))
 
 
-def test_typed_fused_step_groups_its_projections(one_chip):
+def _typed_fused_step(one_chip):
     """The published R-GAT (``models.RGNN``) through the fused step, at
     the MAG240M share's tables and published widths (768 float16 rows, 2 x
     1024, 4 heads, 5 relations, fanout [25, 15]) with 64 seeds where the
-    cell has 1,024 (a quarter of a minute where the cell's program takes
-    three and a half).  The chip's compiler turns ``lax.ragged_dot`` into
-    grouped kernels (``ragged-dot-*`` custom calls) for the forward
-    product and for both gradients, so the program's FLOPs are one product
-    per source, not one per relation; the scope table gives each kernel
-    back the ``qt.model.project`` scope the compiler's renaming took, in
-    the pass it ran in."""
+    cell has 1,024 (a quarter of a minute to compile where the cell's
+    program takes three and a half), lowered for the described chip."""
     import numpy as np
     import optax
 
@@ -437,7 +454,6 @@ def test_typed_fused_step_groups_its_projections(one_chip):
     from quiver_tpu.parallel import TrainState
     from quiver_tpu.pipeline import _fused_train_impl
     from quiver_tpu.sampler import run_pipeline
-    from quiver_tpu.telemetry.device_scopes import parse_hlo_scopes
     import types
 
     offsets = tuple(int(v) for v in np.cumsum((0,) + MAG_SHARE))
@@ -448,8 +464,7 @@ def test_typed_fused_step_groups_its_projections(one_chip):
     n_id, n_mask, _, blocks, _, _ = jax.eval_shape(
         lambda ip, ix, s, k: run_pipeline(
             "none", ip, ix, s, k, sizes, (None,) * 2,
-            gather_mode=TPU_GATHER_MODE,
-            sample_rng="hash"),
+            gather_mode=TPU_GATHER_MODE, sample_rng=TPU_SAMPLE_RNG),
         indptr, indices, _s(None, (B,)), jax.random.key(0))
     x = _s(None, (n_id.shape[0], MAG_DIM), jnp.float32)
     v = jax.eval_shape(model.init, jax.random.key(1), x, blocks, n_id,
@@ -463,10 +478,37 @@ def test_typed_fused_step_groups_its_projections(one_chip):
                              rgnn_apply_fn(model), None)
     tables = (*_graph(one_chip, nodes, MAG_EDGES),
               (_s(one_chip, (nodes, MAG_DIM), jnp.float16), None))
-    c = jax.jit(impl, donate_argnums=(1,)).lower(
+    return jax.jit(impl, donate_argnums=(1,)).lower(
         tables, _on(one_chip, state), _s(one_chip, (B,)),
         _s(one_chip, (B,)), _s(one_chip, (B,), jnp.bool_),
-        _key(one_chip)).compile()
+        _key(one_chip))
+
+
+# sha256 of ``_typed_fused_step(...).as_text()``, recorded at ab18fb6
+# (PR 31) before PR 32 took the other gather paths out from under it.  A
+# PR that MEANS to change the typed step's program records the new hash
+TYPED_STEP_AT_PR31 = "cb5ebd4e3bad9fdf3dcc0818faa6a2c8cefb0f19922bbc4beb81b0be33a7a043"
+
+
+def test_fused_typed_step_lowers_as_recorded(one_chip):
+    """``mag240m-rgat.train-fused-typed``'s program, to the letter: the
+    same compile-cache entry, the same numbers for the cell."""
+    import hashlib
+
+    text = _typed_fused_step(one_chip).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == TYPED_STEP_AT_PR31
+
+
+def test_typed_fused_step_groups_its_projections(one_chip):
+    """The chip's compiler turns ``lax.ragged_dot`` into grouped kernels
+    (``ragged-dot-*`` custom calls) for the forward product and for both
+    gradients, so the program's FLOPs are one product per source, not one
+    per relation; the scope table gives each kernel back the
+    ``qt.model.project`` scope the compiler's renaming took, in the pass
+    it ran in."""
+    from quiver_tpu.telemetry.device_scopes import parse_hlo_scopes
+
+    c = _typed_fused_step(one_chip).compile()
     _, table = parse_hlo_scopes(c.as_text())
     kernels = {k: op for k, op in table.items()
                if k.startswith("%ragged-dot-none")}
@@ -495,7 +537,8 @@ def test_serving_bucket_forward_compiles(one_chip, bucket):
         indptr, indices, feat_tables = tables
         n_id, _, _, blocks, _, _ = run_pipeline(
             "none", indptr, indices, seeds, key, REDDIT_FANOUT,
-            (None, None), gather_mode=TPU_GATHER_MODE, sample_rng="hash")
+            (None, None), gather_mode=TPU_GATHER_MODE,
+            sample_rng=TPU_SAMPLE_RNG)
         return apply_fn(params, _lookup_tables(feat_tables, n_id), blocks)
 
     tables = (*_graph(one_chip, REDDIT_NODES, REDDIT_EDGES),

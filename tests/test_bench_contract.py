@@ -124,17 +124,6 @@ class TestBackendGate:
         assert out["vs_baseline"] is None
 
 
-class TestProbe:
-    def test_probe_runs_in_this_process(self, small_graph):
-        """No child: the probe times a sampler on the graph it is given."""
-        ms = bench.probe_sampler(small_graph, "xla", [3, 2], 16)
-        assert ms > 0
-
-    def test_refused_mode_raises(self, small_graph):
-        with pytest.raises(ValueError):
-            bench.probe_sampler(small_graph, "blocked:0", [3, 2], 16)
-
-
 class TestServingSetupCache:
     """_serving_setup's cache must not key on id(topo) alone: a collected
     topo's address can be recycled by a NEW same-shape graph and serve a

@@ -360,7 +360,7 @@ def test_a_kernel_the_compiler_renamed_takes_its_operands_scopes():
         "ragged-dot-none")
 
 
-# cut from a v5e compile of a ``blocked:2`` hop: the ``lax.cond`` between
+# cut from a v5e compile of a ``blocked`` hop: the ``lax.cond`` between
 # the window fetch and the per-draw fetch, and a ``while``
 FLOW_TEXT = '''\
 HloModule jit_qt_fused_train_step, is_scheduled=true

@@ -164,23 +164,3 @@ def test_dist_sampler_padded_indptr_is_monotone(small_graph):
     ip = np.asarray(s.indptr_sh)
     for row in ip:
         assert np.all(np.diff(row.astype(np.int64)) >= 0)
-
-
-def test_dist_sampler_degrades_pwindow_to_blocked(small_graph):
-    """pallas_call outputs lack vma annotations under shard_map, so a
-    tuned/env pwindow pick must degrade to the equivalent XLA blocked
-    mode inside DistGraphSampler instead of failing at trace time."""
-    mesh = make_mesh(("data",))
-    s = DistGraphSampler(small_graph, mesh, sizes=[3],
-                         gather_mode="pwindow:2", sample_rng="hash")
-    assert s.gather_mode == "blocked:2"
-    n_id, n_mask, num, blocks = s.sample(
-        np.arange(16).reshape(8, 2) % small_graph.node_count, key=5)
-    assert np.asarray(n_id).shape[0] == 8
-
-
-def test_dist_sampler_degrades_all_pallas_modes(small_graph):
-    mesh = make_mesh(("data",))
-    for gm, want in (("pallas", "lanes"), ("lanes_fused", "lanes")):
-        s = DistGraphSampler(small_graph, mesh, sizes=[3], gather_mode=gm)
-        assert s.gather_mode == want, (gm, s.gather_mode)

@@ -1,10 +1,8 @@
-"""Fast gather paths: lane-select element gather + Pallas row gather
-(interpret mode on CPU; real-TPU timing lives in benchmarks/)."""
+"""Fast gather paths: lane-select element gather + the feature store's
+Pallas row gather (interpret mode on CPU)."""
 
 import numpy as np
-import jax
 import jax.numpy as jnp
-import pytest
 
 from quiver_tpu.ops.fastgather import element_gather, prepare_table
 
@@ -37,140 +35,3 @@ def test_pallas_gather_rows_interpret(rng):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(table)[np.asarray(idx)], rtol=1e-7
     )
-
-
-def test_pallas_lane_select_interpret(rng):
-    from quiver_tpu.ops.pallas.element_gather_kernel import lane_select, BLK
-
-    rows = jnp.asarray(rng.integers(0, 100, (BLK * 2, 128), dtype=np.int32))
-    lanes = jnp.asarray(rng.integers(0, 128, BLK * 2, dtype=np.int32))
-    out = lane_select(rows, lanes, interpret=True)
-    expect = np.asarray(rows)[np.arange(BLK * 2), np.asarray(lanes)]
-    np.testing.assert_array_equal(np.asarray(out), expect)
-
-
-def test_pallas_element_gather_interpret(rng):
-    """Fused row-DMA gather kernel == ground truth (interpret mode)."""
-    from quiver_tpu.ops.pallas.sample_gather_kernel import (
-        pallas_element_gather)
-
-    table = jnp.asarray(rng.normal(size=(256 * 128,)).astype(np.float32))
-    t2d = table.reshape(-1, 128)
-    # unaligned count exercises the pad+slice path; 2-D idx the reshape
-    idx = rng.integers(0, 256 * 128, (37, 11)).astype(np.int32)
-    out = pallas_element_gather(t2d, jnp.asarray(idx), interpret=True)
-    np.testing.assert_array_equal(np.asarray(out),
-                                  np.asarray(table)[idx])
-
-
-def test_pallas_gather_mode_in_sampler(small_graph, rng):
-    """gather_mode='pallas' flows through sample_neighbors (interpret on
-    CPU is implicit via pallas interpret fallback? no — force interpret by
-    calling the op's gather directly)."""
-    from quiver_tpu.ops.pallas.sample_gather_kernel import (
-        pallas_element_gather)
-
-    indptr, _ = small_graph.to_device()
-    m = indptr.shape[0] // 128 * 128
-    idx = jnp.asarray(rng.integers(0, m, 64).astype(np.int32))
-    got = pallas_element_gather(indptr[:m].reshape(-1, 128), idx,
-                                interpret=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(indptr)[np.asarray(idx)])
-
-
-class TestPallasWindowSample:
-    """Fused window-sampling kernel (PRNG + window DMA + select in one
-    pallas_call): bitwise equality with the XLA hash path on every route
-    (fitting windows, compacted fallback, wholesale classic)."""
-
-    def _xla_reference(self, table, start, deg, key, k):
-        from quiver_tpu.ops.sample import (_hash_uniform,
-                                           _stratified_positions)
-
-        u = _hash_uniform(key, (len(start), k))
-        pos = np.asarray(_stratified_positions(
-            jnp.asarray(u), jnp.asarray(deg), k))
-        return np.asarray(table)[
-            np.clip(np.asarray(start)[:, None] + pos, 0, len(table) - 1)]
-
-    def _mk_csr(self, rng, B, max_deg, U):
-        deg = rng.integers(0, max_deg, B).astype(np.int32)
-        total = int(deg.sum())
-        pad = (-total) % 128 or 128
-        table = rng.integers(0, 1 << 30, total + pad).astype(np.int32)
-        start = np.concatenate([[0], np.cumsum(deg)[:-1]]).astype(np.int32)
-        return table, start, deg
-
-    @pytest.mark.parametrize("U,k,B", [
-        (3, 15, 64), (3, 10, 257),  # products fanout + multi-program grid
-        pytest.param(2, 5, 64, marks=pytest.mark.slow),
-        pytest.param(1, 8, 64, marks=pytest.mark.slow),
-    ])
-    def test_fitting_windows_match_xla(self, rng, U, k, B):
-        from quiver_tpu.ops.pallas.window_sample_kernel import (
-            pallas_window_sample)
-
-        # all windows fit U rows by construction (deg < 128)
-        table, start, deg = self._mk_csr(rng, B, 120, U)
-        key = jax.random.PRNGKey(7)
-        got = np.asarray(pallas_window_sample(
-            jnp.asarray(table).reshape(-1, 128), jnp.asarray(start),
-            jnp.asarray(deg), key, k, U=U, interpret=True))
-        want = self._xla_reference(table, start, deg, key, k)
-        np.testing.assert_array_equal(got, want)
-
-    def test_nonfitting_seeds_route_through_fallback(self, rng):
-        from quiver_tpu.ops.pallas.window_sample_kernel import (
-            pallas_window_sample)
-
-        U, k, B = 2, 7, 96
-        deg = np.where(rng.random(B) < 0.3,
-                       rng.integers(U * 128 + 1, 2000, B),
-                       rng.integers(0, 100, B)).astype(np.int32)
-        total = int(deg.sum())
-        table = rng.integers(0, 1 << 30,
-                             total + ((-total) % 128 or 128)).astype(np.int32)
-        start = np.concatenate([[0], np.cumsum(deg)[:-1]]).astype(np.int32)
-        key = jax.random.PRNGKey(3)
-        got = np.asarray(pallas_window_sample(
-            jnp.asarray(table).reshape(-1, 128), jnp.asarray(start),
-            jnp.asarray(deg), key, k, U=U, fallback_frac=0.5,
-            interpret=True))
-        want = self._xla_reference(table, start, deg, key, k)
-        np.testing.assert_array_equal(got, want)
-
-    def test_wholesale_classic_on_cap_overflow(self, rng):
-        from quiver_tpu.ops.pallas.window_sample_kernel import (
-            pallas_window_sample)
-
-        U, k, B = 1, 6, 64
-        deg = rng.integers(200, 1500, B).astype(np.int32)  # nothing fits
-        total = int(deg.sum())
-        table = rng.integers(0, 1 << 30,
-                             total + ((-total) % 128 or 128)).astype(np.int32)
-        start = np.concatenate([[0], np.cumsum(deg)[:-1]]).astype(np.int32)
-        key = jax.random.PRNGKey(11)
-        got = np.asarray(pallas_window_sample(
-            jnp.asarray(table).reshape(-1, 128), jnp.asarray(start),
-            jnp.asarray(deg), key, k, U=U, fallback_frac=0.02,
-            interpret=True))
-        want = self._xla_reference(table, start, deg, key, k)
-        np.testing.assert_array_equal(got, want)
-
-    def test_window_at_table_end_and_zero_deg(self, rng):
-        from quiver_tpu.ops.pallas.window_sample_kernel import (
-            pallas_window_sample)
-
-        # windows deliberately in the LAST rows of the table (r0 clipping)
-        U, k = 3, 4
-        table = rng.integers(0, 1 << 30, 512).astype(np.int32)  # 4 rows
-        start = np.array([500, 470, 0, 0], np.int32)
-        deg = np.array([12, 42, 0, 0], np.int32)
-        key = jax.random.PRNGKey(1)
-        got = np.asarray(pallas_window_sample(
-            jnp.asarray(table).reshape(-1, 128), jnp.asarray(start),
-            jnp.asarray(deg), key, k, U=U, interpret=True))
-        want = self._xla_reference(table, start, deg, key, k)
-        # zero-degree rows return garbage by contract; compare valid rows
-        np.testing.assert_array_equal(got[:2], want[:2])

@@ -1,29 +1,13 @@
-"""Cross-check sampling with gather_mode='lanes' vs 'xla' — identical
-results (the lane-select path is a pure gather reimplementation)."""
+"""The hop's two gather paths draw the same samples: ``blocked`` (the
+accelerator's: a window of two 128-lane rows per target, row gather +
+lane select for the rest) against ``xla`` (the CPU's ``jnp.take``, the
+reference), op by op and through every consumer of the hop."""
 
 import numpy as np
 import jax
 import pytest
 
 from quiver_tpu import GraphSageSampler
-
-
-def test_lanes_equals_xla(small_graph):
-    seeds = np.arange(32, dtype=np.int64)
-    key = jax.random.PRNGKey(9)
-    b_x = GraphSageSampler(small_graph, [5, 4],
-                           gather_mode="xla").sample(seeds, key=key)
-    b_l = GraphSageSampler(small_graph, [5, 4],
-                           gather_mode="lanes").sample(seeds, key=key)
-    np.testing.assert_array_equal(np.asarray(b_x.n_id),
-                                  np.asarray(b_l.n_id))
-    np.testing.assert_array_equal(np.asarray(b_x.n_id_mask),
-                                  np.asarray(b_l.n_id_mask))
-    for lx, ll in zip(b_x.layers, b_l.layers):
-        np.testing.assert_array_equal(np.asarray(lx.nbr_local),
-                                      np.asarray(ll.nbr_local))
-        np.testing.assert_array_equal(np.asarray(lx.mask),
-                                      np.asarray(ll.mask))
 
 
 def test_blocked_equals_xla(small_graph):
@@ -37,6 +21,8 @@ def test_blocked_equals_xla(small_graph):
                            gather_mode="blocked").sample(seeds, key=key)
     np.testing.assert_array_equal(np.asarray(b_x.n_id),
                                   np.asarray(b_b.n_id))
+    np.testing.assert_array_equal(np.asarray(b_x.n_id_mask),
+                                  np.asarray(b_b.n_id_mask))
     for lx, lb in zip(b_x.layers, b_b.layers):
         np.testing.assert_array_equal(np.asarray(lx.mask),
                                       np.asarray(lb.mask))
@@ -118,7 +104,7 @@ def test_blocked_weighted_marginals():
         out = sample_neighbors_weighted(
             ip, idx_pad, cw, jnp.arange(N, dtype=jnp.int32), k,
             jax.random.PRNGKey(t), sample_rng="key",
-            gather_mode="blocked:2")
+            gather_mode="blocked")
         nb = np.asarray(out.nbrs)[np.asarray(out.mask)]
         np.add.at(counts, nb, 1)
     # aggregate by weight class: class-c mass must be proportional to
@@ -127,52 +113,6 @@ def test_blocked_weighted_marginals():
     mass = np.array([counts[wclass == c].sum() for c in range(3)])
     frac = mass / mass.sum()
     np.testing.assert_allclose(frac, np.array([1, 2, 3]) / 6, atol=0.02)
-
-
-def test_lanes_fused_equals_xla(small_graph):
-    """Pallas-fused lane select produces identical samples (interpret mode
-    covers the kernel on CPU via the pure-XLA fallback equivalence)."""
-    import jax as _jax
-
-    if _jax.default_backend() == "cpu":
-        # the fused kernel needs real TPU or interpret=True; on CPU verify
-        # via the op-level test instead (test_fastgather) and the flag wiring
-        from quiver_tpu.ops.sample import _gather
-        import jax.numpy as jnp
-        import numpy as _np
-
-        table = jnp.asarray(_np.arange(256, dtype=_np.int32))
-        idx = jnp.asarray(_np.array([3, 200, 128], dtype=_np.int32))
-        # lanes mode must match plain take
-        _np.testing.assert_array_equal(
-            _np.asarray(_gather(table, idx, "lanes")),
-            _np.asarray(jnp.take(table, idx)),
-        )
-
-
-def test_pwindow_equals_xla_through_sampler(small_graph):
-    """The fused Pallas window-sampling hop (gather_mode='pwindow')
-    samples bitwise identically to the XLA hash path through the full
-    2-hop sampler (interpret mode on CPU)."""
-    seeds = np.arange(24, dtype=np.int64)
-    key = jax.random.PRNGKey(9)
-    b_x = GraphSageSampler(small_graph, [5, 4], gather_mode="xla",
-                           sample_rng="hash").sample(seeds, key=key)
-    b_p = GraphSageSampler(small_graph, [5, 4], gather_mode="pwindow:2",
-                           sample_rng="hash").sample(seeds, key=key)
-    np.testing.assert_array_equal(np.asarray(b_x.n_id),
-                                  np.asarray(b_p.n_id))
-    for lx, lp in zip(b_x.layers, b_p.layers):
-        np.testing.assert_array_equal(np.asarray(lx.mask),
-                                      np.asarray(lp.mask))
-        np.testing.assert_array_equal(np.asarray(lx.nbr_local),
-                                      np.asarray(lp.nbr_local))
-
-
-def test_pwindow_requires_hash_rng(small_graph):
-    with pytest.raises(ValueError, match="hash"):
-        GraphSageSampler(small_graph, [4], gather_mode="pwindow",
-                         sample_rng="key").sample(np.arange(8))
 
 
 # ---- the chip's default: the window fetch at the shipped block width and
@@ -251,8 +191,7 @@ def test_default_window_sampler_equals_xla_and_counts(heavy):
     kw = dict(sample_rng="hash", dedup="none", return_eid=True)
     b_x = GraphSageSampler(topo, sizes, gather_mode="xla",
                            **kw).sample(seeds, key=key)
-    s_b = GraphSageSampler(topo, sizes, gather_mode=f"blocked:{DEFAULT_U}",
-                           **kw)
+    s_b = GraphSageSampler(topo, sizes, gather_mode="blocked", **kw)
     b_b = s_b.sample(seeds, key=key)
     np.testing.assert_array_equal(np.asarray(b_x.n_id), np.asarray(b_b.n_id))
     np.testing.assert_array_equal(np.asarray(b_x.n_id_mask),
@@ -281,13 +220,206 @@ def test_default_window_sampler_equals_xla_and_counts(heavy):
         assert not any(s["fallback"] or s["classic"] for s in stats[:2])
     else:
         assert any(s["fallback"] or s["classic"] for s in stats[:2])
-    # modes with no window route report none; the frontier-cap drops
-    # keep their meaning under every mode
+    # the path with no window route reports none; the frontier-cap drops
+    # keep their meaning on both
     assert all(s == {"window": 0, "fallback": 0, "classic": True}
                for s in GraphSageSampler(topo, sizes, gather_mode="xla",
                                          **kw).window_stats(b_x))
     np.testing.assert_array_equal(s_b.overflow_stats(b_b), [0, 0, 0])
     np.testing.assert_array_equal(s_b.overflow_stats(), [0, 0, 0])
+
+
+# ---- every consumer of the hop: on a chip each of them runs the window
+# path since PR 31, on the CPU none of them but ``GraphSageSampler.sample``
+# with ``dedup="none"`` ever did
+SIZES = [5, 3]
+
+
+def _batch(b):
+    return [b.n_id, b.n_id_mask,
+            [(l.nbr_local, l.mask, l.eid) for l in b.layers]]
+
+
+def _sampler(topo, gm, **kw):
+    kw.setdefault("dedup", "none")
+    return GraphSageSampler(topo, SIZES, gather_mode=gm, sample_rng="hash",
+                            **kw)
+
+
+def _dedup_hop(topo, gm, seeds):
+    return _batch(_sampler(topo, gm, dedup="hop").sample(
+        seeds, key=jax.random.PRNGKey(5)))
+
+
+def _return_eid(topo, gm, seeds):
+    return _batch(_sampler(topo, gm, return_eid=True).sample(
+        seeds, key=jax.random.PRNGKey(5)))
+
+
+def _streaming(topo, gm, seeds):
+    """Pending insertions and a tombstone on every fourth seed's first
+    base edge: the overlay hop."""
+    from quiver_tpu import CSRTopo
+    from quiver_tpu.stream import StreamingGraph
+
+    rng = np.random.default_rng(0)
+    g = StreamingGraph(CSRTopo(indptr=topo.indptr, indices=topo.indices))
+    try:
+        n = g.node_count
+        g.add_edges(rng.integers(0, n, 200), rng.integers(0, n, 200))
+        dead = [u for u in seeds[::4] if topo.degree[u]]
+        g.remove_edges(dead, [int(topo.indices[topo.indptr[u]])
+                              for u in dead])
+        assert g.pending_deltas and g.tombstone_count
+        return _batch(_sampler(g, gm, return_eid=True).sample(
+            seeds, key=jax.random.PRNGKey(5)))
+    finally:
+        g.close()
+
+
+def _uva(topo, gm, seeds):
+    s = _sampler(topo, gm, mode="UVA", uva_budget=topo.edge_count * 4 // 3)
+    b = s.sample(seeds, key=jax.random.PRNGKey(5))
+    assert s._uva.stats()["cold_edges"]
+    return _batch(b)
+
+
+def _hetero(topo, gm, seeds):
+    from quiver_tpu.hetero import HeteroCSRTopo, HeteroGraphSageSampler
+
+    ht = HeteroCSRTopo({("a", "r", "a"): topo}, {"a": topo.node_count})
+    return HeteroGraphSageSampler(
+        ht, SIZES, seed_type="a", gather_mode=gm,
+        sample_rng="hash").sample(seeds, key=jax.random.PRNGKey(5))
+
+
+def _dist(topo, gm, seeds):
+    from quiver_tpu.dist.sampler import DistGraphSampler
+    from quiver_tpu.utils.mesh import make_mesh
+
+    s = DistGraphSampler(topo, make_mesh(("data",)), sizes=SIZES,
+                         gather_mode=gm, sample_rng="hash")
+    n_id, n_mask, num, blocks = s.sample(seeds.reshape(8, 8), key=7)
+    return [n_id, n_mask, num, [(b.nbr_local, b.mask) for b in blocks]]
+
+
+def _mesh(topo, gm, seeds):
+    from quiver_tpu.mesh import MeshSampler
+
+    out = MeshSampler(topo.indptr, topo.indices, n_shards=4, gather_mode=gm,
+                      sample_rng="hash").sample(seeds, 5,
+                                                jax.random.PRNGKey(5))
+    return [out.nbrs, out.mask, out.counts, out.eid]
+
+
+def _model_setup(topo, gm, seeds):
+    """A sampler on ``gm``, every feature row in HBM, a small SAGE and its
+    parameters (the same for both paths: made from a fixed key on shapes
+    that do not depend on the path)."""
+    import jax.numpy as jnp
+
+    from quiver_tpu import Feature
+    from quiver_tpu.models import GraphSAGE
+
+    feat = np.random.default_rng(1).normal(
+        size=(topo.node_count, 8)).astype(np.float32)
+    feature = Feature(device_cache_size="1G").from_cpu_tensor(feat)
+    sampler = _sampler(topo, gm)
+    model = GraphSAGE(hidden=8, out_dim=4, num_layers=2, dropout=0.0)
+    b0 = sampler.sample(seeds[:8], key=jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(0), feature[b0.n_id], b0.layers)
+
+    def apply_fn(p, x, blocks, train=False, rngs=None):
+        return model.apply(p, x, blocks, train=train, rngs=rngs)
+
+    labels = jnp.asarray(seeds % 4, jnp.int32)
+    return sampler, feature, apply_fn, params, labels
+
+
+def _fused_train(topo, gm, seeds):
+    import jax.numpy as jnp
+    import optax
+
+    from quiver_tpu.parallel import TrainState
+    from quiver_tpu.pipeline import make_fused_train_step
+
+    sampler, feature, apply_fn, params, labels = _model_setup(topo, gm, seeds)
+    tx = optax.adam(1e-2)
+    state, loss = make_fused_train_step(sampler, feature, apply_fn, tx)(
+        TrainState.create(params, tx), jnp.asarray(seeds, jnp.int32), labels,
+        jnp.ones((64,), bool), jax.random.PRNGKey(5))
+    return [loss, state.params]
+
+
+def _scan_epoch(topo, gm, seeds):
+    import jax.numpy as jnp
+    import optax
+
+    from quiver_tpu.parallel import TrainState
+    from quiver_tpu.pipeline import make_scan_epoch
+
+    sampler, feature, apply_fn, params, labels = _model_setup(topo, gm, seeds)
+    tx = optax.adam(1e-2)
+    state, losses = make_scan_epoch(sampler, feature, apply_fn, tx)(
+        TrainState.create(params, tx),
+        jnp.asarray(seeds, jnp.int32).reshape(2, 32), labels.reshape(2, 32),
+        jax.random.PRNGKey(5))
+    return [losses, state.params]
+
+
+def _fused_eval(topo, gm, seeds):
+    import jax.numpy as jnp
+
+    from quiver_tpu.pipeline import make_fused_eval_fn
+
+    sampler, feature, apply_fn, params, _ = _model_setup(topo, gm, seeds)
+    return [make_fused_eval_fn(sampler, feature, apply_fn)(
+        params, jnp.asarray(seeds, jnp.int32), jax.random.PRNGKey(5))]
+
+
+def _serving_bucket(topo, gm, seeds):
+    """``InferenceServer``'s fused bucket of 64 (it draws its key from
+    numpy's global stream: seeded here)."""
+    import queue
+
+    from quiver_tpu import InferenceServer
+
+    sampler, feature, apply_fn, params, _ = _model_setup(topo, gm, seeds)
+    server = InferenceServer(sampler, feature, apply_fn, params,
+                             queue.Queue())
+    assert server._fused
+    np.random.seed(5)
+    return [server._fused_forward(seeds)]
+
+
+CONSUMERS = {f.__name__.lstrip("_"): f for f in (
+    _dedup_hop, _return_eid, _streaming, _uva, _hetero, _dist, _mesh,
+    _fused_train, _scan_epoch, _fused_eval, _serving_bucket)}
+
+
+@pytest.mark.parametrize("heavy", list(HEAVY))
+@pytest.mark.parametrize("consumer", list(CONSUMERS))
+def test_accelerator_path_equals_cpu_path(consumer, heavy):
+    """What a TPU resolves to, ``("blocked", "hash")``, against ``("xla",
+    "hash")`` through every consumer of the hop, every output to the bit,
+    on the three frontiers: every window fits; a few of the seeds take
+    the compacted fallback slots; more of them than the slots, so a hop
+    that has a window route goes per draw as a whole."""
+    from quiver_tpu.ops.blockgather import DEFAULT_U, fallback_slots
+
+    topo, deg = _windowed_graph(HEAVY[heavy])
+    # 64 seeds, the targets over the window first
+    seeds = np.argsort(-deg, kind="stable")[:64].astype(np.int64)
+    misses = int(_spans(topo, seeds, True, DEFAULT_U).sum())
+    S = fallback_slots(len(seeds))
+    assert {"none": misses == 0, "some": 0 < misses <= S,
+            "many": misses > S}[heavy]
+    got, want = (
+        jax.tree_util.tree_leaves(CONSUMERS[consumer](topo, gm, seeds))
+        for gm in ("blocked", "xla"))
+    assert len(got) == len(want) and len(got) >= 1
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_window_counters_reach_the_registry():
@@ -330,7 +462,7 @@ def test_hop_within_block_width_lowers_without_cond():
     def lowered(k):
         return sample_neighbors.lower(
             indptr, indices, seeds, k, jax.random.PRNGKey(0),
-            gather_mode="blocked:2", sample_rng="hash").as_text()
+            gather_mode="blocked", sample_rng="hash").as_text()
 
     assert "stablehlo.case" not in lowered(2)
     assert "stablehlo.sort" not in lowered(2)

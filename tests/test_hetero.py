@@ -221,24 +221,3 @@ def test_hetero_hash_rng_executes(mag_topo):
     for hop_blocks in b1.layers:
         for blk in hop_blocks:
             _assert_block_edges_real(topo, b1, blk, max_targets=12)
-
-
-def test_hetero_pwindow_matches_xla():
-    """The fused Pallas window mode flows through the typed sampler
-    (interpret on CPU) with draws identical to the XLA hash path."""
-    import jax
-
-    rng = np.random.default_rng(3)
-    ei = {("a", "r", "a"): np.stack([rng.integers(0, 400, 2500),
-                                     rng.integers(0, 400, 2500)])}
-    ht = HeteroCSRTopo.from_edge_index_dict(ei, node_counts={"a": 400})
-    kw = dict(seed_type="a", sample_rng="hash")
-    seeds = np.arange(16)
-    key = jax.random.PRNGKey(21)
-    bx = HeteroGraphSageSampler(ht, [3, 2], gather_mode="xla",
-                                **kw).sample(seeds, key=key)
-    bp = HeteroGraphSageSampler(ht, [3, 2], gather_mode="pwindow:2",
-                                **kw).sample(seeds, key=key)
-    for t in bx.n_id:
-        np.testing.assert_array_equal(np.asarray(bx.n_id[t]),
-                                      np.asarray(bp.n_id[t]))

@@ -113,11 +113,11 @@ def test_config_env_and_update(monkeypatch):
     import quiver_tpu.config as cfg_mod
 
     monkeypatch.setattr(cfg_mod, "_config", None)
-    monkeypatch.setenv("QUIVER_TPU_GATHER_MODE", "xla")
+    monkeypatch.setenv("QUIVER_TPU_CACHE_POLICY", "p2p_clique_replicate")
     c = cfg_mod.get_config()
-    assert c.gather_mode == "xla"
-    cfg_mod.update(gather_mode="lanes")
-    assert cfg_mod.get_config().gather_mode == "lanes"
+    assert c.cache_policy == "p2p_clique_replicate"
+    cfg_mod.update(cache_policy="device_replicate")
+    assert cfg_mod.get_config().cache_policy == "device_replicate"
     import pytest as _pytest
 
     with _pytest.raises(AttributeError):

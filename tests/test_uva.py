@@ -82,11 +82,11 @@ def test_uva_pinned_key_replays_both_tiers(power_graph):
                                       np.asarray(l2.mask))
 
 
-def test_uva_lanes_gather_covers_tail_nodes():
-    """Regression: the lanes gather truncates tables to a 128 multiple
+def test_uva_element_gather_covers_tail_nodes():
+    """Regression: the element gather truncates tables to a 128 multiple
     and clips indices — an unpadded [n+1] indptr returned a WRONG row's
     pointers for the last (n+1) % 128 node ids.  Sample exactly those
-    tail nodes with gather_mode='lanes' on an all-hot UVA graph and
+    tail nodes with gather_mode='blocked' on an all-hot UVA graph and
     verify every edge against the CSR."""
     rng = np.random.default_rng(7)
     n = 300  # n+1 = 301: 45 tail ids past the 256 truncation boundary
@@ -99,7 +99,7 @@ def test_uva_lanes_gather_covers_tail_nodes():
     topo = CSRTopo(indptr=indptr, indices=indices)
     s = GraphSageSampler(topo, [4], mode="UVA",
                          uva_budget=topo.edge_count * 4,  # all hot
-                         gather_mode="lanes")
+                         gather_mode="blocked")
     tail = np.arange(256, n, dtype=np.int64)  # ids the clip used to eat
     b = s.sample(tail, key=jax.random.PRNGKey(2))
     assert s._uva.stats()["cold_edges"] == 0

@@ -129,11 +129,12 @@ def test_cpu_mode_sampler_weighted_end_to_end(small_graph, rng):
                 assert n_id[local[v, j]] in row
 
 
-def test_weighted_lanes_matches_xla(wgraph):
-    """gather_mode='lanes' draws identical samples to 'xla' for the same
-    key (the binary search reads the same cum_weights values either
-    way).  Tables shorter than 128 exercise the truncation path only via
-    the padded-table contract, so pad like the sampler does."""
+def test_weighted_blocked_matches_xla(wgraph):
+    """gather_mode='blocked' draws identical samples to 'xla' for the
+    same key, edge ids included (the count over the gathered CDF block
+    lands where the binary search does).  Tables shorter than 128
+    exercise the truncation path only via the padded-table contract, so
+    pad like the sampler does."""
     from quiver_tpu.ops.fastgather import pad_table_128
 
     indptr, indices, cw, _ = wgraph
@@ -146,7 +147,7 @@ def test_weighted_lanes_matches_xla(wgraph):
         a = sample_neighbors_weighted(ip, ix, cwp, seeds, 3, key,
                                       gather_mode="xla")
         b = sample_neighbors_weighted(ip, ix, cwp, seeds, 3, key,
-                                      gather_mode="lanes")
+                                      gather_mode="blocked")
         np.testing.assert_array_equal(np.asarray(a.nbrs), np.asarray(b.nbrs))
         np.testing.assert_array_equal(np.asarray(a.mask), np.asarray(b.mask))
         np.testing.assert_array_equal(np.asarray(a.eid), np.asarray(b.eid))
