@@ -4,7 +4,9 @@ Mirrors the reference's feature benchmarks behind
 docs/Introduction_en.md:90-126 (single-device cache 14.82 GB/s; NVLink
 clique 108.6 GB/s).  Compares:
   * XLA row gather (``jnp.take``) — the Feature hot path
-  * Pallas pipelined-DMA gather (``ops.pallas.gather_rows``)
+  * Pallas masked DMA gather (``ops.pallas.gather_rows``; rows of 128
+    32-bit words only: ``--dim 128``.  What it is for, a frontier with
+    dead slots, is timed by ``probe_feature_gather.py``)
   * Feature with partial cache (hot/cold mix, host tail)
 """
 
@@ -55,10 +57,8 @@ def main():
     bench("XLA row gather (full HBM)", take, table, idx,
           bytes_per_iter=nbytes)
     try:
-        m_pad = m // 256 * 256
-        bench("Pallas DMA row gather",
-              lambda t, i: gather_rows(t, i[:m_pad]), table, idx,
-              bytes_per_iter=m_pad * d * 4)
+        bench("Pallas DMA row gather", gather_rows, table, idx,
+              bytes_per_iter=nbytes)
     except Exception as e:
         print(f"pallas gather failed: {e}")
 

@@ -63,10 +63,29 @@ def _fresh_bucket(n: int) -> int:
     return p
 
 
-def _lookup_tables(tables, idx):
+# rows between the rows two neighbouring dead slots ask for: each in a
+# (16, 128) tile of its own.  Measured: 11.54 ms for the SAGE cell's
+# frontier against 11.70 at a stride of 1 and 15.18 when all ask for row 0
+_DEAD_STRIDE = 16
+
+
+def _lookup_tables(tables, idx, mask=None):
     """``Feature.lookup_device`` over explicit ``(hot, order)`` arrays
     (from ``Feature._device_tables``), so a jitted caller can take the
-    tables as arguments."""
+    tables as arguments.
+
+    ``mask`` is the sampler's word on its own ids (``n_mask`` beside
+    ``n_id``), and the gather acts on the two things it says.  Every id
+    is in range by construction, so ``jnp.take``'s out-of-range pass (a
+    select over every gathered row) goes.  And a slot where it is False
+    is dead: every consumer multiplies its row by a zero mask, so it may
+    read ANY row.  The sampler writes id 0 there, and one row asked for
+    650 K times a step is the dearest there is: 16.2 ns a slot on a v5e
+    where rows all over the table cost 10.7 (PERF.md, PR 33).  So a dead
+    slot asks for a row of its own, ``_DEAD_STRIDE`` rows on from the
+    last one's.  Live slots read the table's rows exactly; dead ones some
+    row of the table.  Without a mask (a caller's own ids) the semantics
+    are ``jnp.take``'s to the letter."""
     import jax
     import jax.numpy as jnp
 
@@ -74,7 +93,12 @@ def _lookup_tables(tables, idx):
     with jax.named_scope(FEATURE_GATHER):
         if order is not None:
             idx = jnp.take(order, idx, mode="clip")
-        return jnp.take(hot, idx, axis=0)
+        if mask is None:
+            return jnp.take(hot, idx, axis=0)
+        own = (jnp.arange(idx.shape[0], dtype=jnp.uint32) * _DEAD_STRIDE
+               % hot.shape[0]).astype(idx.dtype)
+        return hot.at[jnp.where(mask, idx, own)].get(
+            mode="promise_in_bounds")
 
 
 @dataclass
@@ -1061,10 +1085,24 @@ class Feature:
             )
         return self.hot, getattr(self, "_order_dev", None)
 
-    def lookup_device(self, idx):
+    def lookup_device(self, idx, mask=None):
         """Pure-device gather for jit pipelines (requires full HBM cache).
-        Applies ``feature_order`` on device; safe to call under jit."""
-        return _lookup_tables(self._device_tables(), idx)
+        Applies ``feature_order`` on device; safe to call under jit.
+        ``mask`` (a sampler's ``n_id_mask`` beside its ``n_id``): see
+        :func:`_lookup_tables`; called eagerly with one, the slots asked
+        for and the live ones among them are counted, so that a
+        frontier's live share is a reading."""
+        import jax
+
+        if mask is not None and not isinstance(mask, jax.core.Tracer):
+            from . import telemetry
+
+            telemetry.counter("feature_gather_slots_total").inc(
+                float(mask.shape[0]))
+            # one read of the mask, on the eager path only
+            telemetry.counter("feature_gather_live_slots_total").inc(
+                float(np.asarray(mask).sum()))
+        return _lookup_tables(self._device_tables(), idx, mask)
 
     # ------------------------------------------------------------------
     def size(self, dim: int) -> int:

@@ -106,7 +106,7 @@ def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
             dedup, indptr, indices, seeds, ks, sizes, caps, gather_mode=gm,
             sample_rng=srng
         )
-        x = _lookup_tables(feat_tables, n_id)
+        x = _lookup_tables(feat_tables, n_id, n_mask)
 
         def compute(params):
             with jax.named_scope(MODEL):
@@ -185,7 +185,7 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
             dedup, indptr, indices, seeds, key, sizes, caps, gather_mode=gm,
             sample_rng=srng
         )
-        x = _lookup_tables(feat_tables, n_id)
+        x = _lookup_tables(feat_tables, n_id, n_mask)
         with jax.named_scope(MODEL):
             logits, _ = call_model(apply_fn, params, x, blocks,
                                    Frontier(n_id, n_mask), model_state,

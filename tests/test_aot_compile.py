@@ -176,11 +176,17 @@ def test_weighted_hop_compiles(one_chip):
 # library refuses the rest by name on a TPU (``KernelConstraintError``,
 # ops/pallas/__init__.py); the strict xfails below switch that check off
 # and keep the chip compiler's own words on record — when a repair makes
-# one of them compile, its XPASS fails the suite and the check can go.
+# one of them compile, its XPASS fails the suite and the check can go
+# (PR 33 did so for the row gather's 1.08M-row frontier: its ids come per
+# grid program as SMEM blocks where they were prefetched whole).
 _LANES = ("Mosaic failed to compile TPU kernel: Slice shape along "
           "dimension N must be aligned to tiling (128), but is 100 / 602")
 _SUBLANE = ("Mosaic failed to compile TPU kernel: Slice shape along "
             "dimension 0 must be aligned to tiling (8), but is 1")
+_HALF_ROWS = ("Mosaic failed to compile TPU kernel: Slice shape along "
+              "dimension 0 must be aligned to tiling (8), but is 1: a "
+              "one-row DMA out of bf16[N,128] (through ref.bitcast(int32) "
+              "in the kernel: ... tiling (4))")
 _SMEM = ("RESOURCE_EXHAUSTED: Allocation (size=4325376) would exceed "
          "memory (size=1048576) ... space=smem ... prefetched SMEM "
          "operand 0")
@@ -191,17 +197,28 @@ def unchecked(monkeypatch):
     """Let a refused shape through to the compiler."""
     from quiver_tpu.ops.pallas import gather_kernel, page_gather_kernel
 
-    for mod in (gather_kernel, page_gather_kernel):
-        monkeypatch.setattr(mod, "check_lane_width", lambda *a: None)
-        monkeypatch.setattr(mod, "check_scalar_prefetch", lambda *a: None)
+    monkeypatch.setattr(gather_kernel, "check_word_rows", lambda *a: None)
+    monkeypatch.setattr(page_gather_kernel, "check_lane_width",
+                        lambda *a: None)
+    monkeypatch.setattr(page_gather_kernel, "check_scalar_prefetch",
+                        lambda *a: None)
 
 
-def _row_gather(one_chip, d, m):
+def _row_gather(one_chip, d, m, dtype=jnp.float32):
+    """The masked row gather: a row fetched for a live slot only."""
     from quiver_tpu.ops.pallas.gather_kernel import gather_rows
 
     return _compile(gather_rows,
-                    _s(one_chip, (PRODUCTS_NODES, d), jnp.float32),
-                    _s(one_chip, (m,)))
+                    _s(one_chip, (PRODUCTS_NODES, d), dtype),
+                    _s(one_chip, (m,)), _s(one_chip, (m,), jnp.bool_))
+
+
+def _row_gather_16_bit(one_chip, d, m):
+    return _row_gather(one_chip, d, m, jnp.bfloat16)
+
+
+def _row_gather_words(one_chip, d, m):
+    return _row_gather(one_chip, d, m, jnp.int32)
 
 
 def _page_gather(one_chip, d, m):
@@ -220,10 +237,15 @@ def _page_gather(one_chip, d, m):
         _s(one_chip, (m,)), _s(one_chip, (m,)))
 
 
-@pytest.mark.parametrize("kernel", [_row_gather, _page_gather])
-def test_feature_row_kernels_compile_at_128_lanes(one_chip, kernel):
-    """D=128 with an index plan that fits SMEM: the envelope."""
-    assert "tpu_custom_call" in kernel(one_chip, 128, 65_536).as_text()
+@pytest.mark.parametrize("kernel,m", [
+    (_row_gather, 65_536), (_page_gather, 65_536),
+    # the SAGE cell's frontier and twice it: refused (``_SMEM``) while
+    # the row gather's ids were prefetched whole, through PR 32
+    (_row_gather, 1_081_344), (_row_gather, 2_162_688)])
+def test_feature_row_kernels_compile_at_128_lanes(one_chip, kernel, m):
+    """D=128 with an index plan that fits SMEM: the envelope.  The row
+    gather's plan is a block of ids per grid program, whatever ``m``."""
+    assert "tpu_custom_call" in kernel(one_chip, 128, m).as_text()
 
 
 @pytest.mark.parametrize("kernel,d,m", [
@@ -231,8 +253,10 @@ def test_feature_row_kernels_compile_at_128_lanes(one_chip, kernel):
                  marks=pytest.mark.xfail(strict=True, reason=_LANES)),
     pytest.param(_row_gather, 602, 65_536,
                  marks=pytest.mark.xfail(strict=True, reason=_SUBLANE)),
-    pytest.param(_row_gather, 128, 1_081_344,
-                 marks=pytest.mark.xfail(strict=True, reason=_SMEM)),
+    pytest.param(_row_gather_16_bit, 128, 65_536,
+                 marks=pytest.mark.xfail(strict=True, reason=_HALF_ROWS)),
+    pytest.param(_row_gather_words, 768, 65_536,
+                 marks=pytest.mark.xfail(strict=True, reason=_SUBLANE)),
     pytest.param(_page_gather, 100, 65_536,
                  marks=pytest.mark.xfail(strict=True, reason=_LANES)),
     pytest.param(_page_gather, 602, 65_536,
@@ -242,14 +266,17 @@ def test_feature_row_kernels_compile_at_128_lanes(one_chip, kernel):
 ])
 def test_feature_row_kernels_refused_by_mosaic(one_chip, unchecked, kernel,
                                                d, m):
-    """Products (D=100) and Reddit (D=602) widths, and a products-sized
-    frontier (1.08M rows) at any width."""
+    """Products (D=100) and Reddit (D=602) widths; a one-row DMA out of
+    a 16-bit table or out of a row wider than 128 words (the typed cell's
+    768); the page gather's products-sized frontier (1.08M rows) at any
+    width."""
     kernel(one_chip, d, m)
 
 
 @pytest.mark.parametrize("kernel,d,m", [
     (_row_gather, 100, 32_768), (_row_gather, 602, 32_768),
-    (_row_gather, 128, 2_162_688), (_page_gather, 100, 32_768),
+    (_row_gather_16_bit, 128, 32_768), (_row_gather_words, 768, 32_768),
+    (_page_gather, 100, 32_768),
     (_page_gather, 602, 32_768), (_page_gather, 128, 2_162_688)])
 def test_feature_row_kernels_refuse_by_name(one_chip, kernel, d, m):
     # sizes differ from the xfails above: those traced the same jitted
@@ -270,6 +297,47 @@ def test_feature_hot_gather_compiles(one_chip, nodes, d, m):
     _compile(_lookup_tables,
              (_s(one_chip, (nodes, d), jnp.float32), _s(one_chip, (nodes,))),
              _s(one_chip, (m,)))
+
+
+SAGE_CELL_NODES, SAGE_CELL_FRONTIER = 27_764_989, 1_081_344
+
+
+@pytest.mark.parametrize("stored", ["words", "plain"])
+def test_masked_lookup_compiles_at_the_sage_cell(one_chip, stored):
+    """The SAGE cell's frontier out of its table, widened as its model
+    does.  ``plain``: what a fused program runs, ``_lookup_tables`` handed
+    the sampler's mask: XLA's gather with no out-of-range pass.
+    ``words``: the table as ``int32[13,882,495,128]`` word rows through
+    the masked DMA kernel and the half-pick (no caller in the library:
+    the chip turned it down, PERF.md PR 33)."""
+    from quiver_tpu.feature import _lookup_tables
+    from quiver_tpu.ops.pallas.gather_kernel import (gather_rows,
+                                                     pick_word_rows)
+
+    n, m = SAGE_CELL_NODES, SAGE_CELL_FRONTIER
+    if stored == "words":
+        table = _s(one_chip, ((n + 1) // 2, 128))
+
+        def lookup(t, i, mk):
+            return pick_word_rows(gather_rows(t, i >> 1, mk), i,
+                                  jnp.bfloat16)
+    else:
+        table = _s(one_chip, (n, 128), jnp.bfloat16)
+
+        def lookup(t, i, mk):
+            return _lookup_tables((t, None), i, mk)
+
+    text = _compile(lambda t, i, mk: lookup(t, i, mk).astype(jnp.float32),
+                    table, _s(one_chip, (m,)),
+                    _s(one_chip, (m,), jnp.bool_)).as_text()
+    entry = text[text.index("\nENTRY "):]
+    if stored == "words":
+        assert "tpu_custom_call" in entry
+        assert f"s32[{m // 2048},1,2048]" in entry  # a block of ids each
+        assert f"bf16[{m},128]" not in entry        # the pick is fused
+    else:
+        assert "tpu_custom_call" not in text
+        assert "broadcast_select" not in entry      # no out-of-range pass
 
 
 # ------------------------------------------------------------ whole steps
@@ -364,12 +432,14 @@ def _small_fused_sage_step(one_chip):
 # sha256 of ``_small_fused_sage_step(...).as_text()``.  It stood at
 # f33332031cfac31e037a8c56a00d8e0102b244201ab4318ff8b5c5924ea6342a from
 # commit e4950b4 (PR 28), before ``TrainState`` had a slot for model state
-# and the fused step a frontier to hand over, through d90ff1a (PR 30).
-# Re-recorded on PR 31's tree (the commit after 78c559f), which MEANS to
-# change the step: its hops fetch a two-row window per target
-# (``TPU_GATHER_MODE``) where they fetched a row per draw.  PR 32 took the
-# other gather paths away and left it as it was
-SAGE_STEP_BEFORE_MODEL_STATE = "13b12aa3dff04b751e6615a9002ac9aff735aec8affd487987d33e7af1ee1f6a"
+# and the fused step a frontier to hand over, through d90ff1a (PR 30), and
+# at 13b12aa3dff04b751e6615a9002ac9aff735aec8affd487987d33e7af1ee1f6a from
+# PR 31's tree (the hops fetch a two-row window per target) through
+# da26c51 (PR 32).  Re-recorded on PR 33's tree, which MEANS to change the
+# step: its lookup is handed the sampler's mask, so the row gather
+# promises its ids in bounds (``jnp.take``'s out-of-range pass is gone)
+# and a dead slot asks for a row of its own, not for row 0
+SAGE_STEP_BEFORE_MODEL_STATE = "30f914a0c0e56c4babbc4a22242217f8c29cfcf171d53437f1ac569e1b78643b"
 
 
 def test_fused_sage_step_lowers_as_before_model_state(one_chip):
@@ -484,10 +554,12 @@ def _typed_fused_step(one_chip):
         _key(one_chip))
 
 
-# sha256 of ``_typed_fused_step(...).as_text()``, recorded at ab18fb6
-# (PR 31) before PR 32 took the other gather paths out from under it.  A
-# PR that MEANS to change the typed step's program records the new hash
-TYPED_STEP_AT_PR31 = "cb5ebd4e3bad9fdf3dcc0818faa6a2c8cefb0f19922bbc4beb81b0be33a7a043"
+# sha256 of ``_typed_fused_step(...).as_text()``.  It stood at
+# cb5ebd4e3bad9fdf3dcc0818faa6a2c8cefb0f19922bbc4beb81b0be33a7a043 from
+# ab18fb6 (PR 31) through da26c51 (PR 32).  Re-recorded on PR 33's tree,
+# which MEANS to change the typed step's program: its lookup is handed
+# the sampler's mask, as the SAGE step's above
+TYPED_STEP_RECORDED = "45d1a2cd8c96e8d41c8ca0b3ae21f5486168ad480427d6832adebb030e8f7d74"
 
 
 def test_fused_typed_step_lowers_as_recorded(one_chip):
@@ -496,7 +568,7 @@ def test_fused_typed_step_lowers_as_recorded(one_chip):
     import hashlib
 
     text = _typed_fused_step(one_chip).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == TYPED_STEP_AT_PR31
+    assert hashlib.sha256(text.encode()).hexdigest() == TYPED_STEP_RECORDED
 
 
 def test_typed_fused_step_groups_its_projections(one_chip):
