@@ -6,7 +6,10 @@ The reference's multi-node path is the papers100M benchmark
 DDP ranks, row-partitioned DistFeature, NCCL exchange.  Here the same
 shape runs as one jit program set over a mesh: row-sharded
 :class:`DistGraphSampler` (all-to-all seed routing), all-to-all
-:class:`DistFeature`, and a data-parallel train step (XLA psum = DDP).
+:class:`DistFeature` over the sampler's row ranges, and a data-parallel
+train step (XLA psum = DDP): three launches a step and no host read, the
+recipe the benchmark cell ``papers100m-sage-host.train-dist`` times
+(``cellbench/programs/sage_dist.py``).
 
 ``run_dist_training`` is sized by arguments so the driver's dryrun can run
 it tiny and the slow test at 100K+ nodes with the reference fanout.
@@ -50,7 +53,7 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
     import jax.numpy as jnp
     import optax
 
-    from quiver_tpu import CSRTopo, DistFeature, PartitionInfo
+    from quiver_tpu import CSRTopo, DistFeature
     from quiver_tpu.dist.hier import HierFeature
     from quiver_tpu.dist.sampler import DistGraphSampler
     from quiver_tpu.models import GraphSAGE
@@ -91,14 +94,14 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
             feat[order], hmesh, hot_count=hot_count,
             global2host=g2h_hier)
         hier_old2new = old2new
-    dist_feat = None
-    if hier is None:
-        g2h = rng.integers(0, n_devices, topo.node_count).astype(np.int32)
-        info = PartitionInfo(host=0, hosts=n_devices, global2host=g2h)
-        dist_feat = DistFeature.from_global_feature(feat, mesh, info)
     sampler = DistGraphSampler(topo, mesh, sizes=list(sizes),
                                gather_mode=gather_mode,
                                sample_rng=sample_rng)
+    dist_feat = None
+    if hier is None:
+        # one partition for graph and table: the sampler's row ranges
+        dist_feat = DistFeature.from_row_ranges(feat, mesh,
+                                                sampler.row_starts_host)
 
     model = GraphSAGE(hidden=hidden, out_dim=classes, num_layers=len(sizes),
                       dropout=0.0)
@@ -132,7 +135,8 @@ def run_dist_training(n_devices: int, n_nodes: int = 256,
             feat_overflow += int(st["drops"].sum())
             xs = jnp.asarray(out).reshape(n_devices, -1, feat_dim)
         else:
-            xs = dist_feat.lookup(np.asarray(n_id))
+            # the device arrays as the sampler returned them: no host trip
+            xs = dist_feat.lookup(n_id, n_mask)
             feat_overflow += int(np.asarray(dist_feat.last_overflow).sum())
         if state is None:
             params = model.init(

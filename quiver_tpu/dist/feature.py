@@ -26,11 +26,17 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..feature import _lookup_tables
+from ..parallel.train import replicate
 from ..resilience import chaos
 from ..resilience.deadline import check_ambient
 from ..resilience.errors import PeerTimeout
+from ..telemetry.device_scopes import (FEATURE_GATHER, exchange as
+                                       exchange_scope, register_program)
+from .exchange import (put_row_blocks, record_exchange, route, shard_len,
+                       unroute)
 
-__all__ = ["PartitionInfo", "DistFeature"]
+__all__ = ["PartitionInfo", "DistFeature", "lookup_program"]
 
 # fault-injection site for the cross-host exchange (no-op unless a
 # chaos plan is installed)
@@ -105,13 +111,77 @@ class PartitionInfo:
         return out_ids, out_pos
 
 
+def lookup_program(mesh: Mesh, axis: str, cap: int, ranged: bool):
+    """The jitted lookup over a sharded table, ``jit_qt_dist_lookup``:
+    ``(shards [n, m, D], tables, ids [n, B], valid [n, B]) -> (rows [n, B,
+    D], dropped [n], live [n])``.  ``tables`` is the partition's own,
+    replicated: ``{"row_starts"}`` when ``ranged``, else a global2host
+    partition's five maps.  It holds no table: all arrive as arguments."""
+    n = int(mesh.shape[axis])
+
+    def body(shard, tables, ids, valid):
+        # shard: [1, m, D]; ids, valid: [1, B] — this rank's query batch.
+        shard = shard[0]
+        ids, valid = ids[0], valid[0]
+        me = jax.lax.axis_index(axis)
+        with exchange_scope(FEATURE_GATHER):
+            if ranged:
+                starts = tables["row_starts"]
+                # an id outside the table is nobody's: no request
+                valid = valid & (ids >= 0) & (ids < starts[n])
+                owner = (jnp.searchsorted(starts, ids, side="right")
+                         - 1).astype(jnp.int32)
+            else:
+                owner = jnp.where(tables["rep_mask"][ids], me,
+                                  tables["g2h"][ids])
+        # ---- phase 1: ship request ids to owners
+        r = route(FEATURE_GATHER, axis, n, cap, ids, owner, valid)
+        with exchange_scope(FEATURE_GATHER):
+            if ranged:
+                lslot = r.rids - starts[me]
+            else:
+                rid = jnp.where(r.rvalid, r.rids, 0)
+                lslot = jnp.where(
+                    tables["rep_mask"][rid],
+                    tables["owned_counts"][me] + tables["rep_rank"][rid],
+                    tables["g2l"][rid])
+        # the owner's fetch, told which received slots are empty: an
+        # empty slot asks for a row of its own (PERF.md, PR 33) and
+        # its answer is never unpacked
+        feats = _lookup_tables((shard, None), lslot, r.rvalid)
+        # ---- phase 2: ship features back to requesters
+        got = unroute(FEATURE_GATHER, axis, n, cap, feats, r)
+        with exchange_scope(FEATURE_GATHER):
+            out = jnp.where(r.ok[:, None], got, 0)
+        return out[None], r.dropped[None], r.live[None]
+
+    f = shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis, None, None), P(), P(axis, None),
+                  P(axis, None)),
+        out_specs=(P(axis, None, None), P(axis), P(axis)),
+    )
+
+    def qt_dist_lookup(shards, tables, ids, valid):
+        return f(shards, tables, ids, valid)
+
+    return jax.jit(qt_dist_lookup)
+
+
 class DistFeature:
     """Sharded feature with all-to-all remote lookup.
 
-    Build with :meth:`from_global_feature` (single-controller: the full
-    feature is available and gets laid out into shards), then index with
+    Build with :meth:`from_row_ranges` (contiguous row ranges, the
+    sampler's: each device's rows are put from a slice of the host table
+    and an owner is found by a search over the ``n + 1`` range starts) or
+    :meth:`from_global_feature` (any node -> host map, replication; the
+    table is laid out into shards on the host first), then index with
     ``dist_feature[ids]`` where ``ids`` is ``[n_hosts, B]`` (one query batch
     per host shard) or ``[B]`` (this host's batch, parity mode).
+
+    The lookup program (``jit_qt_dist_lookup``) is handed the shards and
+    the partition's tables as ARGUMENTS: a device array captured by a
+    jitted closure is baked into the executable as a constant.
 
     :meth:`enable_cold_cache` attaches a per-host HBM overlay in front
     of the all-to-all: this host's recurring remote rows are served from
@@ -122,17 +192,21 @@ class DistFeature:
 
     _guarded_by = {"_overlay": "_ov_lock"}
 
-    def __init__(self, mesh: Mesh, info: PartitionInfo, axis: str = "data",
-                 request_cap: Optional[int] = None):
+    def __init__(self, mesh: Mesh, info: Optional[PartitionInfo],
+                 axis: str = "data", request_cap: Optional[int] = None):
         self.mesh = mesh
-        self.info = info
+        self.info = info         # None for a partition by row ranges
         self.axis = axis
         self.n = int(mesh.shape[axis])
-        assert self.n == info.hosts, (self.n, info.hosts)
+        assert info is None or self.n == info.hosts, (self.n, info.hosts)
         self.request_cap = request_cap
-        self.shards = None       # [n*max_local, D] sharded
-        self.g2l = None          # [N] int32 device (local slot incl. replicas)
-        self.g2h = None          # [N] int32 device
+        self.shards = None       # [n, max_local, D] sharded over ``axis``
+        self.row_starts_host = None  # [n+1] int64, row-range partitions
+        # what the program is handed beside the shards, replicated over
+        # the mesh once (an array on one device would be copied to the
+        # others at every call): the range starts, or a global2host
+        # partition's maps
+        self.tables = None
         self._fn = {}
         self._host_source = None  # numpy global feature (overlay admission)
         self.cold_cache = None    # ColdRowCache over global-id space
@@ -167,74 +241,72 @@ class DistFeature:
         # and fold at lookup (slot = owned_count[host] + rep_rank).
         rep_rank = np.zeros(n, dtype=np.int32)
         rep_rank[info.rep_ids] = np.arange(len(info.rep_ids), dtype=np.int32)
-        self._rep_rank = rep_rank
         self._host_source = np.asarray(feature)  # overlay admission source
         sharding = NamedSharding(mesh, P(axis, None, None))
         self.shards = jax.device_put(shards, sharding)
-        self.g2l = jnp.asarray(g2l)
-        self.g2h = jnp.asarray(info.global2host)
-        self.rep_mask = jnp.asarray(info.replicate_mask)
-        self.rep_rank = jnp.asarray(rep_rank)
-        self.owned_counts = jnp.asarray(info.owned_counts.astype(np.int32))
+        self.tables = replicate(mesh, {
+            "g2l": g2l, "g2h": info.global2host,
+            "rep_mask": info.replicate_mask, "rep_rank": rep_rank,
+            "owned_counts": info.owned_counts.astype(np.int32)})
         return self
 
-    # ------------------------------------------------------------------
-    def _build(self, B: int, cap: int):
-        n, axis = self.n, self.axis
-        g2l, g2h = self.g2l, self.g2h
-        rep_mask, rep_rank = self.rep_mask, self.rep_rank
-        owned_counts = self.owned_counts
+    @classmethod
+    def from_row_ranges(cls, feature: np.ndarray, mesh: Mesh, row_starts,
+                        axis: str = "data",
+                        request_cap: Optional[int] = None, dtype=None,
+                        overlay: bool = False,
+                        shard_rows: Optional[int] = None):
+        """Partition by contiguous row ranges: device ``p`` holds rows
+        ``[row_starts[p], row_starts[p+1])`` (``row_starts``: ``[n+1]``,
+        from 0 to the row count; the sampler's ranges,
+        :func:`~quiver_tpu.dist.sampler.plan_row_shards`, make one
+        partition of graph and table).
 
-        def body(shard, ids, valid):
-            # shard: [1, m, D]; ids, valid: [1, B] — this rank's query batch.
-            shard = shard[0]
-            ids, valid = ids[0], valid[0]
-            me = jax.lax.axis_index(axis)
-            local_rep = rep_mask[ids]
-            owner = jnp.where(local_rep, me, g2h[ids])
-            owner = jnp.where(valid, owner, n)  # invalid -> nowhere
-            # rank of each query within its destination bucket
-            onehot = (owner[:, None] == jnp.arange(n)[None, :])
-            rank_in = jnp.cumsum(onehot, axis=0) - 1
-            slot = jnp.sum(jnp.where(onehot, rank_in, 0), axis=1)
-            overflow = slot >= cap
-            dest = jnp.where(valid & ~overflow, owner * cap + slot, n * cap)
-            # requests: [n*cap] node ids (+1 shift, 0 = empty)
-            reqs = jnp.zeros((n * cap,), jnp.int32).at[dest].add(
-                (ids + 1).astype(jnp.int32), mode="drop"
-            )
-            reqs = reqs.reshape(n, cap)
-            # ---- phase 1: ship request ids to owners
-            recv = jax.lax.all_to_all(reqs, axis, split_axis=0,
-                                      concat_axis=0, tiled=True)
-            # recv: [n, cap] requests FROM each source rank, for me.
-            rids = recv.reshape(-1) - 1
-            rvalid = rids >= 0
-            rid_safe = jnp.where(rvalid, rids, 0)
-            lslot = jnp.where(
-                rep_mask[rid_safe],
-                owned_counts[me] + rep_rank[rid_safe],
-                g2l[rid_safe],
-            )
-            feats = jnp.take(shard, lslot, axis=0)
-            feats = jnp.where(rvalid[:, None], feats, 0)
-            feats = feats.reshape(n, cap, -1)
-            # ---- phase 2: ship features back to requesters
-            back = jax.lax.all_to_all(feats, axis, split_axis=0,
-                                      concat_axis=0, tiled=True)
-            flat = back.reshape(n * cap, -1)
-            gathered = jnp.take(flat, jnp.clip(dest, 0, n * cap - 1),
-                                axis=0)
-            out = jnp.where((valid & ~overflow)[:, None], gathered, 0)
-            ocount = (valid & overflow).sum().astype(jnp.int32)
-            return out[None], ocount[None]
+        Each device's rows go up from a SLICE of ``feature``: no second
+        host copy of the table, and nothing ``[N]``-long on the device
+        (owner = ``searchsorted(row_starts, id, "right") - 1``, local row =
+        ``id - row_starts[owner]``).  ``dtype`` stores the rows narrower
+        (converted block by block).  Every shard is as long as the largest
+        range, rounded up to the tile; ``shard_rows`` states a larger
+        length (:func:`~quiver_tpu.dist.exchange.shard_len`; the
+        sampler's ``shard_rows`` keeps both tables' programs at one
+        shape).  ``overlay=True`` is for a caller who goes on to
+        :meth:`enable_cold_cache` or wants the degraded lookup: it keeps
+        what they need, the host table and a :class:`PartitionInfo` of the
+        ranges (an ``[N]`` map on the HOST)."""
+        row_starts = np.asarray(row_starts, dtype=np.int64)
+        n_rows, d = feature.shape
+        self = cls(mesh, None, axis, request_cap)
+        if (len(row_starts) != self.n + 1 or row_starts[0] != 0
+                or row_starts[-1] != n_rows
+                or (np.diff(row_starts) < 0).any()):
+            raise ValueError(
+                f"row_starts {row_starts.tolist()} are not {self.n} "
+                f"contiguous ranges over {n_rows} rows")
+        m = shard_len(np.diff(row_starts).max(), shard_rows)
+        store = np.dtype(feature.dtype if dtype is None else dtype)
 
-        f = shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(axis, None, None), P(axis, None), P(axis, None)),
-            out_specs=(P(axis, None, None), P(axis)),
-        )
-        return jax.jit(f)
+        def block(p):
+            lo = int(row_starts[p])
+            if lo + m <= n_rows:
+                # a view: the rows past the range's end are the next
+                # range's, here as padding that no local row reaches
+                rows = feature[lo:lo + m]
+            else:
+                rows = np.zeros((m, d), feature.dtype)
+                rows[:n_rows - lo] = feature[lo:]
+            return rows.astype(store, copy=False)
+
+        self.shards = put_row_blocks(mesh, axis, (m, d), block)
+        self.row_starts_host = row_starts
+        self.tables = replicate(
+            mesh, {"row_starts": row_starts.astype(np.int32)})
+        if overlay:
+            self.info = PartitionInfo(
+                hosts=self.n, global2host=np.repeat(
+                    np.arange(self.n, dtype=np.int32), np.diff(row_starts)))
+            self._host_source = np.asarray(feature)
+        return self
 
     # -- per-host cold-row overlay (docs/FEATURE_CACHE.md) -------------
     def enable_cold_cache(self, rows: Optional[int] = None,
@@ -250,8 +322,9 @@ class DistFeature:
         as a device-side patch after the collective.
         """
         assert self._host_source is not None, (
-            "enable_cold_cache needs from_global_feature (the host-side "
-            "source copy feeds admission)"
+            "enable_cold_cache needs from_global_feature or "
+            "from_row_ranges(overlay=True) (the host-side source copy "
+            "feeds admission)"
         )
         from ..config import get_config
         from ..ops.coldcache import ColdRowCache
@@ -431,14 +504,18 @@ class DistFeature:
             valid = jnp.ones((nh, B), bool)
         cap = self.request_cap or B
         key = (B, cap)
-        if key not in self._fn:
-            self._fn[key] = self._build(B, cap)
         sharding = NamedSharding(self.mesh, P(self.axis, None))
         ids = jax.device_put(ids, sharding)
         valid = jax.device_put(valid, sharding)
+        args = (self.shards, self.tables, ids, valid)
+        if key not in self._fn:
+            self._fn[key] = lookup_program(
+                self.mesh, self.axis, cap,
+                self.row_starts_host is not None)
+            register_program(self._fn[key], args)
         try:
             _CHAOS_EXCHANGE()
-            out, overflow = self._fn[key](self.shards, ids, valid)
+            out, overflow, live = self._fn[key](*args)
         except (PeerTimeout, TimeoutError):
             # peer shard timed out: degrade to the rows resolvable
             # WITHOUT the collective (owned / replicated / overlay-hit),
@@ -449,6 +526,8 @@ class DistFeature:
         self.last_degraded = False
         self.last_overflow = overflow
         self._overflow_recorded = False
+        self._last_exchange = (nh * self.n * cap, live)
+        self._exchange_recorded = False
         if ov_patch is not None:
             out = ov_patch(out)
         from ..telemetry import flightrec
@@ -475,8 +554,9 @@ class DistFeature:
         info = self.info
         src = self._host_source
         assert src is not None, (
-            "degraded lookup needs from_global_feature (the host-side "
-            "source copy is the hot tier it serves from)")
+            "degraded lookup needs from_global_feature or "
+            "from_row_ranges(overlay=True) (the host-side source copy is "
+            "the hot tier it serves from)")
         nh, B = ids.shape
         owner = info.global2host[ids]
         local = valid & (info.replicate_mask[ids]
@@ -519,6 +599,15 @@ class DistFeature:
 
                 telemetry.counter("dist_feature_overflow_total").inc(total)
         return arr
+
+    def exchange_stats(self):
+        """``(slots, live_slots)`` of the most recent lookup's request
+        exchange, summed over the ranks: slots shipped to the owners (each
+        comes back carrying a row) and those that held a request; None
+        before any call.  Read at query time like :meth:`overflow_stats`,
+        and feeds ``dist_exchange_slots_total`` /
+        ``dist_exchange_live_slots_total{layer="feature"}`` once a call."""
+        return record_exchange(self, "feature")
 
     def __getitem__(self, ids):
         ids = np.asarray(ids)

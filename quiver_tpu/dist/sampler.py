@@ -27,9 +27,16 @@ from ..resilience.errors import PeerTimeout
 from ..resilience.retry import Backoff, retry_call
 from ..utils.topology import CSRTopo
 from ..ops.sample import sample_neighbors
+from ..parallel.train import replicate
 from ..sampler import LayerBlock, SampledBatch
+from ..telemetry.device_scopes import (exchange as exchange_scope,
+                                       register_program, sampler_hop,
+                                       SAMPLER)
+from .exchange import (put_row_blocks, record_exchange, route, shard_len,
+                       unroute)
 
-__all__ = ["DistGraphSampler", "shard_csr_by_rows", "plan_row_shards"]
+__all__ = ["DistGraphSampler", "shard_csr_by_rows", "plan_row_shards",
+           "sample_program"]
 
 # fault-injection site for the per-hop all-to-all exchange (no-op
 # unless a chaos plan is installed)
@@ -78,7 +85,12 @@ def plan_row_shards(indptr, n_shards: int,
 def shard_csr_by_rows(topo: CSRTopo, n_shards: int):
     """Split a CSR into ``n_shards`` contiguous row ranges, balanced by
     edge count.  Returns (row_starts [n+1], local indptr list, local
-    indices list) — local indptr is rebased to each shard's edge offset."""
+    indices list) — local indptr is rebased to each shard's edge offset.
+
+    The plain statement of what a shard holds, as host copies: the tests
+    hold :class:`DistGraphSampler`'s device shards to it.  The sampler
+    itself puts each shard from a slice (:func:`plan_row_shards` +
+    ``exchange.put_row_blocks``) and makes no such copies."""
     indptr = topo.indptr
     row_starts = plan_row_shards(indptr, n_shards)
     local_indptr, local_indices = [], []
@@ -92,143 +104,78 @@ def shard_csr_by_rows(topo: CSRTopo, n_shards: int):
     return row_starts, local_indptr, local_indices
 
 
-class DistGraphSampler:
-    """Multi-hop sampler over a row-sharded CSR on a device mesh.
+def _cap(F: int, frac: float, n: int) -> int:
+    """A request bucket's capacity for a frontier of ``F``."""
+    if frac >= 1.0:
+        # truly exact: even if every frontier entry lands on one
+        # shard, slot < F, so overflow is impossible
+        return F
+    return min(max(int(np.ceil(F * frac / n)) * 2, 8), F)
 
-    Args:
-      topo: full host-side :class:`CSRTopo` (single-controller build).
-      mesh: mesh whose ``axis`` dimension the edges shard over.
-      sizes: fanouts (outward order).
-      request_cap: per-destination bucket capacity as a fraction of the
-        frontier (1.0 = worst case, always exact; smaller trades overflow
-        drops for bandwidth — overflowed seeds just sample 0 neighbors).
 
-    The per-hop exchange:
-      1. owner = searchsorted(row_starts, frontier ids)
-      2. all_to_all the bucketed ids to owners
-      3. owner shard samples locally (dense ``[cap, k]`` + mask)
-      4. all_to_all blocks back, unpacked to frontier order
-    """
+def _hop(axis: str, n: int, gm: str, srng: str, hop: int, k: int, cap: int):
+    layer = sampler_hop(hop)
 
-    def __init__(self, topo: CSRTopo, mesh: Mesh, sizes,
-                 axis: str = "data", request_cap_frac: float = 1.0,
-                 seed: int = 0, gather_mode: str = "auto",
-                 sample_rng: str = "auto"):
-        from ..config import resolve_gather_mode, resolve_sample_rng
-
-        self.topo = topo
-        self.mesh = mesh
-        self.axis = axis
-        self.gather_mode = resolve_gather_mode(gather_mode)
-        self.sample_rng = resolve_sample_rng(sample_rng)
-        self.sizes = list(sizes)
-        self.n = int(mesh.shape[axis])
-        self.request_cap_frac = request_cap_frac
-        row_starts, lips, lids = shard_csr_by_rows(topo, self.n)
-        self.row_starts = jnp.asarray(row_starts, jnp.int32)
-        # pad local shards to a common size, stack, shard over the mesh
-        # (round up to 128 so the element gather's 128-lane reshape covers
-        # the whole table — its tail truncation must never drop real rows)
-        r128 = lambda v: -(-v // 128) * 128
-        max_ip = r128(max(len(x) for x in lips))
-        max_id = r128(max(len(x) for x in lids))
-        # indptr pads repeat the final offset (padded "rows" read degree 0,
-        # never negative — mirrors uva.py's hot-tier padding); indices pads
-        # are plain zeros (never dereferenced: counts=min(deg,k) masks them)
-        pad_edge = lambda a, m: np.pad(a, (0, m - len(a)), mode="edge")
-        pad_zero = lambda a, m: np.pad(a, (0, m - len(a)))
-        ip = np.stack([pad_edge(x, max_ip) for x in lips]).astype(np.int32)
-        ix = np.stack([pad_zero(x, max_id) for x in lids]).astype(np.int32)
-        sh2 = NamedSharding(mesh, P(axis, None))
-        self.indptr_sh = jax.device_put(ip, sh2)
-        self.indices_sh = jax.device_put(ix, sh2)
-        self._fn = {}
-        # retry pacing for the exchange path: short, jittered (so shards
-        # that timed out together don't re-collide), seeded off the
-        # sampler seed so runs replay byte-identically
-        import random as _random
-
-        self._retry_backoff = Backoff(0.005, cap_s=0.02, jitter=0.5,
-                                      rng=_random.Random(seed))
-
-    # ------------------------------------------------------------------
-    def _hop(self, k: int, cap: int):
-        n, axis = self.n, self.axis
-        gm, srng = self.gather_mode, self.sample_rng
-        row_starts = self.row_starts
-
-        def body(ip, ix, ids, valid, key):
-            # ip: [1, max_ip]; ix: [1, max_id]; ids/valid: [1, F]
-            ip, ix, ids, valid = ip[0], ix[0], ids[0], valid[0]
-            me = jax.lax.axis_index(axis)
-            F = ids.shape[0]
+    def body(ip, ix, row_starts, ids, valid, key):
+        # ip: [max_ip]; ix: [max_id]; ids/valid: [F]
+        me = jax.lax.axis_index(axis)
+        with exchange_scope(layer):
             owner = (
                 jnp.searchsorted(row_starts, ids, side="right") - 1
             ).astype(jnp.int32)
-            owner = jnp.where(valid, owner, n)
-            onehot = owner[:, None] == jnp.arange(n)[None, :]
-            rank_in = jnp.cumsum(onehot, axis=0) - 1
-            slot = jnp.sum(jnp.where(onehot, rank_in, 0), axis=1)
-            overflow = slot >= cap
-            ok = valid & ~overflow
-            ocount = (valid & overflow).sum().astype(jnp.int32)
-            dest = jnp.where(ok, owner * cap + slot, n * cap)
-            reqs = jnp.zeros((n * cap,), jnp.int32).at[dest].add(
-                ids + 1, mode="drop"
-            ).reshape(n, cap)
-            recv = jax.lax.all_to_all(reqs, axis, split_axis=0,
-                                      concat_axis=0, tiled=True)
-            rids = recv.reshape(-1) - 1
-            rvalid = rids >= 0
+        r = route(layer, axis, n, cap, ids, owner, valid)
+        with jax.named_scope(layer):
             # rebase to local rows and sample from the local shard
-            local = jnp.clip(rids - row_starts[me], 0, ip.shape[0] - 2)
+            local = jnp.clip(r.rids - row_starts[me], 0, ip.shape[0] - 2)
             sub = jax.random.fold_in(key, me)
             out = sample_neighbors(ip, ix, local, k, sub,
-                                   seed_mask=rvalid,
+                                   seed_mask=r.rvalid,
                                    gather_mode=gm, sample_rng=srng)
+        with exchange_scope(layer):
             # ship [n, cap, k] neighbor ids (+1, 0=invalid) back
-            payload = jnp.where(out.mask, out.nbrs + 1, 0).reshape(
-                n, cap, k
-            )
-            back = jax.lax.all_to_all(payload, axis, split_axis=0,
-                                      concat_axis=0, tiled=True)
-            flat = back.reshape(n * cap, k)
-            got = jnp.take(flat, jnp.clip(dest, 0, n * cap - 1), axis=0)
-            nbrs = jnp.where(ok[:, None], got - 1, -1)
+            payload = jnp.where(out.mask, out.nbrs + 1, 0)
+        got = unroute(layer, axis, n, cap, payload, r)
+        with exchange_scope(layer):
+            nbrs = jnp.where(r.ok[:, None], got - 1, -1)
             mask = nbrs >= 0
-            return nbrs[None], mask[None], ocount
+        return nbrs, mask, r.dropped, r.live
 
-        return body
+    return body
 
-    def _build(self, B: int):
-        from ..utils.rng import default_impl
 
-        sizes = tuple(self.sizes)
-        n, axis = self.n, self.axis
-        frac = self.request_cap_frac
-        prng_impl = default_impl()  # honors QUIVER_TPU_PRNG override
+def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
+                   gather_mode: str, sample_rng: str):
+    """The jitted k-hop program over a row-sharded CSR,
+    ``jit_qt_dist_sample``: ``(indptr_sh, indices_sh, row_starts, seeds
+    [n, B], valid [n, B], key) -> (n_id, n_mask, num, blocks, dropped [n,
+    L], live [n, L])``.  It holds no table: all three arrive as
+    arguments."""
+    from ..utils.rng import default_impl
 
-        def pipeline(ip, ix, seeds, valid, seed_scalar):
-            # seeds/valid: [1, B] per-shard (every shard runs the same
-            # program on ITS OWN seed batch — data-parallel sampling)
+    sizes = tuple(sizes)
+    n = int(mesh.shape[axis])
+    prng_impl = default_impl()  # honors QUIVER_TPU_PRNG override
+
+    def pipeline(ip, ix, row_starts, seeds, valid, seed_scalar):
+        # seeds/valid: [1, B] per-shard (every shard runs the same
+        # program on ITS OWN seed batch — data-parallel sampling)
+        with jax.named_scope(SAMPLER):
             key = jax.random.key(seed_scalar, impl=prng_impl)
-            frontier, fmask = seeds[0], valid[0]
-            blocks = []
-            ocounts = []
-            for l, k in enumerate(sizes):
-                F = frontier.shape[0]
-                if frac >= 1.0:
-                    # truly exact: even if every frontier entry lands on one
-                    # shard, slot < F, so overflow is impossible
-                    cap = F
-                else:
-                    cap = min(max(int(np.ceil(F * frac / n)) * 2, 8), F)
+        ip, ix = ip[0], ix[0]
+        frontier, fmask = seeds[0], valid[0]
+        blocks = []
+        ocounts, lives = [], []
+        for l, k in enumerate(sizes):
+            F = frontier.shape[0]
+            cap = _cap(F, request_cap_frac, n)
+            with jax.named_scope(SAMPLER):
                 key, sub = jax.random.split(key)
-                nbrs, mask, oc = self._hop(k, cap)(
-                    ip, ix, frontier[None], fmask[None], sub
-                )
-                ocounts.append(oc)
-                nbrs, mask = nbrs[0], mask[0]
+            hop = _hop(axis, n, gather_mode, sample_rng, l + 1, k, cap)
+            nbrs, mask, oc, live = hop(ip, ix, row_starts, frontier, fmask,
+                                       sub)
+            ocounts.append(oc)
+            lives.append(live)
+            with jax.named_scope(sampler_hop(l + 1)):
                 pos = (F + jnp.arange(F, dtype=jnp.int32)[:, None] * k
                        + jnp.arange(k, dtype=jnp.int32)[None, :])
                 blocks.append(LayerBlock(
@@ -240,36 +187,140 @@ class DistGraphSampler:
                     [frontier, jnp.where(mask, nbrs, 0).reshape(-1)]
                 )
                 fmask = jnp.concatenate([fmask, mask.reshape(-1)])
-            # leading [1] axis on every leaf so out_specs can globalize
-            # the per-shard results onto the mesh axis
-            blocks_out = tuple(
-                LayerBlock(
-                    nbr_local=b.nbr_local[None],
-                    mask=b.mask[None],
-                    num_targets=b.num_targets[None],
-                )
-                for b in blocks[::-1]  # outermost-first, like SampledBatch
-            )
-            return (frontier[None], fmask[None],
-                    fmask.sum().astype(jnp.int32)[None], blocks_out,
-                    jnp.stack(ocounts)[None])
-
-        blocks_spec = tuple(
+        # leading [1] axis on every leaf so out_specs can globalize
+        # the per-shard results onto the mesh axis
+        blocks_out = tuple(
             LayerBlock(
-                nbr_local=P(self.axis, None, None),
-                mask=P(self.axis, None, None),
-                num_targets=P(self.axis),
+                nbr_local=b.nbr_local[None],
+                mask=b.mask[None],
+                num_targets=b.num_targets[None],
             )
-            for _ in sizes
+            for b in blocks[::-1]  # outermost-first, like SampledBatch
         )
-        f = shard_map(
-            pipeline, mesh=self.mesh,
-            in_specs=(P(self.axis, None), P(self.axis, None),
-                      P(self.axis, None), P(self.axis, None), P()),
-            out_specs=(P(self.axis, None), P(self.axis, None),
-                       P(self.axis), blocks_spec, P(self.axis, None)),
+        return (frontier[None], fmask[None],
+                fmask.sum().astype(jnp.int32)[None], blocks_out,
+                jnp.stack(ocounts)[None], jnp.stack(lives)[None])
+
+    blocks_spec = tuple(
+        LayerBlock(
+            nbr_local=P(axis, None, None),
+            mask=P(axis, None, None),
+            num_targets=P(axis),
         )
-        return jax.jit(f)
+        for _ in sizes
+    )
+    f = shard_map(
+        pipeline, mesh=mesh,
+        in_specs=(P(axis, None), P(axis, None), P(),
+                  P(axis, None), P(axis, None), P()),
+        out_specs=(P(axis, None), P(axis, None),
+                   P(axis), blocks_spec, P(axis, None),
+                   P(axis, None)),
+    )
+
+    def qt_dist_sample(indptr, indices, row_starts, seeds, valid, key):
+        return f(indptr, indices, row_starts, seeds, valid, key)
+
+    return jax.jit(qt_dist_sample)
+
+
+class DistGraphSampler:
+    """Multi-hop sampler over a row-sharded CSR on a device mesh.
+
+    Args:
+      topo: full host-side :class:`CSRTopo` (single-controller build).
+      mesh: mesh whose ``axis`` dimension the edges shard over.
+      sizes: fanouts (outward order).
+      request_cap: per-destination bucket capacity as a fraction of the
+        frontier (1.0 = worst case, always exact; smaller trades overflow
+        drops for bandwidth — overflowed seeds just sample 0 neighbors).
+      shard_rows, shard_edges: length of every device's ``indptr`` /
+        ``indices`` shard.  Default: the largest range's, rounded up to
+        the tile (:func:`~quiver_tpu.dist.exchange.shard_len`).  A larger
+        length of the caller's own keeps graphs of nearly one size (a
+        snapshot a day, a graph that grows) at ONE shape, so that the
+        programs are compiled once; what was used is ``.shard_rows`` /
+        ``.shard_edges`` (hand the first to
+        :meth:`DistFeature.from_row_ranges` for the same of the table).
+
+    The per-hop exchange:
+      1. owner = searchsorted(row_starts, frontier ids)
+      2. all_to_all the bucketed ids to owners
+      3. owner shard samples locally (dense ``[cap, k]`` + mask)
+      4. all_to_all blocks back, unpacked to frontier order
+    """
+
+    def __init__(self, topo: CSRTopo, mesh: Mesh, sizes,
+                 axis: str = "data", request_cap_frac: float = 1.0,
+                 seed: int = 0, gather_mode: str = "auto",
+                 sample_rng: str = "auto", shard_rows=None,
+                 shard_edges=None):
+        from ..config import resolve_gather_mode, resolve_sample_rng
+
+        self.topo = topo
+        self.mesh = mesh
+        self.axis = axis
+        self.gather_mode = resolve_gather_mode(gather_mode)
+        self.sample_rng = resolve_sample_rng(sample_rng)
+        self.sizes = list(sizes)
+        self.n = int(mesh.shape[axis])
+        self.request_cap_frac = request_cap_frac
+        indptr = np.asarray(topo.indptr)
+        indices = np.asarray(topo.indices)
+        row_starts = plan_row_shards(indptr, self.n)
+        self.row_starts_host = row_starts
+        # a [n+1] table, replicated: the program's argument, not its constant
+        self.row_starts = replicate(mesh, row_starts.astype(np.int32))
+        # local shards of one length (the largest range's, rounded up to
+        # the tile, or the caller's own), each put on its device from a
+        # slice of the host CSR.  A multiple of the tile is a multiple of
+        # 128: the element gather's 128-lane reshape covers the whole table,
+        # its tail truncation never drops real rows
+        rows = np.diff(row_starts)
+        edges = indptr[row_starts[1:]] - indptr[row_starts[:-1]]
+        max_ip = shard_len(int(rows.max()) + 1, shard_rows)
+        max_id = shard_len(int(edges.max()), shard_edges)
+        self.shard_rows, self.shard_edges = max_ip, max_id
+
+        def local_indptr(p):
+            # rebased to the shard's edge offset; pads repeat the final
+            # offset (padded "rows" read degree 0, never negative — mirrors
+            # uva.py's hot-tier padding)
+            lo, hi = row_starts[p], row_starts[p + 1]
+            ip = (indptr[lo: hi + 1] - indptr[lo]).astype(np.int32)
+            return np.pad(ip, (0, max_ip - len(ip)), mode="edge")
+
+        def local_indices(p):
+            # pads are never dereferenced (counts=min(deg,k) masks them):
+            # where the host array goes on past the shard, the pad is a
+            # view of what follows; only at its end is it a zero-filled copy
+            lo = int(indptr[row_starts[p]])
+            if lo + max_id <= len(indices):
+                return indices[lo: lo + max_id].astype(np.int32, copy=False)
+            ix = np.zeros(max_id, np.int32)
+            ix[:len(indices) - lo] = indices[lo:]
+            return ix
+
+        self.indptr_sh = put_row_blocks(mesh, axis, (max_ip,), local_indptr)
+        self.indices_sh = put_row_blocks(mesh, axis, (max_id,),
+                                         local_indices)
+        self._fn = {}
+        # retry pacing for the exchange path: short, jittered (so shards
+        # that timed out together don't re-collide), seeded off the
+        # sampler seed so runs replay byte-identically
+        import random as _random
+
+        self._retry_backoff = Backoff(0.005, cap_s=0.02, jitter=0.5,
+                                      rng=_random.Random(seed))
+
+    def hop_caps(self, B: int):
+        """The request bucket's capacity at each hop for a batch of ``B``
+        seeds a rank: the whole frontier at ``request_cap_frac`` 1.0."""
+        caps, F = [], B
+        for k in self.sizes:
+            caps.append(_cap(F, self.request_cap_frac, self.n))
+            F *= 1 + k
+        return caps
 
     def sample(self, seed_batches: np.ndarray, key=None):
         """``seed_batches``: [n_shards, B] — one seed batch per device;
@@ -281,6 +332,8 @@ class DistGraphSampler:
         device array of per-hop counts of frontier entries that overflowed
         their destination bucket and were silently dropped (sampled 0
         neighbors).  Always zero at ``request_cap_frac=1.0``.
+        ``self.last_live`` holds, in the same shape, the requests each rank
+        sent at each hop (its live frontier slots, whoever owns them).
         """
         seeds = jnp.asarray(seed_batches, jnp.int32)
         nd, B = seeds.shape
@@ -288,17 +341,20 @@ class DistGraphSampler:
         valid = jnp.ones((nd, B), bool)
         if key is None:
             key = np.random.randint(0, 2**31 - 1)
-        if B not in self._fn:
-            self._fn[B] = self._build(B)
         sh = NamedSharding(self.mesh, P(self.axis, None))
         seeds = jax.device_put(seeds, sh)
         valid = jax.device_put(valid, sh)
+        args = (self.indptr_sh, self.indices_sh, self.row_starts, seeds,
+                valid, jnp.int32(key))
+        if B not in self._fn:
+            self._fn[B] = sample_program(
+                self.mesh, self.axis, self.sizes, self.request_cap_frac,
+                self.gather_mode, self.sample_rng)
+            register_program(self._fn[B], args)
+
         def _exchange():
             _CHAOS_EXCHANGE()
-            return self._fn[B](
-                self.indptr_sh, self.indices_sh, seeds, valid,
-                jnp.int32(key),
-            )
+            return self._fn[B](*args)
 
         def _on_retry(attempt, exc):
             from .. import telemetry
@@ -309,12 +365,24 @@ class DistGraphSampler:
         # transient peer stall usually clears; a second timeout surfaces
         # to the caller (sampling has no partial-answer degrade: a
         # frontier with holes would silently bias the training batch)
-        n_id, n_mask, num, blocks, overflow = retry_call(
+        n_id, n_mask, num, blocks, overflow, live = retry_call(
             _exchange, attempts=2, backoff=self._retry_backoff,
             retry_on=(PeerTimeout, TimeoutError), on_retry=_on_retry)
         self.last_overflow = overflow
         self._overflow_recorded = False
+        self.last_live = live       # [n_shards, L], beside last_overflow
+        self._last_exchange = (nd * self.n * sum(self.hop_caps(B)), live)
+        self._exchange_recorded = False
         return n_id, n_mask, num, blocks
+
+    def exchange_stats(self):
+        """``(slots, live_slots)`` of the most recent ``sample``'s request
+        exchanges, summed over hops and ranks: slots shipped to the owners
+        (each comes back carrying ``k`` draws) and those that held a
+        request; None before any call.  Feeds ``dist_exchange_slots_total``
+        / ``dist_exchange_live_slots_total{layer="sampler"}`` once a call,
+        at query time like :meth:`overflow_stats`."""
+        return record_exchange(self, "sampler")
 
     def overflow_stats(self):
         """Per-hop dropped-request counts from the most recent ``sample``

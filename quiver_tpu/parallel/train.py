@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..telemetry.device_scopes import MODEL, OPTIMIZER, register_program
+
 __all__ = ["TrainState", "Frontier", "call_model", "make_train_step",
            "shard_batch", "replicate"]
 
@@ -140,40 +142,51 @@ def make_train_step(apply_fn: Callable, tx: optax.GradientTransformation,
     # XLA place one replica per device and psum the gradients.
     ndev = int(mesh.shape[data_axis])
 
-    def dp_step(state: TrainState, x, blocks, labels, label_mask, key,
-                frontier=None):
+    # the program's name (``jit_qt_dp_train_step``) keys the device
+    # scopes and the compile cache, as ``pipeline.py``'s programs' do
+    def qt_dp_train_step(state: TrainState, x, blocks, labels, label_mask,
+                         key, frontier=None):
         keys = jax.random.split(key, ndev)
 
         def compute(params):
-            losses, model_states = jax.vmap(
-                lambda xx, bb, ll, mm, kk, ff: apply_and_loss(
-                    params, state.model_state, xx, bb, ll, mm, kk, ff
-                )
-            )(x, blocks, labels, label_mask, keys, frontier)
-            return losses.mean(), jax.tree_util.tree_map(
-                lambda a: a.mean(axis=0), model_states)
+            with jax.named_scope(MODEL):
+                losses, model_states = jax.vmap(
+                    lambda xx, bb, ll, mm, kk, ff: apply_and_loss(
+                        params, state.model_state, xx, bb, ll, mm, kk, ff
+                    )
+                )(x, blocks, labels, label_mask, keys, frontier)
+                return losses.mean(), jax.tree_util.tree_map(
+                    lambda a: a.mean(axis=0), model_states)
 
         (loss, model_state), grads = jax.value_and_grad(
             compute, has_aux=True)(state.params)
-        updates, opt_state = state.tx.update(grads, state.opt_state,
-                                             state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(OPTIMIZER):
+            updates, opt_state = state.tx.update(grads, state.opt_state,
+                                                 state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.tx, model_state), loss
 
     repl = NamedSharding(mesh, P())
     data = NamedSharding(mesh, P(data_axis))
     jitted = jax.jit(
-        dp_step,
+        qt_dp_train_step,
         donate_argnums=(0,),
         in_shardings=(repl, data, data, data, data, repl, data),
         out_shardings=(repl, repl),
     )
+    registered = False
 
     # one sharding per argument: the frontier is always handed over
     def sharded_step(state, x, blocks, labels, label_mask, key,
                      frontier=None):
-        return jitted(state, x, blocks, labels, label_mask, key, frontier)
+        nonlocal registered
+        args = (state, x, blocks, labels, label_mask, key, frontier)
+        if not registered:      # before the call: ``state`` is donated
+            registered = True
+            register_program(jitted, args)
+        return jitted(*args)
 
+    sharded_step.jitted = jitted    # to lower it for a described mesh
     return sharded_step
 
 

@@ -41,6 +41,7 @@ the key.
 
 from __future__ import annotations
 
+import contextlib
 import re
 import sys
 import threading
@@ -49,7 +50,8 @@ from collections import Counter
 from typing import Dict, Optional, Tuple
 
 __all__ = ["PREFIX", "SAMPLER", "FEATURE_GATHER", "MODEL", "MODEL_PROJECT",
-           "MODEL_ATTENTION", "OPTIMIZER", "FLOW", "sampler_hop",
+           "MODEL_ATTENTION", "OPTIMIZER", "FLOW", "EXCHANGE", "sampler_hop",
+           "exchange",
            "register_program", "device_scopes", "parse_hlo_scopes",
            "instruction_key", "scoped"]
 
@@ -66,12 +68,29 @@ OPTIMIZER = PREFIX + "optimizer"
 # an instruction that runs whole computations (the ``conditional`` a
 # ``lax.cond`` becomes, a ``while``): no layer's own time, see above
 FLOW = PREFIX + "flow"
+# the part of a SHARDED layer that is there because the table is another
+# chip's (``dist/``): owner search, slot ranks, request buckets, both
+# ``all_to_all``s and the unpacking.  Always the LAST name under the layer
+# it serves (``qt.sampler.hop3/qt.exchange``, ``qt.feature.gather/
+# qt.exchange``): a whole-layer reader takes the first name and so counts
+# a layer with its exchange, a part reader the last
+EXCHANGE = PREFIX + "exchange"
 
 
 def sampler_hop(n: int) -> str:
     """Scope of hop ``n`` of a k-hop pipeline, counted from 1 at the
     seeds."""
     return f"{SAMPLER}.hop{n}"
+
+
+@contextlib.contextmanager
+def exchange(layer: str):
+    """``layer``'s exchange: ``<layer>/qt.exchange`` around what is traced
+    inside."""
+    import jax
+
+    with jax.named_scope(layer), jax.named_scope(EXCHANGE):
+        yield
 
 
 # -- the parse ------------------------------------------------------------

@@ -621,3 +621,123 @@ def test_serving_bucket_forward_compiles(one_chip, bucket):
     m = c.memory_analysis()
     assert m.argument_size_in_bytes > 1_000_000_000
     assert m.generated_code_size_in_bytes < 64 << 20
+
+
+# ------------------------------------------ the whole papers100M host (PR 34)
+# The three programs of the cell ``papers100m-sage-host.train-dist`` at its
+# real shapes over the described 2x2: a quarter of 111,059,956 rows of 128
+# bfloat16 and of 1,615,685,872 int32 edges a chip (edge-balanced ranges
+# are uneven and differ from seed to seed: the cell's program states one
+# shard length for all of them, ``programs/sage_dist.one_shape``),
+# 1,024 seeds a rank, [15,10,5], exact caps.
+HOST_NODES, HOST_EDGES, HOST_DIM, HOST_CLASSES = (111_059_956, 1_615_685_872,
+                                                  128, 172)
+HOST_RANKS = 4
+HOST_ROWS = 29_360_128     # one_shape of the 27.77 M rows a chip read there
+HOST_SHARD_EDGES = 436_207_616      # one_shape of a quarter of the edges
+HOST_FRONTIER = BATCH * 16 * 11 * 6             # 1,081,344 slots a rank
+V5E_HBM = int(15.75 * 2 ** 30)                  # what a v5e chip reports
+
+
+@pytest.fixture(scope="module")
+def host_programs(topo, one_chip):
+    """``{name: compiled}`` of ``jit_qt_dist_sample``, ``jit_qt_dist_lookup``
+    and ``jit_qt_dp_train_step``, abstract arguments sharded over a mesh of
+    the four described chips."""
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from quiver_tpu.dist.feature import lookup_program
+    from quiver_tpu.dist.sampler import sample_program
+    from quiver_tpu.models import GraphSAGE
+    from quiver_tpu.parallel import TrainState, make_train_step
+    from quiver_tpu.sampler import LayerBlock
+
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    n = HOST_RANKS
+    assert mesh.size == n
+
+    def S(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    out = {}
+    out["jit_qt_dist_sample"] = sample_program(
+        mesh, "data", FANOUT, 1.0, TPU_GATHER_MODE, TPU_SAMPLE_RNG).lower(
+        S((n, _pad128(HOST_ROWS + 1)), jnp.int32, "data", None),
+        S((n, HOST_SHARD_EDGES), jnp.int32, "data", None),
+        S((n + 1,), jnp.int32), S((n, BATCH), jnp.int32, "data", None),
+        S((n, BATCH), jnp.bool_, "data", None), S((), jnp.int32)).compile()
+    out["jit_qt_dist_lookup"] = lookup_program(
+        mesh, "data", HOST_FRONTIER, True).lower(
+        S((n, HOST_ROWS, HOST_DIM), jnp.bfloat16, "data", None, None),
+        {"row_starts": S((n + 1,), jnp.int32)},
+        S((n, HOST_FRONTIER), jnp.int32, "data", None),
+        S((n, HOST_FRONTIER), jnp.bool_, "data", None)).compile()
+
+    model = GraphSAGE(hidden=256, out_dim=HOST_CLASSES, num_layers=3,
+                      dropout=0.5)
+
+    def apply_fn(p, x, blocks, train=False, rngs=None):
+        return model.apply(p, x.astype(jnp.float32), blocks, train=train,
+                           rngs=rngs)
+
+    targets = [BATCH * 16 * 11, BATCH * 16, BATCH]      # outermost first
+    one = tuple(LayerBlock(
+        nbr_local=jax.ShapeDtypeStruct((t, k), jnp.int32),
+        mask=jax.ShapeDtypeStruct((t, k), jnp.bool_),
+        num_targets=jax.ShapeDtypeStruct((), jnp.int32))
+        for t, k in zip(targets, FANOUT[::-1]))
+    tx = optax.adam(3e-3)
+    params = jax.eval_shape(
+        model.init, jax.random.key(1),
+        jax.ShapeDtypeStruct((HOST_FRONTIER, HOST_DIM), jnp.float32), one)
+    state = jax.eval_shape(lambda p: TrainState.create(p, tx), params)
+    tm = jax.tree_util.tree_map
+    out["jit_qt_dp_train_step"] = make_train_step(
+        apply_fn, tx, mesh=mesh).jitted.lower(
+        tm(lambda s: S(s.shape, s.dtype), state),
+        S((n, HOST_FRONTIER, HOST_DIM), jnp.bfloat16, "data"),
+        tm(lambda s: S((n,) + s.shape, s.dtype, "data"), one),
+        S((n, BATCH), jnp.int32, "data"), S((n, BATCH), jnp.bool_, "data"),
+        S((2,), jnp.uint32), None).compile()
+    return out
+
+
+def _device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("name", ["jit_qt_dist_sample", "jit_qt_dist_lookup",
+                                  "jit_qt_dp_train_step"])
+def test_host_cell_program_fits_a_chip_at_the_real_shapes(host_programs,
+                                                          name):
+    """By the compiler's own estimate, a device's share of each program
+    (its tables among the arguments) is under the chip's memory, its
+    tables are arguments (no constant of a table's size in the code), and
+    the sharded two exchange through ``all-to-all``."""
+    c = host_programs[name]
+    m = c.memory_analysis()
+    assert _device_bytes(c) < V5E_HBM, m
+    assert m.generated_code_size_in_bytes < 128 << 20
+    text = c.as_text()
+    if name == "jit_qt_dp_train_step":
+        assert "all-reduce" in text and "all-to-all" not in text
+    else:
+        assert "all-to-all" in text
+        assert m.argument_size_in_bytes > 1_500_000_000
+
+
+def test_host_cell_programs_fit_beside_each_other(host_programs):
+    """The step's three programs launched back to back, two steps in
+    flight: both tables once, and every program's outputs and temporaries
+    at once (the runtime may hold them all), under the chip's memory."""
+    m = {k: c.memory_analysis() for k, c in host_programs.items()}
+    tables = (m["jit_qt_dist_sample"].argument_size_in_bytes
+              + m["jit_qt_dist_lookup"].argument_size_in_bytes)
+    rest = sum(x.temp_size_in_bytes + x.output_size_in_bytes
+               for x in m.values())
+    assert tables + rest < V5E_HBM, (tables, rest)

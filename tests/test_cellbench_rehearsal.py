@@ -69,3 +69,50 @@ def test_typed_cells_bf16_control_is_not_correct():
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert line["correct"] is False, line
     assert line["failed"] == 0      # wrong, not broken
+
+
+HOST = "papers100m-sage-host.train-dist"
+EXCHANGE = """
+import json, sys
+sys.path[:0] = [{root!r}, {bench!r}]
+import run
+_, cell, cfg, traffic = run.find_cell({cell!r})
+run.rehearsal_size(cfg, traffic)
+out = run.run_cell(cell, cfg, traffic, seed=2**31 + 77, seconds=0.5, trace=0)
+limits, stated = run.limits_of(cfg, cell), cfg["precision"]["matmul"]
+alone = out["numbers_fn"](out["replayed"], stated, fault="rank0_alone")
+facts = out["facts"]
+work = run.load_named("work", cfg["work"])
+print(json.dumps({{
+    "sound": run.compare(out["numbers"], limits)[1],
+    "alone": run.compare(alone, limits)[1], "alone_numbers": alone,
+    "facts": {{k: v for k, v in facts.items() if k.startswith("exchange")}},
+    "bytes": work.exchange_bytes(facts, cfg),
+    "bytes_uncounted": work.exchange_bytes({{}}, cfg)}}))
+"""
+
+
+def test_host_cell_counts_its_exchange_and_sees_it_left_out():
+    """The four-rank cell at its toy size: the reference with the ranks'
+    average left out (rank 0's loss and gradient alone) in the program's
+    place fails the cell's limits where the sound run passes; the live
+    slots the kind hands the exchange's readers add up by layer, and the
+    work model's bytes are made of them (nothing counted, no floor)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "-c",
+         EXCHANGE.format(root=ROOT, bench=BENCH, cell=HOST)],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["sound"] is True and line["alone"] is False, line
+    f = line["facts"]
+    assert f["exchange_drops"] == 0
+    assert (sum(f["exchange_live_hops"]) + f["exchange_live_rows"]
+            == f["exchange_live_slots"] <= f["exchange_slots"] // 4)
+    # 3 checked steps, 4 ranks, fanout [15, 10, 5], 16-wide bfloat16 rows
+    asked = sum(n * (4 + 4 * k) for n, k in zip(f["exchange_live_hops"],
+                                                (15, 10, 5)))
+    asked += f["exchange_live_rows"] * (4 + 32)
+    assert line["bytes"] == pytest.approx(2 * 0.75 * asked / 12)
+    assert line["bytes_uncounted"] is None

@@ -134,3 +134,187 @@ def test_ring_feature_lookup(mesh, rng):
     out = np.asarray(rf.lookup(ids))
     for h in range(NHOSTS):
         np.testing.assert_allclose(out[h], full[ids[h]], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# A partition by contiguous row ranges: DistFeature.from_row_ranges
+# (PERF.md, PR 34: the whole papers100M host)
+import re  # noqa: E402
+
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+from quiver_tpu import telemetry  # noqa: E402
+from quiver_tpu.dist.exchange import TILE, shard_len  # noqa: E402
+from quiver_tpu.dist.feature import lookup_program  # noqa: E402
+
+EVEN = [0, 32, 64, 96, 128, 160, 192, 224, 256]
+UNEVEN = [0, 1, 1, 40, 41, 130, 200, 255, 256]     # an empty range too
+
+
+def _boundary_ids(starts, n_rows, B, rng):
+    """Every range's first and last row in every rank's batch, the rest
+    random; some slots masked out, some ids outside the table."""
+    edge = np.unique(np.clip(np.concatenate(
+        [np.asarray(starts) - 1, np.asarray(starts)]), 0, n_rows - 1))
+    ids = rng.integers(0, n_rows, (NHOSTS, B)).astype(np.int32)
+    ids[:, :len(edge)] = edge
+    valid = rng.random((NHOSTS, B)) < 0.7
+    valid[:, :len(edge)] = True
+    return ids, valid
+
+
+@pytest.mark.parametrize("starts", [EVEN, UNEVEN], ids=["even", "uneven"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_row_range_lookup_is_the_tables_rows_bit_for_bit(mesh, rng, starts,
+                                                         dtype):
+    n, d, B = 256, 8, 48
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = DistFeature.from_row_ranges(full, mesh, starts,
+                                     dtype=jnp.dtype(dtype))
+    stored = np.asarray(jnp.asarray(full).astype(dtype))
+    ids, valid = _boundary_ids(starts, n, B, rng)
+    out = np.asarray(df.lookup(ids, valid))
+    want = np.where(valid[..., None], stored[ids], 0)
+    assert out.dtype == stored.dtype
+    assert np.array_equal(out.view(np.uint8), want.view(np.uint8))
+    assert int(df.overflow_stats().sum()) == 0
+    # no mask: every slot valid, the device array as it comes
+    out = np.asarray(df.lookup(jnp.asarray(ids)))
+    assert np.array_equal(out.view(np.uint8), stored[ids].view(np.uint8))
+
+
+def test_row_range_lookup_reads_nothing_for_ids_outside_the_table(mesh, rng):
+    n, d = 256, 4
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = DistFeature.from_row_ranges(full, mesh, UNEVEN)
+    ids = np.tile(np.array([0, -1, n, n + 7, 255, -300], np.int32),
+                  (NHOSTS, 1))
+    out = np.asarray(df.lookup(ids))
+    inside = (ids >= 0) & (ids < n)
+    assert np.array_equal(out, np.where(inside[..., None],
+                                        full[np.clip(ids, 0, n - 1)], 0))
+    slots, live = df.exchange_stats()
+    assert live == int(inside.sum()) and slots == NHOSTS * NHOSTS * 6
+
+
+def test_row_range_shards_are_slices_of_the_host_table(mesh, rng):
+    full = rng.normal(size=(256, 4)).astype(np.float32)
+    df = DistFeature.from_row_ranges(full, mesh, UNEVEN)
+    assert df._host_source is None and df.info is None
+    shards = np.asarray(df.shards)
+    assert shards.shape == (NHOSTS, shard_len(max(np.diff(UNEVEN))), 4)
+    for p in range(NHOSTS):
+        lo, hi = UNEVEN[p], UNEVEN[p + 1]
+        assert np.array_equal(shards[p, :hi - lo], full[lo:hi])
+    with pytest.raises(ValueError, match="contiguous ranges"):
+        DistFeature.from_row_ranges(full, mesh, [0, 10, 256])
+
+
+def _literal_lengths(text):
+    """Element counts of the literal constants of a lowered program: those
+    written out element by element or as a hex blob; a splat is one
+    element however wide it is broadcast."""
+    out = []
+    for m in re.finditer(
+            r'constant dense<(\[|"0x)[^>]*> : tensor<([0-9x]*)x?[a-z]',
+            text):
+        dims = [int(x) for x in m.group(2).split("x") if x]
+        out.append(int(np.prod(dims)) if dims else 1)
+    return out
+
+
+@pytest.mark.parametrize("form", ["ranges", "global2host"])
+def test_lookup_program_holds_no_node_length_constant(mesh, rng, form):
+    """The tables are the program's ARGUMENTS: its lowered text holds no
+    literal longer than the range starts, whatever the node count."""
+    n, d, B = 50_000, 8, 64
+    sh = lambda *spec: NamedSharding(mesh, P(*spec))
+    S = jax.ShapeDtypeStruct
+    if form == "ranges":
+        tables = {"row_starts": S((NHOSTS + 1,), jnp.int32, sharding=sh())}
+    else:
+        tables = {k: S((NHOSTS,) if k == "owned_counts" else (n,),
+                       jnp.bool_ if k == "rep_mask" else jnp.int32,
+                       sharding=sh())
+                  for k in ("g2l", "g2h", "rep_mask", "rep_rank",
+                            "owned_counts")}
+    text = lookup_program(mesh, "data", B, form == "ranges").lower(
+        S((NHOSTS, n // NHOSTS + 1, d), jnp.float32,
+          sharding=sh("data", None, None)), tables,
+        S((NHOSTS, B), jnp.int32, sharding=sh("data", None)),
+        S((NHOSTS, B), jnp.bool_, sharding=sh("data", None))).as_text()
+    assert "all_to_all" in text
+    assert max(_literal_lengths(text), default=0) <= NHOSTS + 1
+
+
+def test_global2host_form_keeps_its_answers_with_maps_as_arguments(mesh,
+                                                                  rng):
+    n, d, B = 300, 8, 40
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    g2h = rng.integers(0, NHOSTS, n).astype(np.int32)
+    info = PartitionInfo(host=0, hosts=NHOSTS, global2host=g2h,
+                         replicate=np.arange(0, n, 17))
+    df = DistFeature.from_global_feature(full, mesh, info)
+    assert set(df.tables) == {"g2l", "g2h", "rep_mask", "rep_rank",
+                              "owned_counts"}
+    assert all(len(t.sharding.device_set) == NHOSTS
+               for t in df.tables.values())
+    ids = rng.integers(0, n, (NHOSTS, B)).astype(np.int32)
+    valid = rng.random((NHOSTS, B)) < 0.5
+    out = np.asarray(df.lookup(ids, valid))
+    assert np.array_equal(out, np.where(valid[..., None], full[ids], 0))
+
+
+def test_exchange_counters_count_slots_and_live_slots(mesh, rng):
+    n, d, B = 256, 4, 32
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = DistFeature.from_row_ranges(full, mesh, EVEN)
+    assert df.exchange_stats() is None
+
+    def counted(name):
+        return telemetry.counter(name, layer="feature").value
+
+    before = (counted("dist_exchange_slots_total"),
+              counted("dist_exchange_live_slots_total"))
+    valid = rng.random((NHOSTS, B)) < 0.25
+    df.lookup(rng.integers(0, n, (NHOSTS, B)).astype(np.int32), valid)
+    slots, live = df.exchange_stats()
+    assert (slots, live) == (NHOSTS * NHOSTS * B, int(valid.sum()))
+    assert df.exchange_stats() == (slots, live)     # counted once a call
+    assert counted("dist_exchange_slots_total") - before[0] == slots
+    assert counted("dist_exchange_live_slots_total") - before[1] == live
+    assert int(df.overflow_stats().sum()) == 0
+
+
+def test_row_ranges_with_the_overlay_kept(mesh, rng):
+    n, d, B = 256, 4, 32
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = DistFeature.from_row_ranges(full, mesh, UNEVEN, overlay=True)
+    df.enable_cold_cache(rows=64, admit_threshold=1)
+    ids = rng.integers(0, n, (NHOSTS, B)).astype(np.int32)
+    for _ in range(3):      # admitted on the first pass, served after
+        assert np.array_equal(np.asarray(df.lookup(ids)), full[ids])
+
+
+def test_a_shard_is_as_long_as_its_largest_range_or_as_the_caller_says(mesh,
+                                                                       rng):
+    """The library pads a shard to the chip's tile and no further (an
+    unaligned ``[1, E]`` shard is re-laid out whole in every step: 7.8 ms
+    on the chip, PR 34); a caller who wants graphs of nearly one size at
+    one shape states the length, and one too short is refused."""
+    for v in (1, 15, 1023, 1024, 1025, 27_774_070, 403_921_468):
+        assert v <= shard_len(v) < v + TILE and shard_len(v) % TILE == 0
+        assert shard_len(shard_len(v)) == shard_len(v)
+    assert shard_len(27_774_070, 29_360_128) == 29_360_128 == shard_len(
+        27_768_776, 29_360_128)
+    assert shard_len(10, 1500) == 2 * TILE
+    with pytest.raises(ValueError, match="cannot hold"):
+        shard_len(27_774_070, 27_000_000)
+    full = rng.normal(size=(256, 4)).astype(np.float32)
+    df = DistFeature.from_row_ranges(full, mesh, UNEVEN, shard_rows=3 * TILE)
+    assert df.shards.shape == (NHOSTS, 3 * TILE, 4)
+    ids = rng.integers(0, 256, (NHOSTS, 16)).astype(np.int32)
+    assert np.array_equal(np.asarray(df.lookup(ids)), full[ids])
+    with pytest.raises(ValueError, match="cannot hold"):
+        DistFeature.from_row_ranges(full, mesh, UNEVEN, shard_rows=16)

@@ -1,6 +1,11 @@
 """Scaled distributed-training evidence (VERDICT next #7): 100K nodes,
 reference fanout [15,10,5], full dist stack on the virtual 8-mesh, loss
-decreases over 20+ steps, zero silent drops at exact caps."""
+decreases over 20+ steps, zero silent drops at exact caps.
+
+A dry run on 8 virtual CPU devices, as the ``MULTICHIP_r0*.json`` files at
+the root are: it says the stack is wired and exact, nothing about chips.
+The evidence on chips is the benchmark cell
+``papers100m-sage-host.train-dist`` (four v5e chips; PERF.md)."""
 
 import numpy as np
 import pytest

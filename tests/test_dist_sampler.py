@@ -164,3 +164,186 @@ def test_dist_sampler_padded_indptr_is_monotone(small_graph):
     ip = np.asarray(s.indptr_sh)
     for row in ip:
         assert np.all(np.diff(row.astype(np.int64)) >= 0)
+
+
+# ---------------------------------------------------------------------------
+# The sharded step the cell ``papers100m-sage-host.train-dist`` times
+# (PERF.md, PR 34): draws against the WHOLE graph, the exchange's counters,
+# its scopes, and the three-program step against the plain DDP reference.
+import os  # noqa: E402
+import sys  # noqa: E402
+
+import jax.numpy as jnp  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# appended, not put first: ``cellbench/tests`` must not come to stand for
+# this directory's ``tests`` package in files collected after this one
+for p in (ROOT, os.path.join(ROOT, "cellbench")):
+    if p not in sys.path:
+        sys.path.append(p)
+
+RANKS = 4
+CELL = "papers100m-sage-host.train-dist"
+
+
+@pytest.fixture(scope="module")
+def host_cell():
+    """``(cfg, data, reference, program)`` of the cell at its rehearsal
+    size over four of the eight virtual devices, float32 rows."""
+    import run
+
+    _, cell, cfg, traffic = run.find_cell(CELL)
+    run.rehearsal_size(cfg, traffic)
+    cfg["feature_dtype"] = "float32"
+    parts = run.parts_of(cfg, traffic)
+    data = parts["reference"].make_data(cfg, 2 ** 31 + 34)
+    prog = parts["program"].Program(cfg, data, jax.devices()[:RANKS])
+    return cfg, data, parts["reference"], prog
+
+
+def _host_seeds(cfg, data, rng):
+    return rng.choice(cfg["nodes"], RANKS * cfg["batch"],
+                      replace=False).astype(np.int32)
+
+
+def test_every_ranks_draw_is_held_to_the_whole_graph(host_cell, rng):
+    cfg, data, ref, prog = host_cell
+    seeds = _host_seeds(cfg, data, rng)
+    n_id, n_mask, layers = prog.replay_sample(seeds, 1234)
+    assert n_id.shape[0] == RANKS
+    bad, edges = ref.check_sample(data["indptr"], data["indices"],
+                                  cfg["fanout"], seeds, n_id, n_mask, layers)
+    assert edges > 0 and not any(bad.values()), bad
+    # three quarters of what a rank asks for is another rank's
+    starts = prog.sampler.row_starts_host
+    owner = np.searchsorted(starts, n_id, side="right") - 1
+    remote = (owner != np.arange(RANKS)[:, None]) & n_mask
+    assert 0.6 < remote.sum() / n_mask.sum() < 0.9
+    rows = prog.replay_rows(n_id, n_mask).copy()
+    assert ref.check_rows(data["features"], n_id, n_mask, rows) == 0
+    rows[1, 0, 0] += 1.0    # the check sees one wrong row
+    assert ref.check_rows(data["features"], n_id, n_mask, rows) == 1
+
+
+def test_exchange_drops_nothing_at_default_caps_and_counts_its_slots(
+        host_cell, rng):
+    cfg, data, _, prog = host_cell
+    s = prog.sampler
+    seeds = _host_seeds(cfg, data, rng)
+    n_id, n_mask, _ = prog.replay_sample(seeds, 77)
+    prog.replay_rows(n_id, n_mask)
+    assert prog.exchange_drops() == 0
+    assert s.overflow_stats().shape == (RANKS, len(cfg["fanout"]))
+    caps = s.hop_caps(cfg["batch"])
+    assert caps == [16, 16 * 16, 16 * 16 * 11]      # the whole frontier
+    slots, live = s.exchange_stats()
+    assert slots == RANKS * RANKS * sum(caps)
+    # every live target of every hop was asked of its owner, once
+    assert live == sum(int(n_mask[:, :F].sum()) for F in caps)
+    f_slots, f_live = prog.feature.exchange_stats()
+    assert f_slots == RANKS * RANKS * n_id.shape[1]
+    assert f_live == int(n_mask.sum())
+    assert prog.exchange_slots() == (slots + f_slots, live + f_live)
+
+
+def test_the_three_programs_carry_their_names_and_scopes(host_cell,
+                                                         monkeypatch):
+    import re
+
+    from quiver_tpu import telemetry
+
+    # the module, not the function the package re-exports under its name
+    ds = sys.modules["quiver_tpu.telemetry.device_scopes"]
+    cfg, data, _, prog = host_cell
+    monkeypatch.setattr(ds, "_programs", {})
+    monkeypatch.setattr(ds, "_tables", {})
+    prog.sampler._fn.clear()    # programs register at their first call
+    prog.feature._fn.clear()
+    state, step = prog.fused_train_step()
+    B = RANKS * cfg["batch"]
+    seeds = jnp.arange(B, dtype=jnp.int32)
+    state, loss = step(state, seeds, jnp.asarray(data["labels"][:B]),
+                       jnp.ones((B,), bool), prog.make_key(5))
+    assert np.isfinite(float(loss))
+    tables = telemetry.device_scopes()
+    assert set(tables) == {"jit_qt_dist_sample", "jit_qt_dist_lookup",
+                           "jit_qt_dp_train_step"}
+    names = {k: set(v.values()) for k, v in tables.items()}
+    last = lambda n: (re.findall(r"qt(?:\.[A-Za-z0-9_]+)+", n)
+                      or [None])[-1]
+    first = lambda n: (re.findall(r"qt(?:\.[A-Za-z0-9_]+)+", n)
+                       or [None])[0]
+    sample = names["jit_qt_dist_sample"]
+    for hop in (1, 2, 3):
+        scope = ds.sampler_hop(hop)
+        assert any(first(n) == scope and last(n) == ds.EXCHANGE
+                   for n in sample), f"no exchange under hop {hop}"
+        assert any(last(n) == scope for n in sample), \
+            f"no local draw under hop {hop}"
+    lookup = names["jit_qt_dist_lookup"]
+    assert any(first(n) == ds.FEATURE_GATHER and last(n) == ds.EXCHANGE
+               for n in lookup)
+    assert any(last(n) == ds.FEATURE_GATHER for n in lookup)
+    assert all(first(n) in (None, ds.FEATURE_GATHER, ds.FLOW)
+               for n in lookup)
+    # the collectives themselves sit under the exchange
+    for prog_names in (sample, lookup):
+        assert any("all_to_all" in n and last(n) == ds.EXCHANGE
+                   for n in prog_names)
+    train = names["jit_qt_dp_train_step"]
+    assert any(ds.MODEL in n and "transpose(" not in n for n in train)
+    assert any(ds.MODEL in n and "transpose(" in n for n in train)
+    assert any(ds.OPTIMIZER in n for n in train)
+    assert not any(ds.EXCHANGE in n for n in train)
+
+
+def test_three_program_step_follows_the_ddp_reference(host_cell, rng):
+    """Loss, every leaf of the first gradient and the parameters after
+    three Adam steps, at ``highest``: the reference computes each rank's
+    loss and gradient apart and averages them."""
+    cfg, data, ref, prog = host_cell
+    host = dict(cfg, batch=RANKS * cfg["batch"])
+    B = host["batch"]
+    state, step = prog.fused_train_step()
+    batches, losses, first = [], [], None
+    for i in range(3):
+        seeds = _host_seeds(cfg, data, rng)
+        key = jax.random.fold_in(prog.make_key(34), i)
+        labels = data["labels"][seeds]
+        state, loss = step(state, jnp.asarray(seeds), jnp.asarray(labels),
+                           jnp.ones((B,), bool), key)
+        losses.append(float(loss))
+        if first is None:
+            first = prog.first_gradient(state)
+        ks, kd = prog.step_keys(key)
+        n_id, n_mask, layers = prog.replay_sample(seeds, ks)
+        batches.append({"rows": data["features"][n_id], "layers": layers,
+                        "labels": labels, "drop_key": kd})
+    want_losses, want_first, want_params = ref.train_follow(
+        data["params"], batches, host, "highest")
+    np.testing.assert_allclose(losses, want_losses, rtol=2e-6)
+    leaves = jax.tree_util.tree_leaves_with_path
+    for (path, got), (_, want) in zip(leaves(first), leaves(want_first)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
+    for (path, got), (_, want) in zip(leaves(state.params),
+                                      leaves(want_params)):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                                   atol=1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+    # and a dropped rank is seen: the first half of the host's batch alone
+    half_losses, _, _ = ref.train_follow(data["params"], batches, host,
+                                         "highest", fault="half_batch")
+    assert abs(half_losses[0] - want_losses[0]) > 1e-3
+
+
+def test_sampler_shards_are_put_from_slices_of_the_host_csr(small_graph):
+    mesh = make_mesh(("data",))
+    s = DistGraphSampler(small_graph, mesh, [3])
+    starts, lips, lids = shard_csr_by_rows(small_graph, 8)
+    assert np.array_equal(s.row_starts_host, starts)
+    ip, ix = np.asarray(s.indptr_sh), np.asarray(s.indices_sh)
+    for p in range(8):
+        assert np.array_equal(ip[p, :len(lips[p])], lips[p])
+        assert (ip[p, len(lips[p]):] == lips[p][-1]).all()
+        assert np.array_equal(ix[p, :len(lids[p])], lids[p])
