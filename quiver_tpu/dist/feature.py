@@ -6,9 +6,13 @@ Reference parity: ``PartitionInfo`` (``feature.py:461-526``) and
 
 TPU-first redesign: the whole request/response dance — dispatch ids by
 owner, send id lists, remote gather, send features back, scatter merge — is
-ONE jitted ``shard_map`` body with two ``all_to_all``s.  Ragged per-host
-request counts become fixed-capacity buckets with validity masks (the
-static-shape discipline); XLA overlaps the collective with the local gather.
+ONE jitted ``shard_map`` body with two ``all_to_all``s a round.  Ragged
+per-host request counts become buckets of a static length with validity
+masks (the static-shape discipline): at the default sized for an owner's
+share of the batch and shipped in as many rounds as the fullest bucket asks
+for (one where the ids are spread, ``n`` where one host owns them all;
+nothing dropped), under a caller's ``request_cap`` one round that drops and
+counts what overflows (``dist/exchange.py``).
 
 Layout: the partitioned feature lives as a single ``jax.Array`` of shape
 ``[n_parts * max_local, D]`` sharded over the mesh axis, so "host p's
@@ -33,8 +37,8 @@ from ..resilience.deadline import check_ambient
 from ..resilience.errors import PeerTimeout
 from ..telemetry.device_scopes import (FEATURE_GATHER, exchange as
                                        exchange_scope, register_program)
-from .exchange import (put_row_blocks, record_exchange, route, shard_len,
-                       unroute)
+from .exchange import (bucket_len, exchange, put_row_blocks,
+                       record_exchange, shard_len)
 
 __all__ = ["PartitionInfo", "DistFeature", "lookup_program"]
 
@@ -111,12 +115,15 @@ class PartitionInfo:
         return out_ids, out_pos
 
 
-def lookup_program(mesh: Mesh, axis: str, cap: int, ranged: bool):
+def lookup_program(mesh: Mesh, axis: str, cap, ranged: bool):
     """The jitted lookup over a sharded table, ``jit_qt_dist_lookup``:
     ``(shards [n, m, D], tables, ids [n, B], valid [n, B]) -> (rows [n, B,
-    D], dropped [n], live [n])``.  ``tables`` is the partition's own,
-    replicated: ``{"row_starts"}`` when ``ranged``, else a global2host
-    partition's five maps.  It holds no table: all arrive as arguments."""
+    D], dropped [n], live [n], rounds [n])``.  ``cap``: a request bucket's
+    slots for one round that drops what overflows, or None for the exact
+    exchange in rounds (``dist.exchange.exchange``).  ``tables`` is the
+    partition's own, replicated: ``{"row_starts"}`` when ``ranged``, else a
+    global2host partition's five maps.  It holds no table: all arrive as
+    arguments."""
     n = int(mesh.shape[axis])
 
     def body(shard, tables, ids, valid):
@@ -134,32 +141,33 @@ def lookup_program(mesh: Mesh, axis: str, cap: int, ranged: bool):
             else:
                 owner = jnp.where(tables["rep_mask"][ids], me,
                                   tables["g2h"][ids])
-        # ---- phase 1: ship request ids to owners
-        r = route(FEATURE_GATHER, axis, n, cap, ids, owner, valid)
-        with exchange_scope(FEATURE_GATHER):
-            if ranged:
-                lslot = r.rids - starts[me]
-            else:
-                rid = jnp.where(r.rvalid, r.rids, 0)
-                lslot = jnp.where(
-                    tables["rep_mask"][rid],
-                    tables["owned_counts"][me] + tables["rep_rank"][rid],
-                    tables["g2l"][rid])
-        # the owner's fetch, told which received slots are empty: an
-        # empty slot asks for a row of its own (PERF.md, PR 33) and
-        # its answer is never unpacked
-        feats = _lookup_tables((shard, None), lslot, r.rvalid)
-        # ---- phase 2: ship features back to requesters
-        got = unroute(FEATURE_GATHER, axis, n, cap, feats, r)
-        with exchange_scope(FEATURE_GATHER):
-            out = jnp.where(r.ok[:, None], got, 0)
-        return out[None], r.dropped[None], r.live[None]
+
+        def fetch(rids, rvalid, r):
+            with exchange_scope(FEATURE_GATHER):
+                if ranged:
+                    lslot = rids - starts[me]
+                else:
+                    rid = jnp.where(rvalid, rids, 0)
+                    lslot = jnp.where(
+                        tables["rep_mask"][rid],
+                        tables["owned_counts"][me] + tables["rep_rank"][rid],
+                        tables["g2l"][rid])
+            # the owner's fetch, told which received slots are empty: an
+            # empty slot asks for a row of its own (PERF.md, PR 33) and
+            # its answer is never unpacked
+            return _lookup_tables((shard, None), lslot, rvalid)
+
+        # ship request ids to owners, features back to requesters
+        out, counts = exchange(
+            FEATURE_GATHER, axis, n, cap, ids, owner, valid, fetch,
+            jax.ShapeDtypeStruct(shard.shape[1:], shard.dtype))
+        return (out[None],) + tuple(c[None] for c in counts)
 
     f = shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None, None), P(), P(axis, None),
                   P(axis, None)),
-        out_specs=(P(axis, None, None), P(axis), P(axis)),
+        out_specs=(P(axis, None, None), P(axis), P(axis), P(axis)),
     )
 
     def qt_dist_lookup(shards, tables, ids, valid):
@@ -486,9 +494,12 @@ class DistFeature:
         After each call ``self.last_overflow`` holds a ``[n_hosts]`` device
         array counting queries that overflowed their destination bucket and
         got ZERO feature rows.  Always zero when ``request_cap`` is None
-        (cap = B, the exact worst case); check :meth:`overflow_stats` when
-        running with a reduced cap — training on silently zeroed features
-        is the failure mode this guards against."""
+        (the exact exchange: buckets sized for an owner's share of the
+        batch, in as many rounds as the counts ask for,
+        ``self.last_rounds``); check :meth:`overflow_stats` when running
+        with a cap of your own, which is ONE round of buckets that long —
+        training on silently zeroed features is the failure mode this
+        guards against."""
         check_ambient("dist_feature")
         ov_patch = None
         if self.cold_cache is not None and not isinstance(ids, jax.Array):
@@ -502,7 +513,7 @@ class DistFeature:
         nh, B = ids.shape
         if valid is None:
             valid = jnp.ones((nh, B), bool)
-        cap = self.request_cap or B
+        cap = self.request_cap or None      # None: exact, in rounds
         key = (B, cap)
         sharding = NamedSharding(self.mesh, P(self.axis, None))
         ids = jax.device_put(ids, sharding)
@@ -515,7 +526,7 @@ class DistFeature:
             register_program(self._fn[key], args)
         try:
             _CHAOS_EXCHANGE()
-            out, overflow, live = self._fn[key](*args)
+            out, overflow, live, rounds = self._fn[key](*args)
         except (PeerTimeout, TimeoutError):
             # peer shard timed out: degrade to the rows resolvable
             # WITHOUT the collective (owned / replicated / overlay-hit),
@@ -526,7 +537,9 @@ class DistFeature:
         self.last_degraded = False
         self.last_overflow = overflow
         self._overflow_recorded = False
-        self._last_exchange = (nh * self.n * cap, live)
+        self.last_rounds = rounds
+        self._last_exchange = (self.n * bucket_len(B, self.n, cap), rounds,
+                               live)
         self._exchange_recorded = False
         if ov_patch is not None:
             out = ov_patch(out)
@@ -574,6 +587,7 @@ class DistFeature:
         self.last_degraded = True
         self.last_degraded_mask = local
         self.last_overflow = np.zeros((nh,), np.int32)
+        self.last_rounds = np.zeros((nh,), np.int32)    # no exchange ran
         self._overflow_recorded = True
         telemetry.counter("dist_feature_degraded_total").inc()
         if flightrec.tracing():
@@ -602,11 +616,12 @@ class DistFeature:
 
     def exchange_stats(self):
         """``(slots, live_slots)`` of the most recent lookup's request
-        exchange, summed over the ranks: slots shipped to the owners (each
-        comes back carrying a row) and those that held a request; None
-        before any call.  Read at query time like :meth:`overflow_stats`,
-        and feeds ``dist_exchange_slots_total`` /
-        ``dist_exchange_live_slots_total{layer="feature"}`` once a call."""
+        exchange, summed over the ranks: slots shipped to the owners (rounds
+        x ranks x bucket; each comes back carrying a row) and those that
+        held a request; None before any call.  Read at query time like
+        :meth:`overflow_stats`, and feeds ``dist_exchange_slots_total`` /
+        ``dist_exchange_live_slots_total`` /
+        ``dist_exchange_rounds_total{layer="feature"}`` once a call."""
         return record_exchange(self, "feature")
 
     def __getitem__(self, ids):

@@ -5,9 +5,10 @@ stays in pinned host memory and CUDA kernels read it over PCIe
 (``quiver.cu.hpp:16-26``, mode ``ZERO_COPY``).  The TPU equivalent is to
 **shard the edge array over the mesh** and let ICI play the role of PCIe —
 each device owns a contiguous row range (so ``indptr`` stays local and
-dense), seeds are routed to their owner with the same fixed-capacity
-all-to-all bucketing as :class:`quiver_tpu.dist.DistFeature`, sampled
-neighbor blocks ride back on a second all-to-all.
+dense), seeds are routed to their owner through the exchange
+:class:`quiver_tpu.dist.DistFeature` shares (``dist/exchange.py``: buckets
+sized for an owner's share, as many rounds of two all-to-alls as the
+counts ask for), sampled neighbor blocks ride back on the second.
 
 papers100M at int32 is ~6.5 GB of indices — over a v5e-8 that is <1 GB per
 chip, leaving HBM for features.  Single-chip sampling of a sharded graph is
@@ -32,8 +33,8 @@ from ..sampler import LayerBlock, SampledBatch
 from ..telemetry.device_scopes import (exchange as exchange_scope,
                                        register_program, sampler_hop,
                                        SAMPLER)
-from .exchange import (put_row_blocks, record_exchange, route, shard_len,
-                       unroute)
+from .exchange import (bucket_len, exchange, put_row_blocks,
+                       record_exchange, shard_len)
 
 __all__ = ["DistGraphSampler", "shard_csr_by_rows", "plan_row_shards",
            "sample_program"]
@@ -104,16 +105,15 @@ def shard_csr_by_rows(topo: CSRTopo, n_shards: int):
     return row_starts, local_indptr, local_indices
 
 
-def _cap(F: int, frac: float, n: int) -> int:
-    """A request bucket's capacity for a frontier of ``F``."""
+def _cap(F: int, frac: float, n: int):
+    """A caller's request bucket for a frontier of ``F``: None at ``frac``
+    1.0, the exact exchange, which sizes its own and drops nothing."""
     if frac >= 1.0:
-        # truly exact: even if every frontier entry lands on one
-        # shard, slot < F, so overflow is impossible
-        return F
+        return None
     return min(max(int(np.ceil(F * frac / n)) * 2, 8), F)
 
 
-def _hop(axis: str, n: int, gm: str, srng: str, hop: int, k: int, cap: int):
+def _hop(axis: str, n: int, gm: str, srng: str, hop: int, k: int, cap):
     layer = sampler_hop(hop)
 
     def body(ip, ix, row_starts, ids, valid, key):
@@ -123,22 +123,26 @@ def _hop(axis: str, n: int, gm: str, srng: str, hop: int, k: int, cap: int):
             owner = (
                 jnp.searchsorted(row_starts, ids, side="right") - 1
             ).astype(jnp.int32)
-        r = route(layer, axis, n, cap, ids, owner, valid)
-        with jax.named_scope(layer):
-            # rebase to local rows and sample from the local shard
-            local = jnp.clip(r.rids - row_starts[me], 0, ip.shape[0] - 2)
-            sub = jax.random.fold_in(key, me)
-            out = sample_neighbors(ip, ix, local, k, sub,
-                                   seed_mask=r.rvalid,
-                                   gather_mode=gm, sample_rng=srng)
+
+        def draw(rids, rvalid, r):
+            with jax.named_scope(layer):
+                # rebase to local rows and sample from the local shard;
+                # a round draws from a counter stream of its own
+                local = jnp.clip(rids - row_starts[me], 0, ip.shape[0] - 2)
+                sub = jax.random.fold_in(jax.random.fold_in(key, me), r)
+                out = sample_neighbors(ip, ix, local, k, sub,
+                                       seed_mask=rvalid,
+                                       gather_mode=gm, sample_rng=srng)
+            with exchange_scope(layer):
+                # ship [n * bucket, k] neighbor ids (+1, 0=invalid) back
+                return jnp.where(out.mask, out.nbrs + 1, 0)
+
+        got, counts = exchange(layer, axis, n, cap, ids, owner, valid, draw,
+                               jax.ShapeDtypeStruct((k,), jnp.int32))
         with exchange_scope(layer):
-            # ship [n, cap, k] neighbor ids (+1, 0=invalid) back
-            payload = jnp.where(out.mask, out.nbrs + 1, 0)
-        got = unroute(layer, axis, n, cap, payload, r)
-        with exchange_scope(layer):
-            nbrs = jnp.where(r.ok[:, None], got - 1, -1)
+            nbrs = got - 1      # no request sent: 0, so -1
             mask = nbrs >= 0
-        return nbrs, mask, r.dropped, r.live
+        return nbrs, mask, counts
 
     return body
 
@@ -148,8 +152,8 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
     """The jitted k-hop program over a row-sharded CSR,
     ``jit_qt_dist_sample``: ``(indptr_sh, indices_sh, row_starts, seeds
     [n, B], valid [n, B], key) -> (n_id, n_mask, num, blocks, dropped [n,
-    L], live [n, L])``.  It holds no table: all three arrive as
-    arguments."""
+    L], live [n, L], rounds [n, L])``.  It holds no table: all three arrive
+    as arguments."""
     from ..utils.rng import default_impl
 
     sizes = tuple(sizes)
@@ -164,17 +168,15 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
         ip, ix = ip[0], ix[0]
         frontier, fmask = seeds[0], valid[0]
         blocks = []
-        ocounts, lives = [], []
+        counted = []
         for l, k in enumerate(sizes):
             F = frontier.shape[0]
             cap = _cap(F, request_cap_frac, n)
             with jax.named_scope(SAMPLER):
                 key, sub = jax.random.split(key)
             hop = _hop(axis, n, gather_mode, sample_rng, l + 1, k, cap)
-            nbrs, mask, oc, live = hop(ip, ix, row_starts, frontier, fmask,
-                                       sub)
-            ocounts.append(oc)
-            lives.append(live)
+            nbrs, mask, counts = hop(ip, ix, row_starts, frontier, fmask, sub)
+            counted.append(counts)
             with jax.named_scope(sampler_hop(l + 1)):
                 pos = (F + jnp.arange(F, dtype=jnp.int32)[:, None] * k
                        + jnp.arange(k, dtype=jnp.int32)[None, :])
@@ -199,7 +201,7 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
         )
         return (frontier[None], fmask[None],
                 fmask.sum().astype(jnp.int32)[None], blocks_out,
-                jnp.stack(ocounts)[None], jnp.stack(lives)[None])
+                *(jnp.stack(c)[None] for c in zip(*counted)))
 
     blocks_spec = tuple(
         LayerBlock(
@@ -215,7 +217,7 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
                   P(axis, None), P(axis, None), P()),
         out_specs=(P(axis, None), P(axis, None),
                    P(axis), blocks_spec, P(axis, None),
-                   P(axis, None)),
+                   P(axis, None), P(axis, None)),
     )
 
     def qt_dist_sample(indptr, indices, row_starts, seeds, valid, key):
@@ -231,9 +233,13 @@ class DistGraphSampler:
       topo: full host-side :class:`CSRTopo` (single-controller build).
       mesh: mesh whose ``axis`` dimension the edges shard over.
       sizes: fanouts (outward order).
-      request_cap: per-destination bucket capacity as a fraction of the
-        frontier (1.0 = worst case, always exact; smaller trades overflow
-        drops for bandwidth — overflowed seeds just sample 0 neighbors).
+      request_cap_frac: 1.0 (the default) is the exact exchange: buckets
+        sized for an owner's share of the frontier, shipped in as many
+        rounds as the fullest bucket asks for, nothing dropped whatever
+        the skew (``dist/exchange.py``).  Smaller is ONE round of buckets
+        of that fraction of the frontier (twice an even share of it):
+        overflowed seeds sample 0 neighbors and ``overflow_stats()``
+        counts them.
       shard_rows, shard_edges: length of every device's ``indptr`` /
         ``indices`` shard.  Default: the largest range's, rounded up to
         the tile (:func:`~quiver_tpu.dist.exchange.shard_len`).  A larger
@@ -243,10 +249,10 @@ class DistGraphSampler:
         ``.shard_edges`` (hand the first to
         :meth:`DistFeature.from_row_ranges` for the same of the table).
 
-    The per-hop exchange:
+    The per-hop exchange (``dist.exchange.exchange``), per round:
       1. owner = searchsorted(row_starts, frontier ids)
-      2. all_to_all the bucketed ids to owners
-      3. owner shard samples locally (dense ``[cap, k]`` + mask)
+      2. all_to_all the round's bucketed ids to owners
+      3. owner shard samples locally (dense ``[n * bucket, k]`` + mask)
       4. all_to_all blocks back, unpacked to frontier order
     """
 
@@ -314,11 +320,15 @@ class DistGraphSampler:
                                       rng=_random.Random(seed))
 
     def hop_caps(self, B: int):
-        """The request bucket's capacity at each hop for a batch of ``B``
-        seeds a rank: the whole frontier at ``request_cap_frac`` 1.0."""
+        """The request bucket's slots at each hop for a batch of ``B``
+        seeds a rank: at ``request_cap_frac`` 1.0 the exact exchange's
+        (:func:`~quiver_tpu.dist.exchange.bucket_len`: an owner's share of
+        the frontier, shipped in as many rounds as the counts ask for),
+        else the caller's one-round cap."""
         caps, F = [], B
         for k in self.sizes:
-            caps.append(_cap(F, self.request_cap_frac, self.n))
+            caps.append(bucket_len(
+                F, self.n, _cap(F, self.request_cap_frac, self.n)))
             F *= 1 + k
         return caps
 
@@ -333,7 +343,9 @@ class DistGraphSampler:
         their destination bucket and were silently dropped (sampled 0
         neighbors).  Always zero at ``request_cap_frac=1.0``.
         ``self.last_live`` holds, in the same shape, the requests each rank
-        sent at each hop (its live frontier slots, whoever owns them).
+        sent at each hop (its live frontier slots, whoever owns them), and
+        ``self.last_rounds`` the rounds each hop's exchange was shipped in
+        (every rank's the same; 1 under a caller's cap).
         """
         seeds = jnp.asarray(seed_batches, jnp.int32)
         nd, B = seeds.shape
@@ -365,23 +377,26 @@ class DistGraphSampler:
         # transient peer stall usually clears; a second timeout surfaces
         # to the caller (sampling has no partial-answer degrade: a
         # frontier with holes would silently bias the training batch)
-        n_id, n_mask, num, blocks, overflow, live = retry_call(
+        n_id, n_mask, num, blocks, overflow, live, rounds = retry_call(
             _exchange, attempts=2, backoff=self._retry_backoff,
             retry_on=(PeerTimeout, TimeoutError), on_retry=_on_retry)
         self.last_overflow = overflow
         self._overflow_recorded = False
         self.last_live = live       # [n_shards, L], beside last_overflow
-        self._last_exchange = (nd * self.n * sum(self.hop_caps(B)), live)
+        self.last_rounds = rounds
+        self._last_exchange = (self.n * np.asarray(self.hop_caps(B)), rounds,
+                               live)
         self._exchange_recorded = False
         return n_id, n_mask, num, blocks
 
     def exchange_stats(self):
         """``(slots, live_slots)`` of the most recent ``sample``'s request
         exchanges, summed over hops and ranks: slots shipped to the owners
-        (each comes back carrying ``k`` draws) and those that held a
-        request; None before any call.  Feeds ``dist_exchange_slots_total``
-        / ``dist_exchange_live_slots_total{layer="sampler"}`` once a call,
-        at query time like :meth:`overflow_stats`."""
+        (rounds x ranks x bucket; each comes back carrying ``k`` draws) and
+        those that held a request; None before any call.  Feeds
+        ``dist_exchange_slots_total`` / ``dist_exchange_live_slots_total``
+        / ``dist_exchange_rounds_total{layer="sampler"}`` once a call, at
+        query time like :meth:`overflow_stats`."""
         return record_exchange(self, "sampler")
 
     def overflow_stats(self):

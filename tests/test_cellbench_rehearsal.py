@@ -108,8 +108,12 @@ def test_host_cell_counts_its_exchange_and_sees_it_left_out():
     assert line["sound"] is True and line["alone"] is False, line
     f = line["facts"]
     assert f["exchange_drops"] == 0
+    # slots are what was shipped: buckets of an owner's share, so more
+    # than one in ``ranks`` is live (the parent's whole-frontier buckets
+    # held ``live <= slots // 4``)
     assert (sum(f["exchange_live_hops"]) + f["exchange_live_rows"]
-            == f["exchange_live_slots"] <= f["exchange_slots"] // 4)
+            == f["exchange_live_slots"] <= f["exchange_slots"])
+    assert f["exchange_live_slots"] > f["exchange_slots"] // 4
     # 3 checked steps, 4 ranks, fanout [15, 10, 5], 16-wide bfloat16 rows
     asked = sum(n * (4 + 4 * k) for n, k in zip(f["exchange_live_hops"],
                                                 (15, 10, 5)))

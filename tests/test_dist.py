@@ -145,7 +145,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from quiver_tpu import telemetry  # noqa: E402
-from quiver_tpu.dist.exchange import TILE, shard_len  # noqa: E402
+from quiver_tpu.dist.exchange import TILE, bucket_len, shard_len  # noqa: E402
 from quiver_tpu.dist.feature import lookup_program  # noqa: E402
 
 EVEN = [0, 32, 64, 96, 128, 160, 192, 224, 256]
@@ -239,7 +239,7 @@ def test_lookup_program_holds_no_node_length_constant(mesh, rng, form):
                        sharding=sh())
                   for k in ("g2l", "g2h", "rep_mask", "rep_rank",
                             "owned_counts")}
-    text = lookup_program(mesh, "data", B, form == "ranges").lower(
+    text = lookup_program(mesh, "data", None, form == "ranges").lower(
         S((NHOSTS, n // NHOSTS + 1, d), jnp.float32,
           sharding=sh("data", None, None)), tables,
         S((NHOSTS, B), jnp.int32, sharding=sh("data", None)),
@@ -267,7 +267,7 @@ def test_global2host_form_keeps_its_answers_with_maps_as_arguments(mesh,
 
 
 def test_exchange_counters_count_slots_and_live_slots(mesh, rng):
-    n, d, B = 256, 4, 32
+    n, d, B = 256, 4, 2048
     full = rng.normal(size=(n, d)).astype(np.float32)
     df = DistFeature.from_row_ranges(full, mesh, EVEN)
     assert df.exchange_stats() is None
@@ -275,16 +275,158 @@ def test_exchange_counters_count_slots_and_live_slots(mesh, rng):
     def counted(name):
         return telemetry.counter(name, layer="feature").value
 
-    before = (counted("dist_exchange_slots_total"),
-              counted("dist_exchange_live_slots_total"))
+    names = ("dist_exchange_slots_total", "dist_exchange_live_slots_total",
+             "dist_exchange_rounds_total")
+    before = [counted(name) for name in names]
     valid = rng.random((NHOSTS, B)) < 0.25
     df.lookup(rng.integers(0, n, (NHOSTS, B)).astype(np.int32), valid)
     slots, live = df.exchange_stats()
-    assert (slots, live) == (NHOSTS * NHOSTS * B, int(valid.sum()))
+    # slots are what was shipped: a quarter of the batch live and spread
+    # over the owners goes in ONE round of buckets sized for an owner's
+    # share, where the parent shipped buckets as long as the batch
+    bucket = bucket_len(B, NHOSTS)
+    assert bucket == 384 and (np.asarray(df.last_rounds) == 1).all()
+    assert (slots, live) == (NHOSTS * NHOSTS * bucket, int(valid.sum()))
     assert df.exchange_stats() == (slots, live)     # counted once a call
-    assert counted("dist_exchange_slots_total") - before[0] == slots
-    assert counted("dist_exchange_live_slots_total") - before[1] == live
+    assert [counted(name) - b for name, b in zip(names, before)] == [
+        slots, live, 1]
     assert int(df.overflow_stats().sum()) == 0
+
+
+# ---------------------------------------------------------------------------
+# The exact exchange ships buckets sized for an owner's share, in as many
+# rounds as its own counts ask for (PERF.md, PR 35)
+
+
+def _stored(full, dtype):
+    return np.asarray(jnp.asarray(full).astype(dtype))
+
+
+def _sharded(form, mesh, full, dtype, g2h=None, request_cap=None):
+    """The table in either partition form, stored as ``dtype``."""
+    if form == "ranges":
+        starts = np.linspace(0, len(full), NHOSTS + 1).astype(np.int64)
+        return DistFeature.from_row_ranges(
+            full, mesh, starts, dtype=jnp.dtype(dtype),
+            request_cap=request_cap)
+    if g2h is None:
+        g2h = (np.arange(len(full)) * NHOSTS // len(full)).astype(np.int32)
+    info = PartitionInfo(hosts=NHOSTS, global2host=g2h,
+                         replicate=np.arange(5, len(full), 97))
+    return DistFeature.from_global_feature(
+        _stored(full, dtype), mesh, info, request_cap=request_cap)
+
+
+def test_a_bucket_is_an_owners_share_or_the_whole_frontier():
+    # the cell's four exchanges: a quarter and eight sigmas of room
+    assert [bucket_len(F, 4) for F in (1024, 16384, 180224, 1081344)] == [
+        384, 4608, 46848, 274560]
+    for F, n in ((1024, 4), (16384, 4), (1081344, 4), (2048, 8), (10**7, 64)):
+        b = bucket_len(F, n)
+        assert b % 128 == 0 and F / n < b < 1.6 * F / n + 128
+    # a frontier too small to divide, or a single owner: one round of F
+    assert [bucket_len(F, n) for F, n in ((16, 8), (32, 8), (6, 8), (256, 2),
+                                          (1024, 1), (10**6, 1))] == [
+        16, 32, 6, 256, 1024, 10**6]
+
+
+@pytest.mark.parametrize("form", ["ranges", "global2host"])
+def test_every_id_on_one_owner_is_n_rounds_and_every_row(mesh, rng, form):
+    # a batch whose owner's share (4,096) dwarfs the slack, so that one
+    # owner's whole batch is exactly ``n`` buckets
+    n, d, B = 512, 4, 32768
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = _sharded(form, mesh, full, "float32",
+                  g2h=np.zeros(n, np.int32))     # host 0 owns every row
+    ids = rng.integers(0, n // NHOSTS, (NHOSTS, B)).astype(np.int32)
+    if form == "global2host":       # but the replicated, served at home
+        ids[np.isin(ids, df.info.rep_ids)] += 1
+    out = np.asarray(df.lookup(ids))
+    assert np.array_equal(out.view(np.uint8), full[ids].view(np.uint8))
+    rounds = np.asarray(df.last_rounds)
+    assert (rounds == NHOSTS).all() and -(-B // bucket_len(B, NHOSTS)) == NHOSTS
+    assert (df.overflow_stats() == 0).all()
+    assert df.exchange_stats() == (
+        NHOSTS * NHOSTS * NHOSTS * bucket_len(B, NHOSTS), NHOSTS * B)
+
+
+def test_ids_spread_evenly_take_one_round(mesh, rng):
+    n, d, B = 256, 4, 2048
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = DistFeature.from_row_ranges(full, mesh, EVEN)
+    # every rank asks every owner for B / n rows: all live, one round
+    ids = rng.permuted(np.tile(np.arange(n, dtype=np.int32),
+                               (NHOSTS, B // n)), axis=1)
+    out = np.asarray(df.lookup(ids))
+    assert np.array_equal(out, full[ids])
+    assert (np.asarray(df.last_rounds) == 1).all()
+    assert df.exchange_stats() == (
+        NHOSTS * NHOSTS * bucket_len(B, NHOSTS), NHOSTS * B)
+    # one id more than a bucket holds on one owner: a second round, on
+    # every rank, and still every row
+    bucket = bucket_len(B, NHOSTS)
+    ids[3, :bucket + 1] = 7
+    out = np.asarray(df.lookup(ids))
+    assert np.array_equal(out, full[ids])
+    assert (np.asarray(df.last_rounds) == 2).all()
+    assert int(df.overflow_stats().sum()) == 0
+
+
+@pytest.mark.parametrize("form", ["ranges", "global2host"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_lookup_in_rounds_equals_the_one_wide_round_bit_for_bit(mesh, rng,
+                                                               form, dtype):
+    """The default (buckets of an owner's share, in rounds) against a
+    caller's ``request_cap=B`` (ONE round of buckets as long as the batch,
+    which nothing overflows either): the same rows to the last bit, on a
+    batch skewed enough to take several rounds."""
+    n, d, B = 512, 8, 2048
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    ids = rng.integers(0, n, (NHOSTS, B)).astype(np.int32)
+    ids[:, ::2] = rng.integers(0, n // NHOSTS, (NHOSTS, B // 2))
+    valid = rng.random((NHOSTS, B)) < 0.8
+    rounds_df = _sharded(form, mesh, full, dtype)
+    wide_df = _sharded(form, mesh, full, dtype, request_cap=B)
+    got = np.asarray(rounds_df.lookup(ids, valid))
+    wide = np.asarray(wide_df.lookup(ids, valid))
+    assert got.dtype == _stored(full, dtype).dtype == wide.dtype
+    assert np.array_equal(got.view(np.uint8), wide.view(np.uint8))
+    assert np.array_equal(got.view(np.uint8), np.where(
+        valid[..., None], _stored(full, dtype)[ids], 0).view(np.uint8))
+    assert (np.asarray(rounds_df.last_rounds) > 1).all()
+    assert (np.asarray(wide_df.last_rounds) == 1).all()
+    assert wide_df.exchange_stats() == (NHOSTS * NHOSTS * B, int(valid.sum()))
+    assert int(rounds_df.overflow_stats().sum()
+               + wide_df.overflow_stats().sum()) == 0
+
+
+@pytest.mark.parametrize("form", ["ranges", "global2host"])
+def test_a_batch_with_no_valid_id_ships_nothing(mesh, rng, form):
+    n, d, B = 512, 4, 2048
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = _sharded(form, mesh, full, "float32")
+    ids = rng.integers(0, n, (NHOSTS, B)).astype(np.int32)
+    out = np.asarray(df.lookup(ids, np.zeros((NHOSTS, B), bool)))
+    assert out.shape == (NHOSTS, B, d) and not out.any()
+    assert (np.asarray(df.last_rounds) == 0).all()
+    assert df.exchange_stats() == (0, 0)
+    assert (df.overflow_stats() == 0).all()
+    # and the next batch is served as ever
+    assert np.array_equal(np.asarray(df.lookup(ids)), full[ids])
+
+
+def test_a_callers_cap_is_one_round_that_drops_and_counts(mesh, rng):
+    n, d, B, cap = 512, 4, 2048, 128
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    df = _sharded("ranges", mesh, full, "float32", request_cap=cap)
+    ids = rng.integers(0, n // NHOSTS, (NHOSTS, B)).astype(np.int32)
+    out = np.asarray(df.lookup(ids))
+    assert (np.asarray(df.last_rounds) == 1).all()
+    assert (df.overflow_stats() == B - cap).all()
+    # the first ``cap`` requests of a bucket are served, the rest zero
+    assert np.array_equal(out[:, :cap], full[ids[:, :cap]])
+    assert not out[:, cap:].any()
+    assert df.exchange_stats() == (NHOSTS * NHOSTS * cap, NHOSTS * cap)
 
 
 def test_row_ranges_with_the_overlay_kept(mesh, rng):

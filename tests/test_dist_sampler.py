@@ -182,6 +182,8 @@ for p in (ROOT, os.path.join(ROOT, "cellbench")):
     if p not in sys.path:
         sys.path.append(p)
 
+from quiver_tpu.dist.exchange import bucket_len  # noqa: E402
+
 RANKS = 4
 CELL = "papers100m-sage-host.train-dist"
 
@@ -235,13 +237,20 @@ def test_exchange_drops_nothing_at_default_caps_and_counts_its_slots(
     assert prog.exchange_drops() == 0
     assert s.overflow_stats().shape == (RANKS, len(cfg["fanout"]))
     caps = s.hop_caps(cfg["batch"])
-    assert caps == [16, 16 * 16, 16 * 16 * 11]      # the whole frontier
+    # an owner's share of each hop's frontier (16, 256, 2,816 slots) and
+    # its room, where the parent shipped the whole frontier; 16 slots are
+    # too few to divide
+    assert caps == [bucket_len(F, RANKS) for F in (16, 256, 2816)] == [
+        16, 128, 1024]
+    rounds = np.asarray(s.last_rounds)
+    assert rounds.shape == (RANKS, len(caps)) and (rounds == 1).all()
     slots, live = s.exchange_stats()
-    assert slots == RANKS * RANKS * sum(caps)
+    assert slots == RANKS * RANKS * sum(caps)       # what was shipped
     # every live target of every hop was asked of its owner, once
-    assert live == sum(int(n_mask[:, :F].sum()) for F in caps)
+    assert live == sum(int(n_mask[:, :F].sum()) for F in (16, 256, 2816))
     f_slots, f_live = prog.feature.exchange_stats()
-    assert f_slots == RANKS * RANKS * n_id.shape[1]
+    assert (np.asarray(prog.feature.last_rounds) == 1).all()
+    assert f_slots == RANKS * RANKS * bucket_len(n_id.shape[1], RANKS)
     assert f_live == int(n_mask.sum())
     assert prog.exchange_slots() == (slots + f_slots, live + f_live)
 
@@ -347,3 +356,86 @@ def test_sampler_shards_are_put_from_slices_of_the_host_csr(small_graph):
         assert np.array_equal(ip[p, :len(lips[p])], lips[p])
         assert (ip[p, len(lips[p]):] == lips[p][-1]).all()
         assert np.array_equal(ix[p, :len(lids[p])], lids[p])
+
+
+# ---------------------------------------------------------------------------
+# The exact exchange in rounds under the hops (PERF.md, PR 35)
+
+
+def _four_rank_sampler(graph, sizes, **kw):
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:RANKS]), ("data",))
+    return DistGraphSampler(graph, mesh, sizes=sizes, **kw)
+
+
+def _assert_draws(graph, s, seeds, n_id, n_mask, blocks, sizes):
+    """Every target of every hop draws ``min(degree, k)`` neighbours of
+    its own, masks are prefixes, nothing was dropped."""
+    n_id, n_mask = np.asarray(n_id), np.asarray(n_mask)
+    deg = np.asarray(graph.degree)
+    F = seeds.shape[1]
+    for blk, k in zip(blocks[::-1], sizes):     # innermost first
+        local, m = np.asarray(blk.nbr_local), np.asarray(blk.mask)
+        targets, live = n_id[:, :F], n_mask[:, :F]
+        want = np.where(live, np.minimum(deg[targets], k), 0)
+        assert np.array_equal(m.sum(axis=2), want)
+        assert (m[..., :-1] >= m[..., 1:]).all()       # prefixes
+        for r in range(RANKS):
+            for t in np.flatnonzero(live[r])[::max(F // 256, 1)]:
+                row = graph.indices[graph.indptr[targets[r, t]]:
+                                    graph.indptr[targets[r, t] + 1]]
+                assert np.isin(n_id[r, local[r, t][m[r, t]]], row).all()
+        F *= 1 + k
+    assert (s.overflow_stats() == 0).all()
+
+
+@pytest.mark.parametrize("sample_rng", ["key", "hash"])
+def test_every_seed_on_one_owner_is_n_rounds_and_every_draw(small_graph,
+                                                            sample_rng):
+    sizes, B = [3, 2], 4096
+    s = _four_rank_sampler(small_graph, sizes, sample_rng=sample_rng)
+    lo, hi = (int(v) for v in s.row_starts_host[2:4])   # rank 2's rows
+    seeds = np.random.default_rng(5).integers(lo, hi, (RANKS, B))
+    n_id, n_mask, _, blocks = s.sample(seeds, key=9)
+    rounds = np.asarray(s.last_rounds)
+    # hop 1: 4,096 requests a rank into rank 2's bucket of 1,280
+    assert s.hop_caps(B) == [1280, 4608]
+    assert (rounds[:, 0] == RANKS).all() and (rounds[0] == rounds).all()
+    _assert_draws(small_graph, s, seeds, n_id, n_mask, blocks, sizes)
+    slots, live = s.exchange_stats()
+    assert slots == RANKS * RANKS * int((rounds[0] * [1280, 4608]).sum())
+    assert live == int(np.asarray(n_mask)[:, :B * 4].sum()) + RANKS * B
+
+
+def test_seeds_spread_evenly_take_one_round(small_graph):
+    sizes, B = [3, 2], 4096
+    s = _four_rank_sampler(small_graph, sizes)
+    starts, rng = s.row_starts_host, np.random.default_rng(6)
+    # a quarter of every rank's seeds in each owner's rows: 1,024 into
+    # buckets of 1,280.  Hop 2's frontier goes where the neighbours live
+    # (ranges balanced by edges are not balanced by rows): its rounds are
+    # the counts', and its slots what those rounds shipped
+    seeds = rng.permuted(np.concatenate(
+        [rng.integers(starts[p], starts[p + 1], (RANKS, B // RANKS))
+         for p in range(RANKS)], axis=1), axis=1)
+    n_id, n_mask, _, blocks = s.sample(seeds, key=10)
+    rounds = np.asarray(s.last_rounds)
+    assert (rounds[:, 0] == 1).all() and (rounds[0] == rounds).all()
+    _assert_draws(small_graph, s, seeds, n_id, n_mask, blocks, sizes)
+    assert s.exchange_stats()[0] == RANKS * RANKS * (
+        1280 + int(rounds[0, 1]) * 4608)
+
+
+def test_a_fraction_under_one_is_one_round_that_drops_and_counts(small_graph):
+    """A caller's cap stays ONE round: at a fraction under 1.0 the same
+    skewed seeds overflow and are counted, where the default drops none."""
+    sizes, B = [3], 4096
+    s = _four_rank_sampler(small_graph, sizes, request_cap_frac=0.5)
+    lo, hi = (int(v) for v in s.row_starts_host[:2])
+    seeds = np.random.default_rng(7).integers(lo, hi, (RANKS, B))
+    s.sample(seeds, key=11)
+    cap = s.hop_caps(B)[0]
+    assert cap == 1024 and (np.asarray(s.last_rounds) == 1).all()
+    assert (s.overflow_stats()[:, 0] == B - cap).all()
+    assert s.exchange_stats() == (RANKS * RANKS * cap, RANKS * cap)
