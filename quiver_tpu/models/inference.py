@@ -163,6 +163,7 @@ def full_graph_inference(model, params=None, x=None, indptr=None,
                 den = _seg_add(den, a, rows)
             out = num / den[..., None]                 # [N, H, F]
             x = out.reshape(n, heads * f) if not last else out.mean(axis=1)
+            x = x + jnp.asarray(layer["bias"])
             if not last:
                 x = jax.nn.elu(x)
         return x

@@ -37,7 +37,8 @@ import jax.numpy as jnp
 from ..hetero import HeteroLayerBlock, HeteroSampledBatch
 from ..sampler import LayerBlock
 from ..telemetry.device_scopes import MODEL_ATTENTION, MODEL_PROJECT
-from .layers import sources
+from .gat import lsc_frame
+from .layers import MaskedBatchNorm, _exact, _weighted_sum, sources
 
 __all__ = ["RGAT", "RGNN", "RelGATConv", "MaskedBatchNorm",
            "grouped_project", "rgnn_apply_fn"]
@@ -118,43 +119,6 @@ def grouped_project(x: jax.Array, group: jax.Array, w: jax.Array,
     # (a dead row's own slot weighs 0, so its gradient is 0 as it is read)
     slot = perm // k * k8 + perm % k
     return _regroup(ys, place, slot, None)
-
-
-def _exact(a: jax.Array, b: jax.Array) -> jax.Array:
-    """``a @ b`` in float32 proper: for the small products that stand in
-    for an elementwise sum (a per-head reduction over lanes), which the
-    published model computes in float32."""
-    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
-
-
-def _widen(alpha: jax.Array, head_of: jax.Array) -> jax.Array:
-    """``[..., H] -> [..., H * C]``: each head's weight over its own lanes
-    (``head_of [H * C, H]`` is 0/1)."""
-    return sum(alpha[..., i, None] * head_of[:, i]
-               for i in range(head_of.shape[1]))
-
-
-@jax.custom_vjp
-def _weighted_sum(alpha, s, head_of):
-    """``out[t] = sum_k alpha[t, k, head of lane] * s[t, k, lane]`` with a
-    backward pass of its own: ONE pass over ``s`` for the weights'
-    gradient (the transposed sum is a pass per head)."""
-    return (_widen(alpha, head_of) * s).sum(axis=1)
-
-
-def _weighted_sum_fwd(alpha, s, head_of):
-    return _weighted_sum(alpha, s, head_of), (alpha, s, head_of)
-
-
-def _weighted_sum_bwd(res, g):
-    alpha, s, head_of = res
-    g = g[:, None, :]
-    d_alpha = _exact((s * g).reshape(-1, s.shape[-1]), head_of)
-    return (d_alpha.reshape(alpha.shape), _widen(alpha, head_of) * g,
-            jnp.zeros_like(head_of))
-
-
-_weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
 
 
 class RelGATConv(nn.Module):
@@ -241,40 +205,6 @@ class RelGATConv(nn.Module):
             return out + (present[:, None] * bias).sum(axis=0)
 
 
-class MaskedBatchNorm(nn.Module):
-    """PyTorch's ``BatchNorm1d`` over the VALID rows only: batch mean and
-    biased variance when training, running averages (``batch_stats``:
-    ``mean``, ``var``; the variance updated unbiased, as PyTorch does)
-    otherwise."""
-
-    momentum: float = 0.1
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x: jax.Array, valid: jax.Array,
-                 train: bool) -> jax.Array:
-        f = x.shape[-1]
-        scale = self.param("scale", nn.initializers.ones, (f,))
-        bias = self.param("bias", nn.initializers.zeros, (f,))
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros((f,), jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones((f,), jnp.float32))
-        if train:
-            m = valid.astype(x.dtype)[:, None]
-            n = jnp.maximum(m.sum(), 1.0)
-            mean = (x * m).sum(axis=0) / n
-            var = (jnp.square(x - mean) * m).sum(axis=0) / n
-            if not self.is_initializing():
-                mom = self.momentum
-                ra_mean.value = (1 - mom) * ra_mean.value + mom * mean
-                ra_var.value = ((1 - mom) * ra_var.value
-                                + mom * var * n / jnp.maximum(n - 1.0, 1.0))
-        else:
-            mean, var = ra_mean.value, ra_var.value
-        return (x - mean) * jax.lax.rsqrt(var + self.eps) * scale + bias
-
-
 class RGNN(nn.Module):
     """The published R-GAT over homogeneous blocks (module docstring).
 
@@ -321,30 +251,19 @@ class RGNN(nn.Module):
         assert len(blocks) == self.num_layers, (
             f"{len(blocks)} blocks for {self.num_layers} layers")
 
-        def dense(features, name):
-            return nn.Dense(features, dtype=self.dtype, name=name)
+        def conv(i, x, blk):
+            rel = self.edge_relations(n_id[:x.shape[0]], blk)
+            return RelGATConv(self.hidden // self.heads, self.heads,
+                              self.num_relations, dtype=self.dtype,
+                              name=f"conv{i}")(x, blk, rel)
 
-        for i, blk in enumerate(blocks):
-            t, p = blk.mask.shape[0], x.shape[0]
-            rel = self.edge_relations(n_id[:p], blk)
-            out = RelGATConv(self.hidden // self.heads, self.heads,
-                             self.num_relations, dtype=self.dtype,
-                             name=f"conv{i}")(x, blk, rel)
-            with jax.named_scope(MODEL_PROJECT):
-                out = out + dense(self.hidden, f"skip{i}")(x[:t]).astype(
-                    out.dtype)
-            x = MaskedBatchNorm(name=f"norm{i}")(out, n_mask[:t], train)
-            x = nn.Dropout(self.dropout, deterministic=not train)(nn.elu(x))
-        valid = n_mask[:x.shape[0]]
-        x = dense(self.hidden, "mlp_lin0")(x).astype(jnp.float32)
-        x = nn.relu(MaskedBatchNorm(name="mlp_norm")(x, valid, train))
-        x = nn.Dropout(self.dropout, deterministic=not train)(x)
-        return dense(self.out_dim, "mlp_lin1")(x).astype(jnp.float32)
+        return lsc_frame(self, conv, x, blocks, n_mask, train)
 
 
-def rgnn_apply_fn(model: RGNN):
+def rgnn_apply_fn(model: nn.Module):
     """The ``apply_fn`` the fused step, the scan epoch, the fused eval and
-    ``make_train_step`` take for ``model``: rows stored narrower than
+    ``make_train_step`` take for ``model`` (an :class:`RGNN`, or the
+    untyped ``models.GNN``, which is called alike): rows stored narrower than
     float32 are widened, parameters are ``{"params": ...}``, the model
     state ``{"batch_stats": ...}`` (``model.init`` returns both)."""
 

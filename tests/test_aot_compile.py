@@ -513,26 +513,21 @@ MAG_EDGES, MAG_DIM, MAG_CLASSES = 54_023_314, 768, 153
 MAG_RELATION_OF = ((0, 2, -1), (1, -1, 3), (-1, 4, -1))
 
 
-def _typed_fused_step(one_chip):
-    """The published R-GAT (``models.RGNN``) through the fused step, at
-    the MAG240M share's tables and published widths (768 float16 rows, 2 x
-    1024, 4 heads, 5 relations, fanout [25, 15]) with 64 seeds where the
-    cell has 1,024 (a quarter of a minute to compile where the cell's
-    program takes three and a half), lowered for the described chip."""
-    import numpy as np
+def _stateful_fused_step(one_chip, model, nodes, edges, B):
+    """A model that carries state and asks for the frontier (``RGNN``,
+    ``GNN``: both through ``rgnn_apply_fn``) in the fused step over a
+    MAG240M share's tables (768-d float16 rows, fanout [25, 15]), lowered
+    for the described chip."""
     import optax
 
-    from quiver_tpu.models import RGNN, rgnn_apply_fn
+    from quiver_tpu.models import rgnn_apply_fn
     from quiver_tpu.parallel import TrainState
     from quiver_tpu.pipeline import _fused_train_impl
     from quiver_tpu.sampler import run_pipeline
     import types
 
-    offsets = tuple(int(v) for v in np.cumsum((0,) + MAG_SHARE))
-    nodes, B, sizes = offsets[-1], 64, (25, 15)
-    model = RGNN(hidden=1024, out_dim=MAG_CLASSES, num_relations=5,
-                 type_offsets=offsets, relation_of=MAG_RELATION_OF)
-    indptr, indices = _graph(None, nodes, MAG_EDGES)
+    sizes = (25, 15)
+    indptr, indices = _graph(None, nodes, edges)
     n_id, n_mask, _, blocks, _, _ = jax.eval_shape(
         lambda ip, ix, s, k: run_pipeline(
             "none", ip, ix, s, k, sizes, (None,) * 2,
@@ -548,12 +543,28 @@ def _typed_fused_step(one_chip):
     feature = types.SimpleNamespace(cache_count=nodes, node_count=nodes)
     impl = _fused_train_impl(_tpu_sampler(sizes), feature,
                              rgnn_apply_fn(model), None)
-    tables = (*_graph(one_chip, nodes, MAG_EDGES),
+    tables = (*_graph(one_chip, nodes, edges),
               (_s(one_chip, (nodes, MAG_DIM), jnp.float16), None))
     return jax.jit(impl, donate_argnums=(1,)).lower(
         tables, _on(one_chip, state), _s(one_chip, (B,)),
         _s(one_chip, (B,)), _s(one_chip, (B,), jnp.bool_),
         _key(one_chip))
+
+
+def _typed_fused_step(one_chip):
+    """The published R-GAT (``models.RGNN``) through the fused step, at
+    the MAG240M share's tables and published widths (768 float16 rows, 2 x
+    1024, 4 heads, 5 relations, fanout [25, 15]) with 64 seeds where the
+    cell has 1,024 (a quarter of a minute to compile where the cell's
+    program takes three and a half), lowered for the described chip."""
+    import numpy as np
+
+    from quiver_tpu.models import RGNN
+
+    offsets = tuple(int(v) for v in np.cumsum((0,) + MAG_SHARE))
+    model = RGNN(hidden=1024, out_dim=MAG_CLASSES, num_relations=5,
+                 type_offsets=offsets, relation_of=MAG_RELATION_OF)
+    return _stateful_fused_step(one_chip, model, offsets[-1], MAG_EDGES, 64)
 
 
 # sha256 of ``_typed_fused_step(...).as_text()``.  It stood at
@@ -593,6 +604,74 @@ def test_typed_fused_step_groups_its_projections(one_chip):
     assert sum("transpose(" in op for op in kernels.values()) == 3, kernels
     # the float16 table stays float16, row-major, and is gathered as it is
     assert "f16[3815006,768]{1,0" in c.as_text()
+
+
+GAT_PAPERS, GAT_EDGES = 3_804_740, 81_109_308   # mag240m-gat: a 32nd
+
+
+def _gat_fused_step(one_chip):
+    """The published GAT (``models.GNN``) through the fused step at
+    ``mag240m-gat.train-fused-stateful``'s REAL shapes: the citation
+    share's tables, 768 float16 rows, 2 x 1024, 4 heads, fanout [25, 15],
+    1,024 seeds (a second to lower, under a minute to compile: one dense
+    product where the typed step sorts and groups)."""
+    from quiver_tpu.models import GNN
+
+    return _stateful_fused_step(one_chip, GNN(hidden=1024,
+                                              out_dim=MAG_CLASSES),
+                                GAT_PAPERS, GAT_EDGES, BATCH)
+
+
+# sha256 of ``_gat_fused_step(...).as_text()``, recorded on PR 36's tree,
+# which adds the program.  A PR that MEANS to change it (ROADMAP S12: the
+# attention's passes) records the new text's hash here
+GAT_STEP_RECORDED = "4bd8cabc8b89275f40288e6b307c28486c028264a1643a60c2ee4806fa641b14"
+
+
+def test_fused_gat_step_lowers_as_recorded(one_chip):
+    """``mag240m-gat.train-fused-stateful``'s program, to the letter: the
+    same compile-cache entry, the same numbers for the cell."""
+    import hashlib
+
+    text = _gat_fused_step(one_chip).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == GAT_STEP_RECORDED
+
+
+def test_fused_gat_step_compiles_at_the_cells_real_shapes(one_chip):
+    """The chip's compiler takes the cell's program at its real shapes:
+    it fits beside the tables; the first layer's projection is ONE
+    ``[425984,768] x [768,1024]`` product whose ``[26624,16,1024]`` view
+    (a target's 15 neighbours and its self-loop, slot by slot) is free:
+    nothing copies, concatenates, pads or re-lays the PROJECTION, forward
+    or backward (what is laid out by slot is the narrower float16 input);
+    and both parts of the convolution carry their scope in both passes."""
+    from quiver_tpu.telemetry.device_scopes import parse_hlo_scopes
+
+    c = _gat_fused_step(one_chip).compile()
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes > 6_100_000_000      # the tables
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes) < 15 << 30
+    text = c.as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert "f16[3804740,768]{1,0" in text       # gathered as it is stored
+    projection = re.compile(
+        r" = (?:f32|bf16)\[(?:425984,1024|26624,16,1024|26624,16,4,256)\]")
+    made_by = [(re.search(r"\} ([\w\-]+)\(", line).group(1),
+                (re.search(r'op_name="([^"]*)"', line) or [None, ""])[1]
+                .rsplit("/", 1)[-1])
+               for line in entry.splitlines() if projection.search(line)]
+    assert ("fusion", "dot_general") in made_by, made_by
+    moved = [m for m in made_by if m[0] in ("copy", "concatenate", "pad",
+                                            "transpose", "gather")
+             or m[1] in ("concatenate", "pad", "copy", "transpose")]
+    assert not moved, moved
+    _, table = parse_hlo_scopes(text)
+    for scope in ("qt.model.project", "qt.model.attention"):
+        for backward in (False, True):
+            assert [op for op in table.values() if "GNN/conv0/" + scope
+                    in op and ("transpose(" in op) == backward], (
+                        scope, backward)
 
 
 @pytest.mark.parametrize("bucket", [8, 128, 2048])

@@ -16,7 +16,9 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "cellbench")
-TYPED = "mag240m-rgat.train-fused-typed"
+# the cells whose model carries state and has a bfloat16 path of its own
+STATEFUL = ["mag240m-rgat.train-fused-typed",
+            "mag240m-gat.train-fused-stateful"]
 
 
 def cells():
@@ -55,15 +57,17 @@ print(json.dumps({{"correct": correct, "compared": compared,
 """
 
 
-def test_typed_cells_bf16_control_is_not_correct():
-    """The program's own lower-precision path (``RGNN(dtype=bfloat16)``:
-    the products' results in bfloat16), through ``run.run_cell(...,
-    control=True)``, has to fail the cell's limits.  In a process of its
-    own: ``run_cell`` points JAX's compile cache at the benchmark's."""
+@pytest.mark.parametrize("cell", STATEFUL)
+def test_typed_cells_bf16_control_is_not_correct(cell):
+    """The program's own lower-precision path (``RGNN(dtype=bfloat16)``,
+    ``GNN(dtype=bfloat16)``: the products' results in bfloat16), through
+    ``run.run_cell(..., control=True)``, has to fail the cell's limits.  In
+    a process of its own: ``run_cell`` points JAX's compile cache at the
+    benchmark's."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable, "-c",
-         CONTROL.format(root=ROOT, bench=BENCH, cell=TYPED)],
+         CONTROL.format(root=ROOT, bench=BENCH, cell=cell)],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
     line = json.loads(p.stdout.strip().splitlines()[-1])

@@ -91,6 +91,43 @@ def test_train_step_table_names_every_layer(fresh, toy):
     assert all(instruction_key(k + " fusion(%x)") == k for k in table)
 
 
+def test_gat_conv_carries_both_part_scopes_in_both_passes(fresh, toy):
+    """The published GAT (``models.GNN``) through the fused step: the
+    convolution's product(s) and the frame's ``skip`` sit under
+    ``qt.model.project``, scores, softmax and weighted sum under
+    ``qt.model.attention``, each nested under ``qt.model`` and in both
+    passes: what the ``rel_*`` readers and ``attention_roofline.train``
+    of the benchmark key on."""
+    from quiver_tpu.models import GNN, rgnn_apply_fn
+    from quiver_tpu.telemetry.device_scopes import (MODEL_ATTENTION,
+                                                    MODEL_PROJECT)
+
+    sampler, feature, _, _, seeds, labels = toy
+    model = GNN(hidden=16, out_dim=4, num_layers=3, heads=2)
+    b0 = sampler.sample(np.asarray(seeds))
+    v = model.init(jax.random.PRNGKey(0), feature[b0.n_id], b0.layers,
+                   b0.n_id, b0.n_id_mask)
+    tx = optax.adam(1e-2)
+    step = make_fused_train_step(sampler, feature, rgnn_apply_fn(model), tx)
+    state = TrainState.create({"params": v["params"]}, tx,
+                              {"batch_stats": v["batch_stats"]})
+    state, loss = step(state, seeds, labels, jnp.ones((B,), bool),
+                       jax.random.PRNGKey(1))
+    assert np.isfinite(float(loss))
+    names = set(telemetry.device_scopes()["jit_qt_fused_train_step"].values())
+    for part in (MODEL_PROJECT, MODEL_ATTENTION):
+        for backward in (False, True):
+            found = [n for n in names if f"GNN/conv0/{part}/" in n
+                     and ("transpose(" in n) == backward]
+            assert found, (part, backward)
+            # nested: the layer's name comes first, the part's last
+            assert all(n.index(MODEL + ")") < n.index(part) for n in found)
+    assert any(f"GNN/{MODEL_PROJECT}/skip0" in n for n in names)
+    assert any("lin/dot_general" in n and MODEL_PROJECT in n for n in names)
+    assert not any(MODEL_ATTENTION in n and "lin/dot_general" in n
+                   for n in names)
+
+
 def test_eval_and_scan_programs_carry_their_own_names(fresh, toy):
     sampler, feature, apply_fn, params, seeds, labels = toy
     ev = make_fused_eval_fn(sampler, feature, apply_fn)

@@ -25,6 +25,9 @@ EXAMPLES = {
         "--papers", "800", "--authors", "400", "--institutions", "50",
         "--steps", "3", "--batch-size", "16",
     ],
+    "examples/mag240m_gat.py": [
+        "--papers", "1500", "--steps", "6", "--batch-size", "32",
+    ],
     "examples/preprocess_partition.py": [
         "--nodes", "2000", "--edges", "20000", "--hosts", "4",
         "--out", "/tmp/qt_part_test",
