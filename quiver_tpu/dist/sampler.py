@@ -10,6 +10,12 @@ dense), seeds are routed to their owner through the exchange
 sized for an owner's share, as many rounds of two all-to-alls as the
 counts ask for), sampled neighbor blocks ride back on the second.
 
+The blocks are built positionally, as ``dedup="none"`` builds them on one
+chip, and say so (``LayerBlock.layout = POSITIONAL``, with a leading shard
+axis on every leaf): a model handed a rank's blocks reads a target's
+sources as a slice of its frontier rows (``models.layers.sources``), no
+gather forward and no scatter-add backward.
+
 papers100M at int32 is ~6.5 GB of indices — over a v5e-8 that is <1 GB per
 chip, leaving HBM for features.  Single-chip sampling of a sharded graph is
 the degenerate n=1 case (no collectives emitted).
@@ -29,7 +35,7 @@ from ..resilience.retry import Backoff, retry_call
 from ..utils.topology import CSRTopo
 from ..ops.sample import sample_neighbors
 from ..parallel.train import replicate
-from ..sampler import LayerBlock, SampledBatch
+from ..sampler import POSITIONAL, LayerBlock, SampledBatch
 from ..telemetry.device_scopes import (exchange as exchange_scope,
                                        register_program, sampler_hop,
                                        SAMPLER)
@@ -153,7 +159,18 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
     ``jit_qt_dist_sample``: ``(indptr_sh, indices_sh, row_starts, seeds
     [n, B], valid [n, B], key) -> (n_id, n_mask, num, blocks, dropped [n,
     L], live [n, L], rounds [n, L])``.  It holds no table: all three arrive
-    as arguments."""
+    as arguments.
+
+    Every block is ``layout=POSITIONAL`` (:class:`~quiver_tpu.sampler.
+    LayerBlock`): hop ``l`` grows a rank's frontier of ``F`` slots to
+    ``F (1 + k)`` by appending target ``b``'s ``k`` draws at ``F + b*k ..
+    F + b*k + k - 1``, and ``nbr_local`` is that position wherever ``mask``.
+    The marker is static structure (no leaf): it crosses this ``jit``, a
+    caller's ``tree_map(lambda l: l[0], blocks)`` and
+    ``make_train_step(mesh=)``'s ``vmap`` as the Python value it is.  A
+    caller that trims, reorders or deduplicates a frontier before the model
+    hands it ``block._replace(layout=None)``; ``sources`` otherwise raises
+    on the length at trace time."""
     from ..utils.rng import default_impl
 
     sizes = tuple(sizes)
@@ -178,12 +195,20 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
             nbrs, mask, counts = hop(ip, ix, row_starts, frontier, fmask, sub)
             counted.append(counts)
             with jax.named_scope(sampler_hop(l + 1)):
+                # POSITIONAL holds through the exchange: it unpacks the
+                # owners' answers into [F, k] in REQUEST order whatever the
+                # number of rounds, so target b's draws are row b; a request
+                # a caller's cap dropped, a dead frontier slot and a target
+                # of degree 0 come back mask == False, which the promise
+                # exempts; and the frontier below is the old one with
+                # nbrs.reshape(-1) appended, F (1 + k) long
                 pos = (F + jnp.arange(F, dtype=jnp.int32)[:, None] * k
                        + jnp.arange(k, dtype=jnp.int32)[None, :])
                 blocks.append(LayerBlock(
                     nbr_local=jnp.where(mask, pos, 0),
                     mask=mask,
                     num_targets=fmask.sum().astype(jnp.int32),
+                    layout=POSITIONAL,
                 ))
                 frontier = jnp.concatenate(
                     [frontier, jnp.where(mask, nbrs, 0).reshape(-1)]
@@ -192,11 +217,7 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
         # leading [1] axis on every leaf so out_specs can globalize
         # the per-shard results onto the mesh axis
         blocks_out = tuple(
-            LayerBlock(
-                nbr_local=b.nbr_local[None],
-                mask=b.mask[None],
-                num_targets=b.num_targets[None],
-            )
+            jax.tree_util.tree_map(lambda a: a[None], b)  # keeps ``layout``
             for b in blocks[::-1]  # outermost-first, like SampledBatch
         )
         return (frontier[None], fmask[None],
@@ -208,6 +229,7 @@ def sample_program(mesh: Mesh, axis: str, sizes, request_cap_frac: float,
             nbr_local=P(axis, None, None),
             mask=P(axis, None, None),
             num_targets=P(axis),
+            layout=POSITIONAL,
         )
         for _ in sizes
     )
@@ -336,7 +358,8 @@ class DistGraphSampler:
         """``seed_batches``: [n_shards, B] — one seed batch per device;
         ``key``: int seed (PRNG keys are derived per shard inside).
         Returns per-shard :class:`SampledBatch`-style pytrees stacked on
-        the leading axis.
+        the leading axis; the blocks are ``layout=POSITIONAL`` per rank
+        (:func:`sample_program`).
 
         After each call ``self.last_overflow`` holds a ``[n_shards, L]``
         device array of per-hop counts of frontier entries that overflowed

@@ -67,8 +67,8 @@ class LayerBlock(NamedTuple):
     ``T + b*k .. T + b*k + k - 1``, so a model reads them as a slice
     (``models.layers.sources``) and no gather, nor its scatter-add
     backward, is compiled.  ``None`` (the default: ``dedup="hop"``, the
-    host and dist samplers, hand-built blocks) promises nothing and
-    ``nbr_local`` is gathered through.
+    host sampler, hand-built blocks) promises nothing and ``nbr_local`` is
+    gathered through.  ``dist.DistGraphSampler`` promises it per rank.
     """
 
     nbr_local: jax.Array   # [T, k] int32 indices into this layer's n_id

@@ -131,3 +131,31 @@ def power_graph():
     src, dst = make_random_csr(n_nodes=500, avg_deg=8, seed=2,
                                power_law=True)
     return CSRTopo(edge_index=np.stack([src, dst]))
+
+
+# ---------------------------------------------------------------------------
+# What a traced train step holds under the model's scope (test_pipeline.py,
+# test_dist.py)
+def model_primitives(jaxpr, inside=False):
+    """Names of the primitives traced under the ``qt.model`` scope
+    (forward, and backward as ``transpose(jvp(qt.model))``), through every
+    nested jaxpr."""
+    names = []
+    for eqn in jaxpr.eqns:
+        here = inside or "qt.model" in str(eqn.source_info.name_stack)
+        if here:
+            names.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names += model_primitives(sub, here)
+    return names
+
+
+def onehot_loss(logits, labels, mask):
+    # the default loss picks each label's logit with a gather of its own;
+    # this one has none, so any gather under qt.model is a conv's
+    ls = -(jax.nn.one_hot(labels, logits.shape[-1])
+           * jax.nn.log_softmax(logits)).sum(-1)
+    return (ls * mask).sum() / jax.numpy.maximum(mask.sum(), 1.0)

@@ -724,7 +724,9 @@ V5E_HBM = int(15.75 * 2 ** 30)                  # what a v5e chip reports
 def host_programs(topo, one_chip):
     """``{name: compiled}`` of ``jit_qt_dist_sample``, ``jit_qt_dist_lookup``
     and ``jit_qt_dp_train_step``, abstract arguments sharded over a mesh of
-    the four described chips."""
+    the four described chips; the step is compiled over the sample
+    program's own output tree of ``blocks``, its ``layout`` marker with
+    it."""
     import numpy as np
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -733,7 +735,6 @@ def host_programs(topo, one_chip):
     from quiver_tpu.dist.sampler import sample_program
     from quiver_tpu.models import GraphSAGE
     from quiver_tpu.parallel import TrainState, make_train_step
-    from quiver_tpu.sampler import LayerBlock
 
     mesh = Mesh(np.array(topo.devices), ("data",))
     n = HOST_RANKS
@@ -744,12 +745,14 @@ def host_programs(topo, one_chip):
                                     sharding=NamedSharding(mesh, P(*spec)))
 
     out = {}
-    out["jit_qt_dist_sample"] = sample_program(
-        mesh, "data", FANOUT, 1.0, TPU_GATHER_MODE, TPU_SAMPLE_RNG).lower(
+    sample = sample_program(mesh, "data", FANOUT, 1.0, TPU_GATHER_MODE,
+                            TPU_SAMPLE_RNG)
+    sample_args = (
         S((n, _pad128(HOST_ROWS + 1)), jnp.int32, "data", None),
         S((n, HOST_SHARD_EDGES), jnp.int32, "data", None),
         S((n + 1,), jnp.int32), S((n, BATCH), jnp.int32, "data", None),
-        S((n, BATCH), jnp.bool_, "data", None), S((), jnp.int32)).compile()
+        S((n, BATCH), jnp.bool_, "data", None), S((), jnp.int32))
+    out["jit_qt_dist_sample"] = sample.lower(*sample_args).compile()
     out["jit_qt_dist_lookup"] = lookup_program(
         mesh, "data", None, True).lower(
         S((n, HOST_ROWS, HOST_DIM), jnp.bfloat16, "data", None, None),
@@ -764,23 +767,25 @@ def host_programs(topo, one_chip):
         return model.apply(p, x.astype(jnp.float32), blocks, train=train,
                            rngs=rngs)
 
-    targets = [BATCH * 16 * 11, BATCH * 16, BATCH]      # outermost first
-    one = tuple(LayerBlock(
-        nbr_local=jax.ShapeDtypeStruct((t, k), jnp.int32),
-        mask=jax.ShapeDtypeStruct((t, k), jnp.bool_),
-        num_targets=jax.ShapeDtypeStruct((), jnp.int32))
-        for t, k in zip(targets, FANOUT[::-1]))
+    tm = jax.tree_util.tree_map
+    # what the step is handed is what the sampler returns: its tree, its
+    # marker, a rank on every leaf's leading axis
+    n_id, _, _, blocks = jax.eval_shape(sample, *sample_args)[:4]
+    assert n_id.shape == (n, HOST_FRONTIER)
+    assert [b.mask.shape for b in blocks] == [
+        (n, t, k) for t, k in zip((BATCH * 16 * 11, BATCH * 16, BATCH),
+                                  FANOUT[::-1])]      # outermost first
+    one = tm(lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype), blocks)
     tx = optax.adam(3e-3)
     params = jax.eval_shape(
         model.init, jax.random.key(1),
         jax.ShapeDtypeStruct((HOST_FRONTIER, HOST_DIM), jnp.float32), one)
     state = jax.eval_shape(lambda p: TrainState.create(p, tx), params)
-    tm = jax.tree_util.tree_map
     out["jit_qt_dp_train_step"] = make_train_step(
         apply_fn, tx, mesh=mesh).jitted.lower(
         tm(lambda s: S(s.shape, s.dtype), state),
         S((n, HOST_FRONTIER, HOST_DIM), jnp.bfloat16, "data"),
-        tm(lambda s: S((n,) + s.shape, s.dtype, "data"), one),
+        tm(lambda s: S(s.shape, s.dtype, "data"), blocks),
         S((n, BATCH), jnp.int32, "data"), S((n, BATCH), jnp.bool_, "data"),
         S((2,), jnp.uint32), None).compile()
     return out
@@ -828,3 +833,25 @@ def test_host_cell_programs_fit_beside_each_other(host_programs):
     rest = sum(x.temp_size_in_bytes + x.output_size_in_bytes
                for x in m.values())
     assert tables + rest < V5E_HBM, (tables, rest)
+
+
+def test_host_cell_step_convs_slice_the_samplers_blocks(host_programs):
+    """``jit_qt_dp_train_step`` over the blocks ``jit_qt_dist_sample``
+    returns, at the cell's real shapes: they say that they are positional,
+    so no instruction of a conv is a gather or a scatter.  With the marker
+    stripped (the program before PR 37) ``conv<i>/jit(_take)/gather`` of
+    every conv and ``scatter-add`` of conv1 and conv2 are there (12.2 and
+    3.3 ms a step on the chip, PERF.md section 5), the compiler takes 118 s
+    over the step where it now takes 6, and a chip's temporaries are
+    1,204,893,184 B where they now are 803,188,224 (AOT here, PR 37)."""
+    from quiver_tpu.telemetry.device_scopes import parse_hlo_scopes
+
+    c = host_programs["jit_qt_dp_train_step"]
+    module, table = parse_hlo_scopes(c.as_text())
+    assert module == "jit_qt_dp_train_step"
+    convs = {op for op in table.values()
+             if "qt.model" in op and "GraphSAGE)/conv" in op}
+    assert any("/conv0/lin_nbr/dot_general" in op for op in convs)
+    assert not {op for op in convs
+                if re.search(r"/(gather|scatter(-add)?)$", op)}
+    assert c.memory_analysis().temp_size_in_bytes < 900_000_000

@@ -460,3 +460,139 @@ def test_a_shard_is_as_long_as_its_largest_range_or_as_the_caller_says(mesh,
     assert np.array_equal(np.asarray(df.lookup(ids)), full[ids])
     with pytest.raises(ValueError, match="cannot hold"):
         DistFeature.from_row_ranges(full, mesh, UNEVEN, shard_rows=16)
+
+
+# ---------------------------------------------------------------------------
+# The data-parallel step over the sharded sampler's blocks: they say that
+# they are positional, so the convs slice (PERF.md, PR 37)
+import optax  # noqa: E402
+
+from quiver_tpu.dist.sampler import DistGraphSampler  # noqa: E402
+from quiver_tpu.models import GAT, GraphSAGE  # noqa: E402
+from quiver_tpu.parallel import (TrainState, make_train_step,  # noqa: E402
+                                 replicate)
+from quiver_tpu.sampler import POSITIONAL  # noqa: E402
+from tests.conftest import (make_random_csr, model_primitives,  # noqa: E402
+                            onehot_loss)
+
+DP_B, DP_CLASSES = 16, 5
+
+
+@pytest.fixture(scope="module")
+def sharded_batches(mesh):
+    """Three batches ``(xs, blocks, labels)`` of the sharded sampler and
+    feature store over one partition, a rank on every leading axis."""
+    from quiver_tpu import CSRTopo
+
+    topo = CSRTopo(edge_index=np.stack(make_random_csr(400, 6, seed=37)))
+    rng = np.random.default_rng(37)
+    feat = rng.normal(size=(topo.node_count, 12)).astype(np.float32)
+    sampler = DistGraphSampler(topo, mesh, sizes=[4, 3])
+    store = DistFeature.from_row_ranges(feat, mesh, sampler.row_starts_host)
+    batches = []
+    for i in range(3):
+        seeds = rng.integers(0, topo.node_count, (NHOSTS, DP_B))
+        n_id, n_mask, _, blocks = sampler.sample(seeds, key=i)
+        batches.append((store.lookup(n_id, n_mask), blocks,
+                        jnp.asarray(seeds % DP_CLASSES, jnp.int32)))
+    # holes to read through: targets of degree 0 and dead frontier slots
+    m = np.asarray(batches[0][1][0].mask)
+    assert not m.all(axis=2).all() and not m.any(axis=2).all()
+    return batches
+
+
+def _dp_model(name):
+    if name == "sage":
+        return GraphSAGE(hidden=16, out_dim=DP_CLASSES, num_layers=2,
+                         dropout=0.5)
+    return GAT(hidden=16, out_dim=DP_CLASSES, num_layers=2, heads=2,
+               dropout=0.5)
+
+
+def _dp_step(mesh, model, batch, **kw):
+    """``(step, fresh state)``: the state is donated, so one a call, and
+    replicated as the step returns it (another sharding is another
+    trace)."""
+    def apply_fn(p, x, blocks, train=False, rngs=None):
+        return model.apply(p, x, blocks, train=train, rngs=rngs)
+
+    xs, blocks, _ = batch
+    tx = optax.adam(1e-2)
+    params = model.init(jax.random.PRNGKey(1), xs[0],
+                        jax.tree.map(lambda l: l[0], blocks))
+    return (make_train_step(apply_fn, tx, mesh=mesh, **kw),
+            lambda: replicate(mesh, TrainState.create(
+                jax.tree.map(jnp.copy, params), tx)))
+
+
+def _stripped(blocks):
+    return tuple(b._replace(layout=None) for b in blocks)
+
+
+@pytest.mark.parametrize("name", ["sage", "gat"])
+def test_dp_step_over_positional_blocks_equals_the_gathered_one(
+        mesh, sharded_batches, name):
+    """The marker changes how a conv finds its sources, not what it finds:
+    loss exactly, the first gradient (Adam's ``mu`` over 0.1) and the
+    parameters after the step to float32 rounding."""
+    xs, blocks, labels = batch = sharded_batches[0]
+    assert all(b.layout is POSITIONAL for b in blocks)
+    step, fresh = _dp_step(mesh, _dp_model(name), batch)
+    ones, key = jnp.ones((NHOSTS, DP_B), bool), jax.random.PRNGKey(7)
+    sliced, loss_s = step(fresh(), xs, blocks, labels, ones, key)
+    gathered, loss_g = step(fresh(), xs, _stripped(blocks), labels, ones, key)
+    assert float(loss_s) == float(loss_g) and np.isfinite(float(loss_s))
+    for got, want in ((sliced.opt_state[0].mu, gathered.opt_state[0].mu),
+                      (sliced.params, gathered.params)):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            scale = float(np.abs(np.asarray(b)).max())
+            assert scale > 0
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6 * scale)
+
+
+@pytest.mark.parametrize("marked", [True, False],
+                         ids=["positional", "stripped"])
+def test_dp_step_model_gathers_follow_the_block_layout(mesh, sharded_batches,
+                                                       marked):
+    """``qt_dp_train_step``'s value_and_grad over the sampler's blocks holds
+    no gather and no scatter under ``qt.model``; with the marker stripped
+    both are back (``jnp.take`` and its scatter-add)."""
+    xs, blocks, labels = batch = sharded_batches[0]
+    step, fresh = _dp_step(mesh, _dp_model("sage"), batch,
+                           loss_fn=onehot_loss)
+    jaxpr = jax.make_jaxpr(step.jitted)(
+        fresh(), xs, blocks if marked else _stripped(blocks), labels,
+        jnp.ones((NHOSTS, DP_B), bool), jax.random.PRNGKey(1), None)
+    names = model_primitives(jaxpr.jaxpr)
+    assert "dot_general" in names  # the scope was found
+    found = {n for n in names if "gather" in n or "scatter" in n}
+    if marked:
+        assert not found, found
+    else:
+        assert "gather" in found and "scatter-add" in found, found
+
+
+def test_dp_step_traces_once(mesh, sharded_batches):
+    """The marker is structure: it crosses the step's ``jit``, its
+    ``in_shardings`` and the replica ``vmap`` as a Python value, and a
+    second and a third batch trace nothing."""
+    model, traces = _dp_model("sage"), []
+
+    class Spy:
+        def apply(self, p, x, blocks, **kw):
+            traces.append(tuple(b.layout for b in blocks))
+            assert x.ndim == 2 and blocks[0].mask.ndim == 2   # one replica
+            return model.apply(p, x, blocks, **kw)
+
+        init = model.init
+
+    step, fresh = _dp_step(mesh, Spy(), sharded_batches[0])
+    state, ones = fresh(), jnp.ones((NHOSTS, DP_B), bool)
+    for i, (xs, blocks, labels) in enumerate(sharded_batches):
+        state, loss = step(state, xs, blocks, labels, ones,
+                           jax.random.PRNGKey(i))
+        if i == 0:
+            first = len(traces)
+    assert first >= 1 and traces == [(POSITIONAL, POSITIONAL)] * first
+    assert np.isfinite(float(loss))

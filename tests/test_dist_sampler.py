@@ -369,6 +369,15 @@ def _four_rank_sampler(graph, sizes, **kw):
     return DistGraphSampler(graph, mesh, sizes=sizes, **kw)
 
 
+def _spread_seeds(s, B, rng):
+    """``[RANKS, B]`` seeds, a quarter of every rank's in each owner's
+    rows."""
+    starts = s.row_starts_host
+    return rng.permuted(np.concatenate(
+        [rng.integers(starts[p], starts[p + 1], (RANKS, B // RANKS))
+         for p in range(RANKS)], axis=1), axis=1)
+
+
 def _assert_draws(graph, s, seeds, n_id, n_mask, blocks, sizes):
     """Every target of every hop draws ``min(degree, k)`` neighbours of
     its own, masks are prefixes, nothing was dropped."""
@@ -411,14 +420,11 @@ def test_every_seed_on_one_owner_is_n_rounds_and_every_draw(small_graph,
 def test_seeds_spread_evenly_take_one_round(small_graph):
     sizes, B = [3, 2], 4096
     s = _four_rank_sampler(small_graph, sizes)
-    starts, rng = s.row_starts_host, np.random.default_rng(6)
-    # a quarter of every rank's seeds in each owner's rows: 1,024 into
-    # buckets of 1,280.  Hop 2's frontier goes where the neighbours live
-    # (ranges balanced by edges are not balanced by rows): its rounds are
-    # the counts', and its slots what those rounds shipped
-    seeds = rng.permuted(np.concatenate(
-        [rng.integers(starts[p], starts[p + 1], (RANKS, B // RANKS))
-         for p in range(RANKS)], axis=1), axis=1)
+    # 1,024 of a rank's seeds into each owner's bucket of 1,280.  Hop 2's
+    # frontier goes where the neighbours live (ranges balanced by edges are
+    # not balanced by rows): its rounds are the counts', and its slots what
+    # those rounds shipped
+    seeds = _spread_seeds(s, B, np.random.default_rng(6))
     n_id, n_mask, _, blocks = s.sample(seeds, key=10)
     rounds = np.asarray(s.last_rounds)
     assert (rounds[:, 0] == 1).all() and (rounds[0] == rounds).all()
@@ -439,3 +445,62 @@ def test_a_fraction_under_one_is_one_round_that_drops_and_counts(small_graph):
     assert cap == 1024 and (np.asarray(s.last_rounds) == 1).all()
     assert (s.overflow_stats()[:, 0] == B - cap).all()
     assert s.exchange_stats() == (RANKS * RANKS * cap, RANKS * cap)
+
+
+# ---------------------------------------------------------------------------
+# The blocks say that they are positional, and the promise holds through the
+# exchange (PERF.md, PR 37)
+
+
+@pytest.mark.parametrize("traffic,sample_rng,frac,hop1_rounds", [
+    ("one-owner", "key", 1.0, RANKS), ("one-owner", "hash", 1.0, RANKS),
+    ("spread", "key", 1.0, 1), ("spread", "hash", 1.0, 1),
+    ("one-owner", "hash", 0.5, 1)])
+def test_blocks_are_positional_whatever_the_exchange_did(
+        small_graph, traffic, sample_rng, frac, hop1_rounds):
+    """``LayerBlock.layout``'s promise for every rank and hop: a frontier of
+    ``T (1 + k)``, ``nbr_local[b, j] == T + b*k + j`` wherever ``mask``, the
+    drawn ids at those very positions - in one round or in ``n``, and where
+    a caller's cap dropped requests (their slots are ``mask == False``)."""
+    from quiver_tpu.sampler import POSITIONAL
+
+    sizes, B = [3, 2], 4096
+    s = _four_rank_sampler(small_graph, sizes, sample_rng=sample_rng,
+                           request_cap_frac=frac)
+    rng = np.random.default_rng(37)
+    if traffic == "spread":
+        seeds = _spread_seeds(s, B, rng)
+    else:       # every seed in rank 1's rows
+        seeds = rng.integers(*s.row_starts_host[1:3], (RANKS, B))
+    n_id, n_mask, _, blocks = s.sample(seeds, key=12)
+    assert (np.asarray(s.last_rounds)[:, 0] == hop1_rounds).all()
+    n_id, n_mask = np.asarray(n_id), np.asarray(n_mask)
+    T = B
+    for blk, k in zip(blocks[::-1], sizes):     # innermost first
+        assert blk.layout is POSITIONAL
+        local, m = np.asarray(blk.nbr_local), np.asarray(blk.mask)
+        assert m.shape == (RANKS, T, k)
+        pos = T + np.arange(T)[:, None] * k + np.arange(k)[None, :]
+        assert np.array_equal(local, np.where(m, pos, 0))
+        # what sits at those positions is what the owners drew, and the
+        # frontier's own mask is the block's
+        assert np.array_equal(n_mask[:, T:T * (1 + k)], m.reshape(RANKS, -1))
+        deg = np.asarray(small_graph.degree)[n_id[:, :T]]
+        served = m.any(axis=2)
+        assert np.array_equal(
+            m.sum(axis=2), np.where(served, np.minimum(deg, k), 0))
+        assert not (served & ~n_mask[:, :T]).any()    # a dead slot asks none
+        T *= 1 + k
+    assert n_id.shape == (RANKS, T)
+    dropped = s.overflow_stats()
+    if frac < 1.0:
+        # hop 1: all 4,096 requests of a rank into one bucket of 1,024
+        assert (dropped[:, 0] == B - s.hop_caps(B)[0]).all()
+        assert (np.asarray(blocks[-1].mask).any(axis=2).sum(axis=1)
+                <= s.hop_caps(B)[0]).all()
+    else:
+        assert (dropped == 0).all()
+    # a rank's blocks, as dist/e2e.py and the example take them
+    one = jax.tree_util.tree_map(lambda l: l[0], blocks)
+    assert all(b.layout is POSITIONAL and b.mask.ndim == 2 for b in one)
+    assert len(jax.tree_util.tree_leaves(one)) == 3 * len(sizes)

@@ -11,6 +11,7 @@ from quiver_tpu.models import GraphSAGE
 from quiver_tpu.parallel import TrainState
 from quiver_tpu.pipeline import make_fused_train_step, make_fused_eval_fn
 from quiver_tpu.utils.synthetic import community_graph
+from tests.conftest import model_primitives, onehot_loss
 
 
 @pytest.fixture(scope="module")
@@ -131,31 +132,6 @@ def test_fused_step_with_ici_sharded_feature(setup):
     assert np.isfinite(float(loss))
 
 
-def _model_primitives(jaxpr, inside=False):
-    """Names of the primitives traced under the ``qt.model`` scope
-    (forward, and backward as ``transpose(jvp(qt.model))``), through every
-    nested jaxpr."""
-    names = []
-    for eqn in jaxpr.eqns:
-        here = inside or "qt.model" in str(eqn.source_info.name_stack)
-        if here:
-            names.append(eqn.primitive.name)
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    names += _model_primitives(sub, here)
-    return names
-
-
-def _onehot_loss(logits, labels, mask):
-    # the default loss picks each label's logit with a gather of its own;
-    # this one has none, so any gather under qt.model is a conv's
-    ls = -(jax.nn.one_hot(labels, logits.shape[-1])
-           * jax.nn.log_softmax(logits)).sum(-1)
-    return (ls * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-
-
 @pytest.mark.parametrize("dedup,gathers", [("none", False), ("hop", True)])
 def test_fused_step_model_gathers_follow_the_block_layout(setup, dedup,
                                                           gathers):
@@ -170,7 +146,7 @@ def test_fused_step_model_gathers_follow_the_block_layout(setup, dedup,
     impl = _fused_train_impl(
         sampler, feature,
         lambda p, x, blocks, train=False, rngs=None: model.apply(
-            p, x, blocks, train=train, rngs=rngs), _onehot_loss)
+            p, x, blocks, train=train, rngs=rngs), onehot_loss)
     B = 32
     seeds = jnp.arange(B, dtype=jnp.int32)
     b0 = sampler.sample(np.arange(B, dtype=np.int64))
@@ -179,7 +155,7 @@ def test_fused_step_model_gathers_follow_the_block_layout(setup, dedup,
     jaxpr = jax.make_jaxpr(impl)(
         _tables(sampler, feature), state, seeds, seeds % 4,
         jnp.ones((B,), bool), jax.random.PRNGKey(1))
-    names = _model_primitives(jaxpr.jaxpr)
+    names = model_primitives(jaxpr.jaxpr)
     assert "dot_general" in names  # the scope was found
     found = {n for n in names if "gather" in n or "scatter" in n}
     if gathers:

@@ -211,11 +211,12 @@ def _blocks_of(producer, topo):
 
 @pytest.mark.parametrize("producer,positional", [
     ("none", True), ("overlay", True), ("hop", False), ("cpu", False),
-    ("dist", False), ("hand-built", False)])
+    ("dist", True), ("hand-built", False)])
 def test_positional_marker_is_static(small_graph, producer, positional):
-    """Only the two positional pipelines mark their blocks, and the marker
-    crosses the sampler's jit as a Python value: part of the tree's
-    structure, never a leaf."""
+    """Only the positional pipelines mark their blocks (the sharded
+    sampler's carry a leading rank axis), and the marker crosses the
+    sampler's jit as a Python value: part of the tree's structure, never a
+    leaf."""
     from quiver_tpu.sampler import POSITIONAL
 
     for blk in _blocks_of(producer, small_graph):
@@ -223,7 +224,7 @@ def test_positional_marker_is_static(small_graph, producer, positional):
         assert not isinstance(blk.layout, jax.Array)
         assert len(jax.tree.leaves(blk)) == 3
         if positional:
-            t, k = blk.mask.shape
+            t, k = blk.mask.shape[-2:]
             pos = t + np.arange(t)[:, None] * k + np.arange(k)[None, :]
             m = np.asarray(blk.mask)
             np.testing.assert_array_equal(np.asarray(blk.nbr_local),
