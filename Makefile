@@ -86,8 +86,8 @@ qos:
 paged:
 	python -m pytest tests/ -m paged -q
 
-# unified timeline / program attribution / perfgate suite
-# (docs/OBSERVABILITY.md "Timeline & program attribution")
+# unified timeline / perfgate suite
+# (docs/OBSERVABILITY.md "Timeline")
 timeline:
 	python -m pytest tests/ -m timeline -q
 

@@ -1382,22 +1382,20 @@ def run_trace_scenario(path):
     from quiver_tpu.recovery.wal import WriteAheadLog
     from quiver_tpu.resilience import chaos
     from quiver_tpu.resilience.qos import DegradationLadder, LadderStep
-    from quiver_tpu.telemetry import flightrec, profile, timeline
+    from quiver_tpu.telemetry import flightrec, timeline
 
     telemetry.set_enabled(True)
     telemetry.reset()
     timeline.enable()
-    profile.enable()
 
     n_nodes, n_edges = 30_000, 400_000
     indptr, indices = build_graph(n_nodes, n_edges, seed=3)
     topo = CSRTopo(indptr=indptr, indices=indices)
     topo.to_device()
 
-    # serving + registry + program attribution: the Device-lane replay.
-    # Telemetry is on, so every request carries a TraceContext (the
-    # correlation origin); warmup compiles land as registry.build
-    # events and every executed program is profile-wrapped.
+    # serving + registry: the Device-lane replay.  Telemetry is on, so
+    # every request carries a TraceContext (the correlation origin);
+    # warmup compiles land as registry.build events.
     bench_serving(topo, 32, 8, n_requests=12, hidden=64, mode="Device")
 
     # paged + wal + chaos under ONE explicit trace so their slices
@@ -1453,17 +1451,14 @@ def run_trace_scenario(path):
     correlated = sorted({
         e.get("cat") for e in evs
         if e.get("args", {}).get("trace_id") in req_ids})
-    top = profile.top_programs(3)
     log(f"trace: {len(evs)} events, subsystems {cats}, "
-        f"{len(req_ids)} request traces, correlated {correlated}, "
-        f"top programs {[p['subsystem'] + ':' + str(p['key'])[:40] for p in top]}")
+        f"{len(req_ids)} request traces, correlated {correlated}")
     ok = (len(cats) >= 5 and len(req_ids) > 0
           and any(c != "serving" for c in correlated))
     print(json.dumps({
         "trace_path": path, "events": len(evs), "subsystems": cats,
         "request_traces": len(req_ids),
         "correlated_subsystems": correlated,
-        "programs_attributed": profile.debug_payload()["programs"],
         "ok": ok,
     }))
     if not ok:

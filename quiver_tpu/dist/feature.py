@@ -35,8 +35,10 @@ from ..parallel.train import replicate
 from ..resilience import chaos
 from ..resilience.deadline import check_ambient
 from ..resilience.errors import PeerTimeout
-from ..telemetry.device_scopes import (FEATURE_GATHER, exchange as
-                                       exchange_scope, register_program)
+from .. import telemetry
+from ..telemetry.device_scopes import (FEATURE_GATHER, HOST_LOOKUP, LAUNCH,
+                                       PLACE, exchange as exchange_scope,
+                                       register_program)
 from .exchange import (bucket_len, exchange, put_row_blocks,
                        record_exchange, shard_len)
 
@@ -363,8 +365,6 @@ class DistFeature:
         ``Feature.invalidate_rows``: resident slots drop, admission
         evidence resets.  Returns overlay slots dropped.
         """
-        from .. import telemetry
-
         if self.cold_cache is None:
             return 0
         ids = np.atleast_1d(np.asarray(global_ids, dtype=np.int64))
@@ -418,7 +418,6 @@ class DistFeature:
         table update + table-value capture all under ``_ov_lock``.
         """
         from ..feature import _pow2_bucket
-        from .. import telemetry
 
         me = self.info.host
         B = ids.shape[1]
@@ -499,34 +498,49 @@ class DistFeature:
         ``self.last_rounds``); check :meth:`overflow_stats` when running
         with a cap of your own, which is ONE round of buckets that long —
         training on silently zeroed features is the failure mode this
-        guards against."""
-        check_ambient("dist_feature")
-        ov_patch = None
-        if self.cold_cache is not None and not isinstance(ids, jax.Array):
-            # host-side overlay probe needs host ids; device ids would
-            # force a sync here, so they bypass the overlay entirely
-            ids = np.asarray(ids, dtype=np.int32)
-            valid = (np.ones(ids.shape, dtype=bool) if valid is None
-                     else np.array(valid, dtype=bool))  # copy: bits clear
-            ov_patch = self._overlay_probe(ids, valid)
-        ids = jnp.asarray(ids, jnp.int32)
-        nh, B = ids.shape
-        if valid is None:
-            valid = jnp.ones((nh, B), bool)
-        cap = self.request_cap or None      # None: exact, in rounds
-        key = (B, cap)
-        sharding = NamedSharding(self.mesh, P(self.axis, None))
-        ids = jax.device_put(ids, sharding)
-        valid = jax.device_put(valid, sharding)
-        args = (self.shards, self.tables, ids, valid)
-        if key not in self._fn:
-            self._fn[key] = lookup_program(
-                self.mesh, self.axis, cap,
-                self.row_starts_host is not None)
-            register_program(self._fn[key], args)
+        guards against.
+
+        Telemetry: each call folds into the ``feature.lookup`` span, with
+        two parts inside it: ``feature.lookup.place`` (the deadline check,
+        the overlay's probe, ids and mask put onto the mesh: everything
+        before the program is called) and ``feature.lookup.launch`` (the
+        call of ``jit_qt_dist_lookup`` until it returns to Python).  All
+        three time how long the CALLER's thread is held, not the device:
+        the rows are still being fetched when the call has returned."""
+        with telemetry.span(HOST_LOOKUP):
+            return self._lookup_impl(ids, valid)
+
+    def _lookup_impl(self, ids, valid):
+        with telemetry.span(HOST_LOOKUP + PLACE):
+            check_ambient("dist_feature")
+            ov_patch = None
+            if self.cold_cache is not None and not isinstance(ids,
+                                                              jax.Array):
+                # host-side overlay probe needs host ids; device ids would
+                # force a sync here, so they bypass the overlay entirely
+                ids = np.asarray(ids, dtype=np.int32)
+                valid = (np.ones(ids.shape, dtype=bool) if valid is None
+                         else np.array(valid, dtype=bool))  # copy: bits clear
+                ov_patch = self._overlay_probe(ids, valid)
+            ids = jnp.asarray(ids, jnp.int32)
+            nh, B = ids.shape
+            if valid is None:
+                valid = jnp.ones((nh, B), bool)
+            cap = self.request_cap or None      # None: exact, in rounds
+            key = (B, cap)
+            sharding = NamedSharding(self.mesh, P(self.axis, None))
+            ids = jax.device_put(ids, sharding)
+            valid = jax.device_put(valid, sharding)
+            args = (self.shards, self.tables, ids, valid)
+            if key not in self._fn:
+                self._fn[key] = lookup_program(
+                    self.mesh, self.axis, cap,
+                    self.row_starts_host is not None)
+                register_program(self._fn[key], args)
         try:
-            _CHAOS_EXCHANGE()
-            out, overflow, live, rounds = self._fn[key](*args)
+            with telemetry.span(HOST_LOOKUP + LAUNCH):
+                _CHAOS_EXCHANGE()
+                out, overflow, live, rounds = self._fn[key](*args)
         except (PeerTimeout, TimeoutError):
             # peer shard timed out: degrade to the rows resolvable
             # WITHOUT the collective (owned / replicated / overlay-hit),
@@ -561,7 +575,6 @@ class DistFeature:
         # shed HERE, before the local-rows gather, not after — the
         # serving loop installed the batch deadline as ambient scope
         check_ambient("dist_feature")
-        from .. import telemetry
         from ..telemetry import flightrec
 
         info = self.info
@@ -609,8 +622,6 @@ class DistFeature:
             self._overflow_recorded = True
             total = float(arr.sum())
             if total:
-                from .. import telemetry
-
                 telemetry.counter("dist_feature_overflow_total").inc(total)
         return arr
 

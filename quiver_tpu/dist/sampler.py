@@ -36,9 +36,10 @@ from ..utils.topology import CSRTopo
 from ..ops.sample import sample_neighbors
 from ..parallel.train import replicate
 from ..sampler import POSITIONAL, LayerBlock, SampledBatch
+from .. import telemetry
 from ..telemetry.device_scopes import (exchange as exchange_scope,
                                        register_program, sampler_hop,
-                                       SAMPLER)
+                                       HOST_SAMPLE, LAUNCH, PLACE, SAMPLER)
 from .exchange import (bucket_len, exchange, put_row_blocks,
                        record_exchange, shard_len)
 
@@ -369,40 +370,53 @@ class DistGraphSampler:
         sent at each hop (its live frontier slots, whoever owns them), and
         ``self.last_rounds`` the rounds each hop's exchange was shipped in
         (every rank's the same; 1 under a caller's cap).
+
+        Telemetry: each call folds into the ``sampler.sample`` span (the
+        name ``GraphSageSampler.sample`` uses: the same boundary), with two
+        parts inside it: ``sampler.sample.place`` (the seeds, their mask
+        and the key put onto the mesh: everything before the program is
+        called) and ``sampler.sample.launch`` (the call of
+        ``jit_qt_dist_sample``, retries included, until it returns to
+        Python).  All three time how long the CALLER's thread is held, not
+        the device: the program runs on after the call has returned.
         """
-        seeds = jnp.asarray(seed_batches, jnp.int32)
-        nd, B = seeds.shape
-        assert nd == self.n, (nd, self.n)
-        valid = jnp.ones((nd, B), bool)
-        if key is None:
-            key = np.random.randint(0, 2**31 - 1)
-        sh = NamedSharding(self.mesh, P(self.axis, None))
-        seeds = jax.device_put(seeds, sh)
-        valid = jax.device_put(valid, sh)
-        args = (self.indptr_sh, self.indices_sh, self.row_starts, seeds,
-                valid, jnp.int32(key))
-        if B not in self._fn:
-            self._fn[B] = sample_program(
-                self.mesh, self.axis, self.sizes, self.request_cap_frac,
-                self.gather_mode, self.sample_rng)
-            register_program(self._fn[B], args)
+        with telemetry.span(HOST_SAMPLE):
+            return self._sample_impl(seed_batches, key)
+
+    def _sample_impl(self, seed_batches, key):
+        with telemetry.span(HOST_SAMPLE + PLACE):
+            seeds = jnp.asarray(seed_batches, jnp.int32)
+            nd, B = seeds.shape
+            assert nd == self.n, (nd, self.n)
+            valid = jnp.ones((nd, B), bool)
+            if key is None:
+                key = np.random.randint(0, 2**31 - 1)
+            sh = NamedSharding(self.mesh, P(self.axis, None))
+            seeds = jax.device_put(seeds, sh)
+            valid = jax.device_put(valid, sh)
+            args = (self.indptr_sh, self.indices_sh, self.row_starts, seeds,
+                    valid, jnp.int32(key))
+            if B not in self._fn:
+                self._fn[B] = sample_program(
+                    self.mesh, self.axis, self.sizes, self.request_cap_frac,
+                    self.gather_mode, self.sample_rng)
+                register_program(self._fn[B], args)
 
         def _exchange():
             _CHAOS_EXCHANGE()
             return self._fn[B](*args)
 
         def _on_retry(attempt, exc):
-            from .. import telemetry
-
             telemetry.counter("dist_sampler_retries_total").inc()
 
         # one retried attempt with a short jittered backoff — a
         # transient peer stall usually clears; a second timeout surfaces
         # to the caller (sampling has no partial-answer degrade: a
         # frontier with holes would silently bias the training batch)
-        n_id, n_mask, num, blocks, overflow, live, rounds = retry_call(
-            _exchange, attempts=2, backoff=self._retry_backoff,
-            retry_on=(PeerTimeout, TimeoutError), on_retry=_on_retry)
+        with telemetry.span(HOST_SAMPLE + LAUNCH):
+            n_id, n_mask, num, blocks, overflow, live, rounds = retry_call(
+                _exchange, attempts=2, backoff=self._retry_backoff,
+                retry_on=(PeerTimeout, TimeoutError), on_retry=_on_retry)
         self.last_overflow = overflow
         self._overflow_recorded = False
         self.last_live = live       # [n_shards, L], beside last_overflow
@@ -437,7 +451,5 @@ class DistGraphSampler:
             self._overflow_recorded = True
             total = float(arr.sum())
             if total:
-                from .. import telemetry
-
                 telemetry.counter("dist_sampler_overflow_total").inc(total)
         return arr

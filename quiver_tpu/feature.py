@@ -32,7 +32,7 @@ from typing import List, Optional, Sequence, Union
 
 import numpy as np
 
-from .telemetry.device_scopes import FEATURE_GATHER
+from .telemetry.device_scopes import FEATURE_GATHER, HOST_GETITEM
 from .utils.topology import CSRTopo, parse_size, reindex_feature
 
 __all__ = ["Feature", "DeviceConfig"]
@@ -600,7 +600,7 @@ class Feature:
         self.lazy_init_from_ipc_handle()
         tier = ("hot" if self.cache_count >= self.node_count else
                 ("cold" if self.cache_count == 0 else "mixed"))
-        with telemetry.span("feature.getitem"), telemetry.histogram(
+        with telemetry.span(HOST_GETITEM), telemetry.histogram(
                 "feature_gather_seconds", tier=tier).time():
             out = self._getitem_impl(node_idx, jax, jnp, telemetry)
         telemetry.counter("feature_gather_batches_total", tier=tier).inc()

@@ -19,7 +19,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..telemetry.device_scopes import MODEL, OPTIMIZER, register_program
+from .. import telemetry
+from ..telemetry.device_scopes import (HOST_STEP_TRAIN, MODEL, OPTIMIZER,
+                                       register_program)
 
 __all__ = ["TrainState", "Frontier", "call_model", "make_train_step",
            "shard_batch", "replicate"]
@@ -102,7 +104,10 @@ def make_train_step(apply_fn: Callable, tx: optax.GradientTransformation,
     ``frontier`` (a :class:`Frontier`: the batch's ``n_id`` and
     ``n_id_mask``) is for an ``apply_fn`` that asks for it
     (:func:`call_model`); such a model's state travels in
-    ``state.model_state``.  On a mesh the replicas' states are averaged.
+    ``state.model_state``.  On a mesh the replicas' states are averaged,
+    and each call of the jitted program (``jit_qt_dp_train_step``) folds
+    into the ``step.train`` span: the call until it returns to Python, the
+    caller's thread and not the device.
     """
     if loss_fn is None:
         def loss_fn(logits, labels, mask):
@@ -184,7 +189,9 @@ def make_train_step(apply_fn: Callable, tx: optax.GradientTransformation,
         if not registered:      # before the call: ``state`` is donated
             registered = True
             register_program(jitted, args)
-        return jitted(*args)
+        # how long the launch holds the caller's thread, not the device
+        with telemetry.span(HOST_STEP_TRAIN):
+            return jitted(*args)
 
     sharded_step.jitted = jitted    # to lower it for a described mesh
     return sharded_step

@@ -22,7 +22,10 @@ import optax
 from .feature import Feature, _lookup_tables
 from .sampler import GraphSageSampler, run_pipeline
 from .parallel.train import Frontier, TrainState, call_model
-from .telemetry.device_scopes import MODEL, OPTIMIZER, register_program
+from . import telemetry
+from .telemetry.device_scopes import (HOST_STEP_EPOCH, HOST_STEP_EVAL,
+                                      HOST_STEP_TRAIN, MODEL, OPTIMIZER,
+                                      register_program)
 
 __all__ = ["make_fused_train_step", "make_fused_eval_fn"]
 
@@ -46,7 +49,13 @@ def make_fused_train_step(sampler: GraphSageSampler, feature: Feature,
     and blocks alone.  One that also has a ``frontier`` parameter (a typed
     model, a model with batch statistics) is handed the sampled frontier
     and ``state.model_state`` and returns ``(logits, model_state)``:
-    :func:`quiver_tpu.parallel.train.call_model`."""
+    :func:`quiver_tpu.parallel.train.call_model`.
+
+    Each call of the jitted program (``jit_qt_fused_train_step``) folds
+    into the ``step.train`` span, as ``make_scan_epoch``'s does into
+    ``step.epoch`` and ``make_fused_eval_fn``'s into ``step.eval``: the
+    call until it returns to Python, which is how long the launch holds
+    the caller's thread and says nothing of the device."""
     impl = _fused_train_impl(sampler, feature, apply_fn, loss_fn)
     tables = _tables(sampler, feature)
     jitted = jax.jit(impl, donate_argnums=(1,))
@@ -58,7 +67,9 @@ def make_fused_train_step(sampler: GraphSageSampler, feature: Feature,
         if not registered:      # before the call: ``state`` is donated
             registered = True
             register_program(jitted, args)
-        return jitted(*args)
+        # how long the launch holds the caller's thread, not the device
+        with telemetry.span(HOST_STEP_TRAIN):
+            return jitted(*args)
 
     return step
 
@@ -160,7 +171,8 @@ def make_scan_epoch(sampler: GraphSageSampler, feature: Feature,
         if not registered:
             registered = True
             register_program(qt_scan_epoch, args)
-        return qt_scan_epoch(*args)
+        with telemetry.span(HOST_STEP_EPOCH):
+            return qt_scan_epoch(*args)
 
     return epoch
 
@@ -201,6 +213,7 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
         if not registered:
             registered = True
             register_program(qt_fused_eval, args)
-        return qt_fused_eval(*args)
+        with telemetry.span(HOST_STEP_EVAL):
+            return qt_fused_eval(*args)
 
     return eval_fn

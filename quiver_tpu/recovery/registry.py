@@ -37,7 +37,6 @@ import weakref
 from typing import Dict, Optional
 
 from .. import telemetry
-from ..telemetry import profile as _profile
 from ..telemetry import timeline as _timeline
 from .errors import RetraceBudgetExceeded
 
@@ -74,8 +73,6 @@ class ProgramCache(dict):
         return present
 
     def __setitem__(self, key, value) -> None:
-        if _profile._ON:   # one global read when profiling is off
-            value = _profile.wrap(self.subsystem, key, value)
         fresh = not dict.__contains__(self, key)
         dict.__setitem__(self, key, value)
         if fresh:

@@ -35,7 +35,7 @@ from .ops.sample import (sample_neighbors, sample_neighbors_overlay,
                          sample_neighbors_weighted, row_cumsum_weights)
 from .ops.blockgather import NO_WINDOW, fallback_slots
 from .ops.reindex import reindex
-from .telemetry.device_scopes import SAMPLER, sampler_hop
+from .telemetry.device_scopes import HOST_SAMPLE, SAMPLER, sampler_hop
 from .utils.topology import CSRTopo
 
 __all__ = ["GraphSageSampler", "SampledBatch", "LayerBlock", "POSITIONAL"]
@@ -607,7 +607,7 @@ class GraphSageSampler:
         counters.
         """
         mode = self.mode.lower()
-        with telemetry.span("sampler.sample"), telemetry.histogram(
+        with telemetry.span(HOST_SAMPLE), telemetry.histogram(
                 "sampler_sample_seconds", mode=mode).time():
             batch = self._sample_impl(input_nodes, key,
                                       time_window=time_window)

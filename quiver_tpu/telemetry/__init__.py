@@ -120,9 +120,6 @@ def reset() -> None:
     tl = sys.modules.get(__name__ + ".timeline")
     if tl is not None:
         tl.reset()
-    pf = sys.modules.get(__name__ + ".profile")
-    if pf is not None:
-        pf.reset()
     slo = sys.modules.get(__name__ + ".slo")
     if slo is not None:
         slo.reset()

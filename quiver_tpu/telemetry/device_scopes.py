@@ -51,7 +51,9 @@ from typing import Dict, Optional, Tuple
 
 __all__ = ["PREFIX", "SAMPLER", "FEATURE_GATHER", "MODEL", "MODEL_PROJECT",
            "MODEL_ATTENTION", "OPTIMIZER", "FLOW", "EXCHANGE", "sampler_hop",
-           "exchange",
+           "exchange", "HOST_SAMPLE", "HOST_GETITEM", "HOST_LOOKUP",
+           "HOST_STEP_TRAIN", "HOST_STEP_EPOCH", "HOST_STEP_EVAL", "PLACE",
+           "LAUNCH",
            "register_program", "device_scopes", "parse_hlo_scopes",
            "instruction_key", "scoped"]
 
@@ -75,6 +77,27 @@ FLOW = PREFIX + "flow"
 # qt.exchange``): a whole-layer reader takes the first name and so counts
 # a layer with its exchange, a part reader the last
 EXCHANGE = PREFIX + "exchange"
+
+# -- the host's side of the same scheme -----------------------------------
+# What ``telemetry.span`` is handed at the library's step-path boundaries.
+# It puts PREFIX in front for the profiler (``qt.sampler.sample`` on the
+# trace's ``/host:CPU`` plane, on the device trace's clock) and keys
+# ``SpanTracer.summary()`` by the name as it stands here.  A host span
+# times how long the CALLER's thread is held, never the device (no
+# ``block=``); a part (``<span>.place``, ``<span>.launch``) runs inside its
+# parent's interval on its thread, and a layer's own time is its span minus
+# its parts.
+HOST_SAMPLE = "sampler.sample"      # GraphSageSampler / DistGraphSampler
+HOST_GETITEM = "feature.getitem"    # Feature.__getitem__
+HOST_LOOKUP = "feature.lookup"      # DistFeature.lookup
+HOST_STEP_TRAIN = "step.train"      # the fused and the data-parallel step
+HOST_STEP_EPOCH = "step.epoch"      # pipeline.make_scan_epoch
+HOST_STEP_EVAL = "step.eval"        # pipeline.make_fused_eval_fn
+# parts of a sharded call: its arguments put onto the mesh (everything
+# before the program is called), and the jitted call until it returns to
+# Python
+PLACE = ".place"
+LAUNCH = ".launch"
 
 
 def sampler_hop(n: int) -> str:

@@ -19,9 +19,8 @@ Three views:
     circuit-breaker states), ``/debug/qos`` (tenant classes, token
     levels, degradation-ladder level + history), ``/debug/timeline``
     (the unified cross-subsystem Chrome trace — Perfetto-loadable),
-    ``/debug/programs`` (top-K per-program time attribution, see
-    ``telemetry.profile``), ``/debug/mesh`` (live mesh feature/sampler
-    shard stats, see docs/SHARDING.md), and ``/debug/fleet`` (router +
+    ``/debug/mesh`` (live mesh feature/sampler shard stats, see
+    docs/SHARDING.md), and ``/debug/fleet`` (router +
     membership view of the replicated serving fleet, see
     docs/FLEET.md).  With a
     live fleet federation (docs/OBSERVABILITY.md), three more:
@@ -264,11 +263,6 @@ class MetricsServer:
                     from ..mesh import mesh_status
 
                     return (json.dumps(mesh_status(), indent=2),
-                            "application/json")
-                if path.startswith("/debug/programs"):
-                    from . import profile
-
-                    return (json.dumps(profile.debug_payload(), indent=2),
                             "application/json")
                 return None
 

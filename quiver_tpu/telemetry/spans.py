@@ -4,9 +4,10 @@ Subsumes the old ``utils.trace.trace_scope`` / ``Timer`` pair.  Two
 independent switches:
 
   * **aggregation** is always on for a live (non-noop) tracer: every
-    span folds into ``{name: [count, total_s]}`` — this is what
-    ``summary()`` (né ``trace_summary``) reads and costs one lock + two
-    adds per span.
+    span folds into ``{name: [count, total_s, max_s]}`` — this is what
+    ``summary()`` (né ``trace_summary``) reads and costs one lock, two
+    adds and one comparison per span.  The longest single call is what
+    shows a stall without a trace: a mean hides one step in a thousand.
   * **event retention** (``set_tracing(True)`` or env
     ``QUIVER_TPU_TRACE=1``) additionally appends one event record per
     span — name, start/duration in µs, pid/tid, nesting depth — which
@@ -93,7 +94,8 @@ class SpanTracer:
 
     def __init__(self, tracing: Optional[bool] = None):
         self._lock = threading.Lock()
-        self._agg: Dict[str, List[float]] = {}   # name -> [count, total_s]
+        # name -> [count, total_s, longest single call in s]
+        self._agg: Dict[str, List[float]] = {}
         self._events: List[dict] = []
         self._dropped = 0
         self._tls = threading.local()
@@ -109,10 +111,12 @@ class SpanTracer:
         with self._lock:
             s = self._agg.get(name)
             if s is None:
-                self._agg[name] = [1, dt]
+                self._agg[name] = [1, dt, dt]
             else:
                 s[0] += 1
                 s[1] += dt
+                if dt > s[2]:
+                    s[2] = dt
             if self._tracing:
                 if len(self._events) < _MAX_EVENTS:
                     self._events.append({
@@ -139,16 +143,18 @@ class SpanTracer:
 
     # -- readout ----------------------------------------------------------
     def summary(self) -> Dict[str, dict]:
-        """``{name: {count, total_s, mean_ms}}`` — same shape the old
-        ``trace_summary()`` returned."""
+        """``{name: {count, total_s, mean_ms, max_ms}}`` since the last
+        ``reset()`` — the old ``trace_summary()``'s shape, and the longest
+        single call beside the mean."""
         with self._lock:
             return {
                 name: {
                     "count": int(c),
                     "total_s": t,
                     "mean_ms": (t / c * 1e3) if c else 0.0,
+                    "max_ms": m * 1e3,
                 }
-                for name, (c, t) in sorted(self._agg.items())
+                for name, (c, t, m) in sorted(self._agg.items())
             }
 
     def events(self) -> List[dict]:

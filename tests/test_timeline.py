@@ -1,9 +1,9 @@
 """Unified timeline profiler suite (ISSUE 11).
 
 Covers the cross-subsystem event bus (:mod:`quiver_tpu.telemetry.
-timeline`), per-program attribution (:mod:`..profile`), the perf gate
-(``benchmarks/perfgate.py``), the hostile-label Prometheus escaping
-fix, and the hardened XLA-profiler wrapper.
+timeline`), the perf gate (``benchmarks/perfgate.py``), the
+hostile-label Prometheus escaping fix, and the hardened XLA-profiler
+wrapper.
 
 The load-bearing tests:
 
@@ -28,7 +28,7 @@ from pathlib import Path
 import pytest
 
 from quiver_tpu import telemetry
-from quiver_tpu.telemetry import flightrec, profile, timeline
+from quiver_tpu.telemetry import flightrec, timeline
 
 pytestmark = pytest.mark.timeline
 
@@ -56,7 +56,6 @@ class TestGating:
         # someone added work to the off path — that is a perf
         # regression at every instrumented call site in the library.
         assert timeline.on.__code__.co_names == ("_ON",)
-        assert profile.on.__code__.co_names == ("_ON",)
 
     def test_off_timeline_records_nothing_from_subsystems(self):
         assert not timeline.on()
@@ -74,8 +73,7 @@ class TestGating:
     def test_enable_respects_telemetry_kill_switch(self):
         telemetry.set_enabled(False)
         assert timeline.enable() is False
-        assert profile.enable() is False
-        assert not timeline.on() and not profile.on()
+        assert not timeline.on()
 
     def test_spans_and_flightrec_land_when_on(self):
         timeline.enable()
@@ -209,87 +207,26 @@ class TestChromeTrace:
         assert cats["wal.fsync"] == "wal"
 
 
-# ------------------------------------------------------------ profile
-class TestProgramAttribution:
-    def test_cache_insertions_are_wrapped_and_attributed(self):
-        from quiver_tpu.recovery.registry import get_program_registry
-
-        profile.enable()
-        cache = get_program_registry().cache("testsub")
-        cache["k1"] = lambda x: x + 1
-        assert type(cache["k1"]).__name__ == "_ProfiledProgram"
-        assert cache["k1"](41) == 42
-        rows = profile.top_programs(5)
-        row = next(r for r in rows if r["subsystem"] == "testsub")
-        assert row["calls"] == 1
-        assert row["total_s"] >= row["host_s"] >= 0
-        # honest device stamping: this suite pins the CPU backend
-        assert row["device"] is False
-        payload = profile.debug_payload()
-        assert payload["enabled"] and payload["programs"] >= 1
-
-    def test_disable_unwraps(self):
-        from quiver_tpu.recovery.registry import get_program_registry
-
-        profile.enable()
-        cache = get_program_registry().cache("unwrapsub")
-        fn = lambda x: x  # noqa: E731
-        cache["k"] = fn
-        profile.disable()
-        assert cache["k"] is fn
-
-    def test_retro_wrap_of_existing_programs(self):
-        from quiver_tpu.recovery.registry import get_program_registry
-
-        cache = get_program_registry().cache("warmsub")
-        cache["old"] = lambda x: x * 2
-        assert type(cache["old"]).__name__ != "_ProfiledProgram"
-        profile.enable()
-        assert type(cache["old"]).__name__ == "_ProfiledProgram"
-        assert cache["old"](3) == 6
-        assert any(r["subsystem"] == "warmsub"
-                   for r in profile.top_programs(50))
-
-    def test_wrapped_program_lands_on_timeline(self):
-        from quiver_tpu.recovery.registry import get_program_registry
-
-        timeline.enable()
-        profile.enable()
-        cache = get_program_registry().cache("tlsub")
-        cache["k"] = lambda: None
-        cache["k"]()
-        doc = timeline.chrome_trace()
-        ev = next(e for e in doc["traceEvents"]
-                  if e.get("name") == "program.tlsub")
-        assert ev["ph"] == "X" and ev["cat"] == "registry"
-        assert ev["args"]["device"] is False
-
-
 # ------------------------------------------------------------ endpoints
 class TestHttpEndpoints:
     def test_debug_timeline_and_programs_roundtrip(self):
+        from urllib.error import HTTPError
         from urllib.request import urlopen
 
         from quiver_tpu.telemetry.export import start_http_server
 
         timeline.enable()
-        profile.enable()
         timeline.emit("http.ev", cat="app", dur_s=0.001)
-        from quiver_tpu.recovery.registry import get_program_registry
-
-        cache = get_program_registry().cache("httpsub")
-        cache["k"] = lambda: 7
-        cache["k"]()
         srv = start_http_server(port=0)
         try:
             doc = json.loads(urlopen(f"{srv.url}/debug/timeline",
                                      timeout=5).read())
             assert any(e.get("name") == "http.ev"
                        for e in doc["traceEvents"])
-            prog = json.loads(urlopen(f"{srv.url}/debug/programs",
-                                      timeout=5).read())
-            assert prog["enabled"] is True
-            assert any(r["subsystem"] == "httpsub" for r in prog["top"])
+            # the blocking program profiler and its route went together
+            with pytest.raises(HTTPError) as gone:
+                urlopen(f"{srv.url}/debug/programs", timeout=5)
+            assert gone.value.code == 404
         finally:
             srv.close()
 
