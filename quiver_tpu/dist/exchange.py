@@ -17,6 +17,15 @@ a balanced frontier takes one round, every id on one owner ``n``, and
 nothing is dropped.  (One round of buckets as long as the whole frontier
 is exact too, and has every owner serve ``n`` frontiers' slots for one:
 twice the step of the four-chip cell, PERF.md section 6, PR 35.)
+
+A round's answers are unpacked into request order by one gather out of
+what came back, one row per request slot.  A slot that sent nothing in
+that round (an invalid id, one dropped, one shipped in another round)
+reads a row of its own, as the one-chip gather's dead slots do
+(``feature._dead_rows``), and the ``where`` after it keeps zeros (or an
+earlier round's answer) there: sent to one row, the 56.5% of the four-chip
+cell's slots that are dead made the unpacking the dearest operation in
+the lookup's loop (PERF.md section 6, PR 39).
 """
 
 from __future__ import annotations
@@ -28,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..feature import _dead_rows
 from ..telemetry.device_scopes import exchange as exchange_scope
 
 __all__ = ["Counts", "exchange", "bucket_len", "put_row_blocks", "shard_len",
@@ -118,8 +128,9 @@ def exchange(layer: str, axis: str, n: int, cap, ids, owner, valid, serve,
             back = jax.lax.all_to_all(
                 payload.reshape((n, bucket) + row.shape), axis,
                 split_axis=0, concat_axis=0, tiled=True)
-            got = jnp.take(back.reshape((n * bucket,) + row.shape),
-                           jnp.clip(dest, 0, n * bucket - 1), axis=0)
+            got = back.reshape((n * bucket,) + row.shape).at[jnp.where(
+                mine, dest, _dead_rows(F, n * bucket, dest.dtype))].get(
+                mode="promise_in_bounds")
             return jnp.where(mine.reshape((F,) + (1,) * len(row.shape)),
                              got, answers)
 
@@ -155,17 +166,21 @@ def shard_len(need: int, room=None) -> int:
 
 def record_exchange(owner, layer: str):
     """Materialise ``owner._last_exchange`` (the slots one round ships per
-    rank, by hop where there are hops; the device arrays of rounds and of
-    live counts) and count it once, at query time: what the
-    ``exchange_stats()`` of sampler and feature store return.  Slots are
-    what was SHIPPED, rounds x ranks x bucket summed over the ranks (and
-    hops); a round is counted once, not once a rank."""
+    rank and the frontier's slots, by hop where there are hops; the device
+    arrays of rounds and of live counts) and count it once, at query time:
+    what the ``exchange_stats()`` of sampler and feature store return.
+    Slots are what was SHIPPED, rounds x ranks x bucket summed over the
+    ranks (and hops); a round is counted once, not once a rank.  Unpacked
+    slots are the request slots every round unpacks, frontier x rounds
+    summed over the ranks: ``1 - live / unpacked`` is the share of them
+    that read a dead row."""
     last = getattr(owner, "_last_exchange", None)
     if last is None:
         return None
     rounds = np.asarray(last[1]).astype(np.int64)
     slots = int((rounds * np.asarray(last[0], np.int64)).sum())
     live = int(np.asarray(last[2]).sum())
+    unpacked = int((rounds * np.asarray(last[3], np.int64)).sum())
     if not getattr(owner, "_exchange_recorded", False):
         owner._exchange_recorded = True
         from .. import telemetry
@@ -174,6 +189,8 @@ def record_exchange(owner, layer: str):
                           layer=layer).inc(float(slots))
         telemetry.counter("dist_exchange_live_slots_total",
                           layer=layer).inc(float(live))
+        telemetry.counter("dist_exchange_unpacked_slots_total",
+                          layer=layer).inc(float(unpacked))
         telemetry.counter("dist_exchange_rounds_total",
                           layer=layer).inc(float(rounds[0].sum()))
     return slots, live

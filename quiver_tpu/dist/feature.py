@@ -553,7 +553,7 @@ class DistFeature:
         self._overflow_recorded = False
         self.last_rounds = rounds
         self._last_exchange = (self.n * bucket_len(B, self.n, cap), rounds,
-                               live)
+                               live, B)
         self._exchange_recorded = False
         if ov_patch is not None:
             out = ov_patch(out)
@@ -632,6 +632,7 @@ class DistFeature:
         held a request; None before any call.  Read at query time like
         :meth:`overflow_stats`, and feeds ``dist_exchange_slots_total`` /
         ``dist_exchange_live_slots_total`` /
+        ``dist_exchange_unpacked_slots_total`` /
         ``dist_exchange_rounds_total{layer="feature"}`` once a call."""
         return record_exchange(self, "feature")
 

@@ -348,12 +348,18 @@ class DistGraphSampler:
         (:func:`~quiver_tpu.dist.exchange.bucket_len`: an owner's share of
         the frontier, shipped in as many rounds as the counts ask for),
         else the caller's one-round cap."""
-        caps, F = [], B
+        return [bucket_len(F, self.n, _cap(F, self.request_cap_frac, self.n))
+                for F in self.hop_frontiers(B)]
+
+    def hop_frontiers(self, B: int):
+        """A rank's frontier slots at each hop for a batch of ``B`` seeds:
+        ``B``, ``B (1 + k_1)``, ...; each is asked of the owners and
+        unpacked once a round."""
+        out, F = [], B
         for k in self.sizes:
-            caps.append(bucket_len(
-                F, self.n, _cap(F, self.request_cap_frac, self.n)))
+            out.append(F)
             F *= 1 + k
-        return caps
+        return out
 
     def sample(self, seed_batches: np.ndarray, key=None):
         """``seed_batches``: [n_shards, B] — one seed batch per device;
@@ -422,7 +428,7 @@ class DistGraphSampler:
         self.last_live = live       # [n_shards, L], beside last_overflow
         self.last_rounds = rounds
         self._last_exchange = (self.n * np.asarray(self.hop_caps(B)), rounds,
-                               live)
+                               live, self.hop_frontiers(B))
         self._exchange_recorded = False
         return n_id, n_mask, num, blocks
 
@@ -432,7 +438,8 @@ class DistGraphSampler:
         (rounds x ranks x bucket; each comes back carrying ``k`` draws) and
         those that held a request; None before any call.  Feeds
         ``dist_exchange_slots_total`` / ``dist_exchange_live_slots_total``
-        / ``dist_exchange_rounds_total{layer="sampler"}`` once a call, at
+        / ``dist_exchange_unpacked_slots_total`` /
+        ``dist_exchange_rounds_total{layer="sampler"}`` once a call, at
         query time like :meth:`overflow_stats`."""
         return record_exchange(self, "sampler")
 

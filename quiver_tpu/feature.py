@@ -69,6 +69,18 @@ def _fresh_bucket(n: int) -> int:
 _DEAD_STRIDE = 16
 
 
+def _dead_rows(slots: int, rows: int, dtype):
+    """A row of its own for each of ``slots`` slots whose row nobody
+    reads, out of a table of ``rows``: slot ``i`` asks for row ``i *
+    _DEAD_STRIDE % rows``, always in range.  One row asked for by every
+    such slot is the dearest there is (PERF.md, PR 33).  Used by the
+    masked gather here and by ``dist.exchange``'s unpacking."""
+    import jax.numpy as jnp
+
+    return (jnp.arange(slots, dtype=jnp.uint32) * _DEAD_STRIDE
+            % rows).astype(dtype)
+
+
 def _lookup_tables(tables, idx, mask=None):
     """``Feature.lookup_device`` over explicit ``(hot, order)`` arrays
     (from ``Feature._device_tables``), so a jitted caller can take the
@@ -82,9 +94,8 @@ def _lookup_tables(tables, idx, mask=None):
     read ANY row.  The sampler writes id 0 there, and one row asked for
     650 K times a step is the dearest there is: 16.2 ns a slot on a v5e
     where rows all over the table cost 10.7 (PERF.md, PR 33).  So a dead
-    slot asks for a row of its own, ``_DEAD_STRIDE`` rows on from the
-    last one's.  Live slots read the table's rows exactly; dead ones some
-    row of the table.  Without a mask (a caller's own ids) the semantics
+    slot asks for a row of its own (:func:`_dead_rows`).  Live slots read
+    the table's rows exactly; dead ones some row of the table.  Without a mask (a caller's own ids) the semantics
     are ``jnp.take``'s to the letter."""
     import jax
     import jax.numpy as jnp
@@ -95,8 +106,7 @@ def _lookup_tables(tables, idx, mask=None):
             idx = jnp.take(order, idx, mode="clip")
         if mask is None:
             return jnp.take(hot, idx, axis=0)
-        own = (jnp.arange(idx.shape[0], dtype=jnp.uint32) * _DEAD_STRIDE
-               % hot.shape[0]).astype(idx.dtype)
+        own = _dead_rows(idx.shape[0], hot.shape[0], idx.dtype)
         return hot.at[jnp.where(mask, idx, own)].get(
             mode="promise_in_bounds")
 

@@ -855,3 +855,46 @@ def test_host_cell_step_convs_slice_the_samplers_blocks(host_programs):
     assert not {op for op in convs
                 if re.search(r"/(gather|scatter(-add)?)$", op)}
     assert c.memory_analysis().temp_size_in_bytes < 900_000_000
+
+
+# sha256 of ``jax.jit(_masked_sage_gather).lower(...).as_text()`` at the SAGE
+# cell's table and frontier, recorded on PR 38's tree (f6856ad) and
+# unchanged on PR 39's, whose shared ``feature._dead_rows`` now makes the
+# index the gather's dead slots read
+MASKED_SAGE_GATHER = ("18f711957a08244868d58e450cc426c5d840ebc90a0dc9ce6e0a"
+                      "36ac245adde6")
+
+
+def _masked_sage_gather(t, i, mk):
+    from quiver_tpu.feature import _lookup_tables
+
+    return _lookup_tables((t, None), i, mk)
+
+
+def test_host_cell_lookup_unpacks_dead_slots_to_rows_of_their_own(
+        host_programs, one_chip):
+    """``jit_qt_dist_lookup`` at the cell's real shapes: the unpacking's
+    slots that sent nothing are no longer clipped to the last received row
+    (the parent's ``qt.exchange/jit(clip)`` and ``jnp.take``'s
+    ``qt.exchange/jit(_take)``: 14.9 ms a step with 56.5% of 1,081,344 slots
+    on one row, PERF.md section 5); each reads a row of its own, a
+    ``u32`` iota times the stride modulo the received rows, and the gather
+    is promised in bounds.  The one-chip fused step's masked gather, the
+    helper's other caller, lowers to the text it had before."""
+    import hashlib
+
+    text = host_programs["jit_qt_dist_lookup"].as_text()
+    ops = set(re.findall(r'op_name="([^"]*/qt\.exchange/[^"]*)"', text))
+    assert ops and not {op for op in ops if re.search(
+        r"/qt\.exchange/jit\((clip|_take)\)/", op)}
+    assert re.search(r'custom_call_target="AssumeGatherIndicesInBound"'
+                     r'[^\n]*op_name="[^"]*/while/body/[^"]*qt\.exchange/'
+                     r'gather"', text)
+    assert re.search(rf'u32\[{HOST_FRONTIER},1\][^\n]*remainder\([^\n]*'
+                     r'op_name="[^"]*/while/body/[^"]*qt\.exchange/', text)
+    lowered = jax.jit(_masked_sage_gather).lower(
+        _s(one_chip, (SAGE_CELL_NODES, 128), jnp.bfloat16),
+        _s(one_chip, (SAGE_CELL_FRONTIER,)),
+        _s(one_chip, (SAGE_CELL_FRONTIER,), jnp.bool_))
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == \
+        MASKED_SAGE_GATHER

@@ -293,6 +293,40 @@ def test_exchange_counters_count_slots_and_live_slots(mesh, rng):
     assert int(df.overflow_stats().sum()) == 0
 
 
+@pytest.mark.parametrize("form", ["one_round", "one_owner", "cap"])
+def test_exchange_counts_the_request_slots_it_unpacks(mesh, rng, form):
+    """``dist_exchange_unpacked_slots_total``: every round unpacks the whole
+    frontier, so F x rounds summed over the ranks, once a call; the live
+    slots are among them (``1 - live / unpacked`` is the dead share)."""
+    n, d, B = 256, 4, 2048
+    full = rng.normal(size=(n, d)).astype(np.float32)
+    cap = 128 if form == "cap" else None
+    df = DistFeature.from_row_ranges(full, mesh, EVEN, request_cap=cap)
+
+    def counted():
+        return telemetry.counter("dist_exchange_unpacked_slots_total",
+                                 layer="feature").value
+
+    before = counted()
+    if form == "one_round":     # the counters test's quarter of a batch
+        ids = rng.integers(0, n, (NHOSTS, B)).astype(np.int32)
+        valid = rng.random((NHOSTS, B)) < 0.25
+    else:                       # every id in rank 0's rows
+        ids = rng.integers(0, EVEN[1], (NHOSTS, B)).astype(np.int32)
+        valid = np.ones((NHOSTS, B), bool)
+    df.lookup(ids, valid)
+    slots, live = df.exchange_stats()
+    rounds = np.asarray(df.last_rounds)
+    assert (rounds == rounds[0]).all()
+    assert int(rounds[0]) == {"one_round": 1, "cap": 1,
+                              "one_owner": -(-B // bucket_len(B, NHOSTS))}[
+        form]
+    assert counted() - before == NHOSTS * B * int(rounds[0])
+    assert live <= counted() - before
+    df.exchange_stats()
+    assert counted() - before == NHOSTS * B * int(rounds[0])   # once a call
+
+
 # ---------------------------------------------------------------------------
 # The exact exchange ships buckets sized for an owner's share, in as many
 # rounds as its own counts ask for (PERF.md, PR 35)
@@ -427,6 +461,119 @@ def test_a_callers_cap_is_one_round_that_drops_and_counts(mesh, rng):
     assert np.array_equal(out[:, :cap], full[ids[:, :cap]])
     assert not out[:, cap:].any()
     assert df.exchange_stats() == (NHOSTS * NHOSTS * cap, NHOSTS * cap)
+
+
+# ---------------------------------------------------------------------------
+# The unpacking: a slot that sent nothing in a round reads a row of its own
+# and the ``where`` after it keeps zeros there (PERF.md, PR 39).  Tested on
+# ``exchange`` itself, over four of the eight devices, against a plain
+# reference: the owner's row where a request was sent, zeros elsewhere.
+import functools  # noqa: E402
+
+from jax import shard_map  # noqa: E402
+from jax.sharding import Mesh  # noqa: E402
+
+from quiver_tpu.dist.exchange import exchange  # noqa: E402
+
+UNPACK_RANKS, UNPACK_IDS, UNPACK_F = 4, 4096, 8192
+UNPACK_ROWS = {"lookup": ((8,), jnp.bfloat16), "hop": ((5,), jnp.int32)}
+
+
+def _unpack_row(layer, table, ids):
+    """What the owner of ``ids`` answers: a lookup's table rows, a hop's
+    ``k`` draws (never 0, as a hop ships them)."""
+    if layer == "lookup":
+        return table[ids]
+    k = UNPACK_ROWS["hop"][0][0]
+    return ids[..., None] * k + np.arange(k, dtype=np.int32) + 1
+
+
+@functools.lru_cache(maxsize=None)
+def _unpack_program(layer, cap):
+    """``(table, ids, valid) -> (answers, dropped, rounds)`` of ``exchange``
+    per rank; rank ``p`` owns ids ``[p N/n, (p+1) N/n)`` and answers any
+    other id with rubbish, so a request sent to the wrong owner shows."""
+    n, N = UNPACK_RANKS, UNPACK_IDS
+    mesh = Mesh(np.array(jax.devices()[:n]), ("data",))
+    shape, dtype = UNPACK_ROWS[layer]
+
+    def body(table, ids, valid):
+        ids, valid = ids[0], valid[0]
+        me = jax.lax.axis_index("data")
+
+        def serve(rids, rvalid, r):
+            own = ((rids * n // N) == me) & rvalid
+            rubbish = jnp.full((rids.shape[0],) + shape, 3, dtype)
+            return jnp.where(own[:, None], _unpack_row(
+                layer, table, jnp.clip(rids, 0, N - 1)).astype(dtype),
+                rubbish)
+
+        got, counts = exchange(f"qt.test.{layer}", "data", n, cap, ids,
+                               ids * n // N, valid, serve,
+                               jax.ShapeDtypeStruct(shape, dtype))
+        return got[None], counts.dropped[None], counts.rounds[None]
+
+    return jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(P(), P("data", None), P("data", None)),
+        out_specs=(P("data"), P("data"), P("data"))))
+
+
+def _unpack_reference(layer, cap, table, ids, valid):
+    """Per request: sent where valid and, under a cap, among the first
+    ``cap`` valid requests to its owner; the owner's row where sent, zeros
+    elsewhere.  Also the dropped counts and the rounds the exact exchange
+    takes: its fullest bucket over one's length, the same on every rank."""
+    n, N = UNPACK_RANKS, UNPACK_IDS
+    owner = ids * n // N
+    sent = np.zeros_like(valid)
+    fullest = 0
+    for p in range(n):
+        to_p = valid & (owner == p)
+        rank = np.cumsum(to_p, axis=1) - 1
+        sent |= to_p & (cap is None or rank < cap)
+        fullest = max(fullest, int(to_p.sum(axis=1).max()))
+    shape, dtype = UNPACK_ROWS[layer]
+    rows = np.asarray(_unpack_row(layer, table, ids)).astype(dtype)
+    want = np.where(sent.reshape(sent.shape + (1,) * len(shape)), rows, 0)
+    bucket = bucket_len(ids.shape[1], n, cap)
+    rounds = 1 if cap is not None else -(-fullest // bucket)
+    return want.astype(dtype), (valid & ~sent).sum(axis=1), rounds
+
+
+@pytest.mark.parametrize("layer", ["lookup", "hop"])
+@pytest.mark.parametrize("form", ["one_round", "one_owner", "cap_drops"])
+@pytest.mark.parametrize("frontier", ["dead_interleaved", "all_dead",
+                                      "all_live"])
+def test_unpacked_answers_are_the_owners_rows_or_zeros_bit_for_bit(
+        layer, form, frontier):
+    """Every round form (the exact exchange in one round, every id on one
+    owner in ``n``, a caller's cap that drops) over frontiers whose dead
+    slots sit among the live ones, or that are all dead or all live, for
+    a lookup's bf16 rows and a hop's int32 draws: the answers are the
+    reference's to the last bit, so a dead slot's own row never leaks."""
+    n, N, F = UNPACK_RANKS, UNPACK_IDS, UNPACK_F
+    rng = np.random.default_rng(39)
+    table = rng.normal(size=(N,) + UNPACK_ROWS["lookup"][0]).astype(
+        np.float32)
+    table = np.asarray(jnp.asarray(table, jnp.bfloat16))
+    hi = N // n if form == "one_owner" else N       # rank 0 owns them all
+    ids = rng.integers(0, hi, (n, F)).astype(np.int32)
+    valid = {"dead_interleaved": rng.random((n, F)) < 0.435,
+             "all_dead": np.zeros((n, F), bool),
+             "all_live": np.ones((n, F), bool)}[frontier]
+    cap = F // (4 * n) if form == "cap_drops" else None
+    got, dropped, rounds = (np.asarray(a) for a in _unpack_program(
+        layer, cap)(jnp.asarray(table), ids, valid))
+    want, want_dropped, want_rounds = _unpack_reference(layer, cap, table,
+                                                        ids, valid)
+    assert got.dtype == want.dtype
+    assert got.tobytes() == want.tobytes()
+    assert np.array_equal(dropped, want_dropped)
+    assert (rounds == want_rounds).all()
+    if form == "cap_drops" and frontier != "all_dead":
+        assert dropped.sum() > 0
+    if form == "one_owner" and frontier == "all_live":
+        assert want_rounds == n
 
 
 def test_row_ranges_with_the_overlay_kept(mesh, rng):

@@ -447,6 +447,37 @@ def test_a_fraction_under_one_is_one_round_that_drops_and_counts(small_graph):
     assert s.exchange_stats() == (RANKS * RANKS * cap, RANKS * cap)
 
 
+@pytest.mark.parametrize("traffic,frac", [("spread", 1.0),
+                                           ("one-owner", 1.0),
+                                           ("one-owner", 0.5)])
+def test_hops_count_the_request_slots_they_unpack(small_graph, traffic, frac):
+    """``dist_exchange_unpacked_slots_total{layer="sampler"}``: each hop
+    unpacks its whole frontier (``hop_frontiers``: B, B (1 + k_1), ...)
+    once a round, summed over the ranks; its live slots are among them."""
+    from quiver_tpu import telemetry
+
+    sizes, B = [3, 2], 4096
+    s = _four_rank_sampler(small_graph, sizes, request_cap_frac=frac)
+    assert s.hop_frontiers(B) == [B, B * 4]
+
+    def counted():
+        return telemetry.counter("dist_exchange_unpacked_slots_total",
+                                 layer="sampler").value
+
+    rng = np.random.default_rng(39)
+    seeds = (_spread_seeds(s, B, rng) if traffic == "spread" else
+             rng.integers(*s.row_starts_host[1:3], (RANKS, B)))
+    before = counted()
+    s.sample(seeds, key=13)
+    _, live = s.exchange_stats()
+    rounds = np.asarray(s.last_rounds)
+    assert (rounds == rounds[0]).all()
+    if traffic == "one-owner":
+        assert rounds[0, 0] == (RANKS if frac == 1.0 else 1)
+    unpacked = RANKS * int((rounds[0] * s.hop_frontiers(B)).sum())
+    assert counted() - before == unpacked and live <= unpacked
+
+
 # ---------------------------------------------------------------------------
 # The blocks say that they are positional, and the promise holds through the
 # exchange (PERF.md, PR 37)
