@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from ..sampler import LayerBlock
 from ..telemetry.device_scopes import MODEL_ATTENTION, MODEL_PROJECT
 
-__all__ = ["SAGEConv", "GATConv", "MaskedBatchNorm", "sources"]
+__all__ = ["SAGEConv", "GATConv", "MaskedBatchNorm", "sources", "targets"]
 
 
 def sources(x: jax.Array, block: LayerBlock) -> jax.Array:
@@ -34,16 +34,31 @@ def sources(x: jax.Array, block: LayerBlock) -> jax.Array:
     are ``x[T:]`` viewed ``[T, k, ...]``: no gather forward, a pad (not a
     scatter-add) backward.  Masked slots then read their own pad row
     instead of row 0; every consumer multiplies them by a zero mask either
-    way.  Any other block is gathered through ``nbr_local``.
+    way.  A typed tree's block keeps them at ``sources + b*k ..``, its first
+    source row (``block.layout.place``): the slice of its ``T k`` rows.
+    Any other block is gathered through ``nbr_local``.
     """
     if block.layout is None:
         return jnp.take(x, block.nbr_local, axis=0)
     t, k = block.mask.shape
-    if x.shape[0] != t * (1 + k):
+    _, s = block.layout.place(t)
+    if x.shape[0] < s + t * k:
         raise ValueError(
             f"positional block with T={t} targets and k={k} expects "
-            f"x of length T*(1+k)={t * (1 + k)}, got {x.shape[0]}")
-    return x[t:].reshape(t, k, *x.shape[1:])
+            f"x of at least {s + t * k} rows, got {x.shape[0]}")
+    return x[s:s + t * k].reshape(t, k, *x.shape[1:])
+
+
+def targets(x: jax.Array, block: LayerBlock) -> jax.Array:
+    """``[T, ...]`` rows of ``x`` that are the block's targets: ``x[:T]``,
+    or a positional block's ``(start, count)`` runs in order
+    (``block.layout.place``; the untyped tree's is ``x[:T]`` too), sliced
+    and put end to end."""
+    t = block.mask.shape[0]
+    if block.layout is None:
+        return x[:t]
+    runs, _ = block.layout.place(t)
+    return jnp.concatenate([x[a:a + n] for a, n in runs])
 
 
 class SAGEConv(nn.Module):

@@ -1,4 +1,4 @@
-"""Relational GAT (R-GAT), in two forms.
+"""Relational GAT (R-GAT), in three forms.
 
 :class:`RGNN` is the PUBLISHED model: OGB-LSC's MAG240M baseline
 (``examples/lsc/mag240m/rgnn.py --model rgat``, which torch-quiver's
@@ -21,6 +21,26 @@ s_j) + bias_r``; a target with no edge of r gets ``bias_r`` alone and a
 relation with no edge in the whole layer contributes nothing.  After the
 last layer: ``Linear -> BatchNorm -> ReLU -> Dropout -> Linear``.
 
+:class:`TypedRGNN` is the PUBLISHED model of MLPerf Training's GNN
+benchmark (MLCommons ``training/graph_neural_network``: ``RGNN(model=
+'rgat')`` on IGBH) over a TYPED tree (``GraphSageSampler(schema=...)``,
+which draws a fanout per relation): each layer is PyG's ``HeteroConv``
+summing, over the relations into a node's type, ``GATConv(in,
+hidden/heads, heads, add_self_loops=False)`` with an int ``in_channels``,
+so ONE weight ``W_r`` projects a relation's sources and its targets alike::
+
+    h_j = W_r x_j,  g_i = W_r x_i
+    e_ij = leaky_relu(a_src_r . h_j + a_dst_r . g_i, 0.2), per head
+    o_i^r = concat_heads(sum_j softmax_j(e_ij) h_j) + b_r
+    y_i = sum over relations r into type(i) of o_i^r
+
+then leaky ReLU (0.01) and dropout between layers, and one ``Linear`` on
+the seeds after the last.  It differs from ``RGNN`` in what the typed tree
+makes static: a relation's sources are a block of their own, so each is
+ONE dense product of its block (no sort, no row permutes, no
+``ragged_dot``); and in the model itself: a shared ``W_r`` (no
+``W_src`` / ``W_dst``), no skip, no BatchNorm, no ELU, no MLP head.
+
 :class:`RGAT` is this repo's OWN earlier form over ``hetero.py``'s
 per-relation blocks (per-type input projections, ReLU, no skip, no
 BatchNorm, no MLP head); it never reaches ``pipeline.py``.
@@ -38,10 +58,11 @@ from ..hetero import HeteroLayerBlock, HeteroSampledBatch
 from ..sampler import LayerBlock
 from ..telemetry.device_scopes import MODEL_ATTENTION, MODEL_PROJECT
 from .gat import lsc_frame
-from .layers import MaskedBatchNorm, _exact, _weighted_sum, sources
+from .layers import (MaskedBatchNorm, _exact, _weighted_sum, sources,
+                     targets)
 
 __all__ = ["RGAT", "RGNN", "RelGATConv", "MaskedBatchNorm",
-           "grouped_project", "rgnn_apply_fn"]
+           "grouped_project", "rgnn_apply_fn", "TypedGATConv", "TypedRGNN"]
 
 
 # ---------------------------------------------------- the published model
@@ -277,6 +298,131 @@ def rgnn_apply_fn(model: nn.Module):
                            rngs=rngs, mutable=["batch_stats"])
 
     return apply_fn
+
+
+# ------------------------------------------- the published IGBH model
+class TypedGATConv(nn.Module):
+    """PyG's ``HeteroConv({r: GATConv(in, features, heads,
+    add_self_loops=False)}, aggr="sum")`` over one layer of a typed tree:
+    ``blocks`` are the hop's relation blocks (``LayerBlock.layout``: the
+    relation, its targets' runs, its sources' first row), ``t`` the rows
+    of the layer's targets (the frontier's prefix).
+
+    A relation's sources are the slice of its ``[n, k]`` block
+    (``layers.sources``) and its targets the runs of its target type
+    (``layers.targets``): each is ONE dense product with ``W_r``, the one
+    weight PyG's ``GATConv`` keeps for an int ``in_channels``.  The softmax
+    runs over a target's live draws of the relation; a target with none
+    gets ``b_r`` alone.  A row's output is the sum over the relations into
+    its type (zeros for a type no relation reaches).  Per-head sums over
+    the lanes are products with a 0/1 matrix, in float32 proper.
+    ``dtype=jnp.bfloat16``: the projections' products and results in
+    bfloat16, the attention in float32."""
+
+    features: int           # per head
+    heads: int
+    num_relations: int
+    dtype: object = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array, blocks: Tuple[LayerBlock, ...],
+                 t: int) -> jax.Array:
+        r_all, h, c = self.num_relations, self.heads, self.features
+        glorot = nn.initializers.glorot_uniform(in_axis=-2, out_axis=-1,
+                                                batch_axis=(0,))
+        lin = self.param("lin", glorot, (r_all, x.shape[-1], h * c))
+        att_src = self.param("att_src", nn.initializers.glorot_uniform(
+            batch_axis=(0,)), (r_all, h, c))
+        att_dst = self.param("att_dst", nn.initializers.glorot_uniform(
+            batch_axis=(0,)), (r_all, h, c))
+        bias = self.param("bias", nn.initializers.zeros, (r_all, h * c))
+        # [H * C, H]: lane j belongs to head j // C
+        head_of = (jnp.arange(h * c)[:, None] // c
+                   == jnp.arange(h)).astype(jnp.float32)
+
+        def project(a, w):
+            if self.dtype is not None:
+                a, w = a.astype(self.dtype), w.astype(self.dtype)
+            return jnp.dot(a, w).astype(jnp.float32)
+
+        summed = {}     # a target type's runs -> the sum of its relations
+        for blk in blocks:
+            r = blk.layout.relation
+            n, k = blk.mask.shape
+            with jax.named_scope(MODEL_PROJECT):
+                s = project(sources(x, blk).reshape(n * k, -1), lin[r])
+                d = project(targets(x, blk), lin[r])
+            with jax.named_scope(MODEL_ATTENTION):
+                a_s = _exact(s, att_src[r].reshape(h * c, 1) * head_of)
+                a_d = _exact(d, att_dst[r].reshape(h * c, 1) * head_of)
+                # PyG GATConv's negative_slope
+                e = nn.leaky_relu(a_s.reshape(n, k, h) + a_d[:, None],
+                                  0.2)                          # [n, k, H]
+                live = blk.mask[..., None]
+                e = jnp.where(live, e, -jnp.inf)
+                top = jax.lax.stop_gradient(e.max(axis=1, keepdims=True))
+                top = jnp.where(jnp.isfinite(top), top, 0.0)
+                p = jnp.where(live, jnp.exp(e - top), 0.0)
+                den = p.sum(axis=1, keepdims=True)
+                alpha = p / jnp.where(den > 0, den, 1.0)
+                o = _weighted_sum(alpha, s.reshape(n, k, h * c),
+                                  head_of) + bias[r]
+            runs = blk.layout.targets
+            summed[runs] = summed[runs] + o if runs in summed else o
+        # the layer's targets in frontier order, run by run
+        pieces = []
+        for runs, o in summed.items():
+            at = 0
+            for a, cnt in runs:
+                pieces.append((a, o[at:at + cnt]))
+                at += cnt
+        out, at = [], 0
+        for a, rows in sorted(pieces, key=lambda p: p[0]):
+            if a > at:
+                out.append(jnp.zeros((a - at, h * c), jnp.float32))
+            out.append(rows)
+            at = a + rows.shape[0]
+        if at < t:
+            out.append(jnp.zeros((t - at, h * c), jnp.float32))
+        return jnp.concatenate(out)
+
+
+class TypedRGNN(nn.Module):
+    """The published R-GAT of MLPerf Training's GNN benchmark over a typed
+    tree (module docstring): a :class:`TypedGATConv` per hop, leaky ReLU
+    (slope 0.01, ``F.leaky_relu``'s default) and dropout between them, a
+    ``Linear`` to ``out_dim`` on the seeds.  ``blocks``: the batch's
+    layers, outermost first, each a tuple of relation blocks
+    (``GraphSageSampler(schema=...)``); ``x``: the frontier's rows as
+    stored (each relation's product widens its own).
+
+    Args:
+      hidden: layer width (``heads`` x the head size).
+      out_dim: classes.
+      num_relations: R, the schema's.
+    """
+
+    hidden: int
+    out_dim: int
+    num_relations: int
+    heads: int = 4
+    dropout: float = 0.2
+    dtype: object = None    # e.g. jnp.bfloat16: the matrix products' path
+
+    @nn.compact
+    def __call__(self, x: jax.Array, blocks, train: bool = False
+                 ) -> jax.Array:
+        for i, hop in enumerate(blocks):
+            # the hop appended its blocks' sources to the targets' frontier
+            t = x.shape[0] - sum(b.mask.size for b in hop)
+            x = TypedGATConv(self.hidden // self.heads, self.heads,
+                             self.num_relations, dtype=self.dtype,
+                             name=f"conv{i}")(x, hop, t)
+            if i != len(blocks) - 1:
+                x = nn.leaky_relu(x, 0.01)      # torch's F.leaky_relu
+                x = nn.Dropout(self.dropout, deterministic=not train)(x)
+        return nn.Dense(self.out_dim, dtype=self.dtype, name="head")(
+            x).astype(jnp.float32)
 
 
 # ------------------------------------ this repo's own per-relation-block form

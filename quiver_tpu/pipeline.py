@@ -76,11 +76,16 @@ def make_fused_train_step(sampler: GraphSageSampler, feature: Feature,
 
 def _tables(sampler: GraphSageSampler, feature: Feature):
     """The device tables a fused program reads — ``(indptr, indices,
-    (hot, order))`` — handed to it as ARGUMENTS: a device array captured
-    by a jitted closure is baked into the executable as a constant, a
-    second copy of graph and features in HBM for every program."""
+    (hot, order))``, and a typed graph's relation windows after them
+    (``GraphSageSampler.windows_device``) — handed to it as ARGUMENTS: a
+    device array captured by a jitted closure is baked into the executable
+    as a constant, a second copy of graph and features in HBM for every
+    program."""
     indptr, indices = sampler.csr_topo.to_device(sampler.device)
-    return indptr, indices, feature._device_tables()
+    tables = (indptr, indices, feature._device_tables())
+    if getattr(sampler, "schema", None) is not None:
+        tables += (sampler.windows_device(),)
+    return tables
 
 
 def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
@@ -100,6 +105,7 @@ def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
     gm, srng = sampler.gather_mode, sampler.sample_rng
     dedup = sampler.dedup
     caps = tuple(sampler.frontier_caps)
+    schema = getattr(sampler, "schema", None)
 
     if loss_fn is None:
         def loss_fn(logits, labels, mask):
@@ -111,11 +117,12 @@ def _fused_train_impl(sampler: GraphSageSampler, feature: Feature,
 
     def qt_fused_train_step(tables, state: TrainState, seeds, labels,
                             label_mask, key):
-        indptr, indices, feat_tables = tables
+        indptr, indices, feat_tables = tables[:3]
         ks, kd = jax.random.split(key)
         n_id, n_mask, num, blocks, _, _ = run_pipeline(
             dedup, indptr, indices, seeds, ks, sizes, caps, gather_mode=gm,
-            sample_rng=srng
+            sample_rng=srng, schema=schema,
+            windows=None if schema is None else tables[3]
         )
         x = _lookup_tables(feat_tables, n_id, n_mask)
 
@@ -189,13 +196,15 @@ def make_fused_eval_fn(sampler: GraphSageSampler, feature: Feature,
 
     dedup = sampler.dedup
     caps = tuple(sampler.frontier_caps)
+    schema = getattr(sampler, "schema", None)
 
     @jax.jit
     def qt_fused_eval(tables, params, seeds, key, model_state):
-        indptr, indices, feat_tables = tables
+        indptr, indices, feat_tables = tables[:3]
         n_id, n_mask, num, blocks, _, _ = run_pipeline(
             dedup, indptr, indices, seeds, key, sizes, caps, gather_mode=gm,
-            sample_rng=srng
+            sample_rng=srng, schema=schema,
+            windows=None if schema is None else tables[3]
         )
         x = _lookup_tables(feat_tables, n_id, n_mask)
         with jax.named_scope(MODEL):

@@ -18,6 +18,15 @@ result is exact (every sampled node kept); caps trade a vanishing amount of
 tail-dropping for bounded shapes — measured frontiers on power-law graphs
 sit far below the no-dedup bound, so a cap ~2x the typical frontier loses
 ~nothing and keeps XLA shapes small.
+
+Typed graphs (``schema=``, a :class:`Schema`): node types by id range and
+relations between them; each hop then draws ``sizes[h]`` sources PER
+RELATION from the part of a target's CSR row that holds that relation's
+source type (its window, read off the CSR once, when the sampler is built),
+into a typed tree whose every position has a type known when the program
+is traced (:meth:`Schema.tree`).  The untyped graph is the schema of one
+type and one relation whose window is the whole row, and samples as it
+always did.
 """
 
 from __future__ import annotations
@@ -38,19 +47,183 @@ from .ops.reindex import reindex
 from .telemetry.device_scopes import HOST_SAMPLE, SAMPLER, sampler_hop
 from .utils.topology import CSRTopo
 
-__all__ = ["GraphSageSampler", "SampledBatch", "LayerBlock", "POSITIONAL"]
+__all__ = ["GraphSageSampler", "SampledBatch", "LayerBlock", "POSITIONAL",
+           "Schema"]
 
 
 @jax.tree_util.register_static
 @dataclasses.dataclass(frozen=True)
 class _Positional:
-    """Type of :data:`POSITIONAL`: a pytree node with no leaves (and, as
-    a field-less frozen dataclass, equal to and hashed like every other
-    instance), so the marker is part of a block's tree *structure* and
-    crosses ``jax.jit`` as the Python value it is, never as a tracer."""
+    """Type of :data:`POSITIONAL`: a pytree node with no leaves (a frozen
+    dataclass, equal to and hashed like every instance with the same
+    fields), so the marker is part of a block's tree *structure* and
+    crosses ``jax.jit`` as the Python value it is, never as a tracer.
+
+    A typed tree's block (:meth:`Schema.tree`) carries its place: its
+    ``relation``, its ``targets`` as ``(start, count)`` runs of the previous
+    frontier in order, and ``sources``, the frontier row of its first
+    source: target ``b``'s sources are rows ``sources + b*k .. sources +
+    b*k + k - 1``.  :data:`POSITIONAL` (the untyped tree's block, from this
+    sampler, the stream overlay and ``dist/``) leaves its place to be read
+    off the block's shape: relation 0, the first ``T`` rows, the ``T k``
+    after them (:meth:`place`)."""
+
+    relation: int = 0
+    targets: Tuple[Tuple[int, int], ...] = ()
+    sources: Optional[int] = None
+
+    def place(self, t: int) -> Tuple[Tuple[Tuple[int, int], ...], int]:
+        """``(targets, sources)`` of a block of ``t`` targets."""
+        if self.sources is None:
+            return ((0, t),), t
+        return self.targets, self.sources
 
 
 POSITIONAL = _Positional()
+
+
+@dataclasses.dataclass(frozen=True)
+class Schema:
+    """The node types and relations of a typed graph, for a sampler that
+    draws per relation.
+
+    Args:
+      type_offsets: where each node type's id range starts, and the node
+        count last: ``(0, n_type0, n_type0 + n_type1, ..., N)`` (the form
+        ``models.RGNN`` takes).
+      relations: ``(source type, target type)`` of each relation; a
+        relation's edges are the CSR entries of a target of its target type
+        whose source is of its source type.
+      seed_type: the type of every seed.
+      names: optional relation names, the counters' ``relation`` label
+        (the relation's index where absent).
+
+    A CSR row must hold its sources grouped by type, in the order of the
+    types: true of any CSR whose rows are sorted by id.  Edges of a pair of
+    types no relation names are never drawn.
+    """
+
+    type_offsets: Tuple[int, ...]
+    relations: Tuple[Tuple[int, int], ...]
+    seed_type: int = 0
+    names: Tuple[str, ...] = ()
+
+    def __post_init__(self):
+        off = self.type_offsets
+        n = len(off) - 1
+        if n < 1 or off[0] != 0 or any(a > b for a, b in zip(off, off[1:])):
+            raise ValueError(f"type_offsets must rise from 0: {off}")
+        if not self.relations or any(
+                not (0 <= s < n and 0 <= t < n) for s, t in self.relations):
+            raise ValueError(f"relations must join types 0..{n - 1}: "
+                             f"{self.relations}")
+        if len(set(self.relations)) != len(self.relations):
+            raise ValueError(f"a pair of types is one relation: "
+                             f"{self.relations}")
+        if not 0 <= self.seed_type < n:
+            raise ValueError(f"seed_type {self.seed_type} of {n} types")
+        if self.names and len(self.names) != len(self.relations):
+            raise ValueError("one name per relation")
+
+    def label(self, r: int) -> str:
+        return self.names[r] if self.names else str(r)
+
+    def tree(self, batch: int, sizes: Sequence[int]
+             ) -> Tuple[Tuple[_Positional, ...], ...]:
+        """The typed tree of ``batch`` seeds under fanouts ``sizes``, hop 1
+        first: per hop, for each relation in order whose target type the
+        frontier holds, the place of its ``[n_t, k]`` block.  Targets are
+        every frontier position of the target type (runs of adjacent ones
+        merged); the block's sources are appended to the frontier in
+        relation order, so each position's type is known here."""
+        segs = [(self.seed_type, 0, batch)]     # (type, start, count)
+        size = batch
+        hops = []
+        for k in sizes:
+            runs, grown = [], []
+            for r, (s, t) in enumerate(self.relations):
+                tgt = []
+                for ty, a, n in segs:
+                    if ty != t:
+                        continue
+                    if tgt and tgt[-1][0] + tgt[-1][1] == a:
+                        tgt[-1] = (tgt[-1][0], tgt[-1][1] + n)
+                    else:
+                        tgt.append((a, n))
+                n_t = sum(n for _, n in tgt)
+                if not n_t:
+                    continue
+                runs.append(_Positional(relation=r, targets=tuple(tgt),
+                                        sources=size))
+                grown.append((s, size, n_t * k))
+                size += n_t * k
+            hops.append(tuple(runs))
+            segs += grown
+        return tuple(hops)
+
+    def slots(self, batch: int, sizes: Sequence[int]) -> list:
+        """Per relation, the draw slots of its blocks over all hops."""
+        out = [0] * len(self.relations)
+        for k, runs in zip(sizes, self.tree(batch, sizes)):
+            for run in runs:
+                out[run.relation] += k * sum(n for _, n in run.targets)
+        return out
+
+    def window_base(self) -> Tuple[int, ...]:
+        """Where each relation's windows start in :meth:`windows`' table:
+        two entries (start, end) per node of its target type."""
+        base, at = [], 0
+        for _, t in self.relations:
+            base.append(at)
+            at += 2 * (self.type_offsets[t + 1] - self.type_offsets[t])
+        return tuple(base)
+
+    def window_rows(self, relation: int, nodes: jax.Array) -> jax.Array:
+        """Traced: the entry of each node's window of ``relation`` in the
+        table, an ``indptr``-like pair ``table[i], table[i + 1]``."""
+        t = self.relations[relation][1]
+        return (self.window_base()[relation]
+                + 2 * (nodes - self.type_offsets[t]))
+
+    def windows(self, indptr, indices) -> np.ndarray:
+        """Host: the ``[start, end)`` of every relation's part of every row
+        of its target type, in positions of ``indices``, as one int32 table
+        (relation by relation, a node's pair side by side; padded to a
+        multiple of 128 for the ``blocked`` gather).  Raises where a row
+        does not hold its sources grouped in the order of the types."""
+        indptr = np.asarray(indptr, np.int64)
+        off = np.asarray(self.type_offsets, np.int64)
+        if off[-1] != len(indptr) - 1:
+            raise ValueError(f"type_offsets count {off[-1]} nodes, the CSR "
+                             f"{len(indptr) - 1}")
+        etype = np.searchsorted(off[1:-1], np.asarray(indices),
+                                side="right").astype(np.int8)
+        down = np.flatnonzero(np.diff(etype) < 0) + 1
+        if not np.isin(down, indptr).all():
+            raise ValueError("a CSR row does not hold its sources grouped "
+                             "in the order of the types (sort rows by id)")
+        parts = []
+        for s, t in self.relations:
+            lo, hi = off[t], off[t + 1]
+            e0 = indptr[lo]
+            seg = etype[e0:indptr[hi]]
+            row0, row1 = indptr[lo:hi] - e0, indptr[lo + 1:hi + 1] - e0
+            pair = np.empty((hi - lo, 2), np.int64)
+            for side, b in enumerate((s, s + 1)):
+                # entries of each row whose source type is below b
+                c = np.zeros(len(seg) + 1, np.int64)
+                np.cumsum(seg < b, out=c[1:])
+                pair[:, side] = e0 + row0 + c[row1] - c[row0]
+            parts.append(pair.reshape(-1))
+        table = np.concatenate(parts) if parts else np.zeros(0, np.int64)
+        out = np.zeros(-(-max(len(table), 1) // 128) * 128, np.int32)
+        out[:len(table)] = table
+        return out
+
+
+# the untyped graph: one type and one relation, whose window of a node is
+# its whole row (``indptr[v], indptr[v + 1]``)
+_UNTYPED = Schema((0, 1), ((0, 0),))
 
 
 class LayerBlock(NamedTuple):
@@ -89,10 +262,13 @@ class SampledBatch(NamedTuple):
     # sampler-level last_drops is unreliable under prefetching)
     version: Optional[int] = None  # streaming: the graph version this
     # batch sampled (the snapshot's), None on frozen-CSR samplers
-    window_misses: Optional[jax.Array] = None  # [L] per-hop count of
-    # targets whose CSR window did not fit the ``blocked`` path's block
-    # (window_stats(batch) reads it; ops.blockgather.NO_WINDOW where the
-    # hop has no window route)
+    window_misses: Optional[jax.Array] = None  # per block, hop 1 first
+    # (one per hop on the untyped tree): count of targets whose CSR window
+    # did not fit the ``blocked`` path's block (window_stats(batch) reads
+    # it; ops.blockgather.NO_WINDOW where the draw has no window route)
+    relation_live: Optional[jax.Array] = None  # [R] live draws of each
+    # relation over all hops (relation_stats(batch) reads it; one relation
+    # on the untyped tree)
 
     def to_pyg_adjs(self):
         """Ragged ``(n_id, batch_size, [Adj])`` view, PyG-compatible.
@@ -107,7 +283,12 @@ class SampledBatch(NamedTuple):
         slices exactly the next layer's node set.  Masked pad slots hold
         node 0 and are referenced by no edge, so they flow through as inert
         rows; ``n_id`` is returned in full (padded) for the same reason.
+
+        Untyped batches only: a typed batch's hop is a block per relation.
         """
+        if not all(isinstance(blk, LayerBlock) for blk in self.layers):
+            raise ValueError("a typed batch has a block per relation and "
+                             "hop; read its layers directly")
         adjs = []
         n_src = int(self.n_id.shape[0])
         for blk in self.layers:
@@ -126,9 +307,33 @@ class SampledBatch(NamedTuple):
         return (np.asarray(self.n_id), self.batch_size, adjs)  # quiverlint: sync-ok[PyG export boundary]
 
 
+def _blocks_in_order(layers):
+    """A batch's blocks hop 1 first, a typed hop's in relation order."""
+    for hop in layers[::-1]:
+        yield from ((hop,) if isinstance(hop, LayerBlock) else hop)
+
+
 def _hop_targets(layers):
-    """Targets of each hop, hop 1 first, from a batch's blocks."""
-    return [blk.mask.shape[0] for blk in layers[::-1]]
+    """Targets of each block, hop 1 first, from a batch's blocks."""
+    return [blk.mask.shape[0] for blk in _blocks_in_order(layers)]
+
+
+def _run_rows(frontier, fmask, runs):
+    """A block's targets: the ``(start, count)`` runs of the frontier and
+    its mask, in order (a run of the whole frontier is the frontier: JAX
+    adds no operation for a full slice or a concatenation of one)."""
+    return tuple(jnp.concatenate([a[s:s + n] for s, n in runs])
+                 for a in (frontier, fmask))
+
+
+def _relation_live(layers, relations):
+    """``[relations]`` int32 live draws of each relation (an untyped
+    block's relation is 0)."""
+    live = [jnp.int32(0)] * relations
+    for blk in _blocks_in_order(layers):
+        r = getattr(blk.layout, "relation", 0)
+        live[r] = live[r] + blk.mask.sum(dtype=jnp.int32)
+    return jnp.stack(live)
 
 
 def _window_misses(nfalls):
@@ -139,7 +344,8 @@ def _window_misses(nfalls):
 
 def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
                              gather_mode="xla", cum_weights=None,
-                             return_eid=False, sample_rng="auto"):
+                             return_eid=False, sample_rng="auto",
+                             schema=None, windows=None):
     """Traced multi-hop pipeline WITHOUT dedup — the TPU hot path.
 
     Design note (why no hash table / no sort): the reference dedups every
@@ -162,47 +368,69 @@ def _sample_pipeline_nodedup(indptr, indices, seeds, key, sizes,
     returned, value for value, for every consumer that indexes by it
     (``to_pyg_adjs``, host checks); inside a fused program nothing reads
     it and XLA drops its ``iota + where``.
+
+    With a ``schema`` (:class:`Schema`) the tree is typed: a hop draws
+    per relation, for every frontier position of the relation's target
+    type, from that node's window of the relation (``windows``, the table
+    of :meth:`Schema.windows`, read as ``indptr`` is: the same draw); each
+    relation's ``[n_t, k]`` block is appended to the frontier in relation
+    order and carries its place (:class:`_Positional`), so a hop returns a
+    tuple of blocks.  Each relation's draw takes its own key of the hop's.
     """
     with jax.named_scope(SAMPLER):
         B = seeds.shape[0]
         frontier = seeds.astype(jnp.int32)
         fmask = jnp.ones((B,), dtype=bool)
         keys = jax.random.split(key, len(sizes))
+    tree = (schema or _UNTYPED).tree(B, sizes)
     blocks = []
     nfalls = []
-    for l, k in enumerate(sizes):
+    for l, (k, runs) in enumerate(zip(sizes, tree)):
         with jax.named_scope(sampler_hop(l + 1)):
-            if cum_weights is not None:
-                out = sample_neighbors_weighted(indptr, indices, cum_weights,
-                                                frontier, k, keys[l],
-                                                seed_mask=fmask,
-                                                sample_rng=sample_rng,
-                                                gather_mode=gather_mode)
-            else:
-                out = sample_neighbors(indptr, indices, frontier, k, keys[l],
-                                       seed_mask=fmask,
-                                       gather_mode=gather_mode,
-                                       sample_rng=sample_rng)
-            nfalls.append(out.nfall)
-            t = frontier.shape[0]
-            pos = (t + jnp.arange(t, dtype=jnp.int32)[:, None] * k
-                   + jnp.arange(k, dtype=jnp.int32)[None, :])
-            blocks.append(
-                LayerBlock(
-                    nbr_local=jnp.where(out.mask, pos, 0),
-                    mask=out.mask,
-                    num_targets=fmask.sum().astype(jnp.int32),
-                    # None lets XLA DCE the eid computation entirely — an
-                    # extra [T, k] int32 per hop is ~40% more sampler output
-                    # HBM traffic, only worth it for edge-featured models
-                    eid=out.eid if return_eid else None,
-                    layout=POSITIONAL,
+            run_keys = ([keys[l]] if len(runs) == 1
+                        else jax.random.split(keys[l], len(runs)))
+            hop, outs = [], []
+            for run, run_key in zip(runs, run_keys):
+                tgt, tmask = _run_rows(frontier, fmask, run.targets)
+                table, rows = indptr, tgt
+                if schema is not None:
+                    table = windows
+                    rows = schema.window_rows(run.relation, tgt)
+                if cum_weights is not None:
+                    out = sample_neighbors_weighted(
+                        table, indices, cum_weights, rows, k, run_key,
+                        seed_mask=tmask, sample_rng=sample_rng,
+                        gather_mode=gather_mode)
+                else:
+                    out = sample_neighbors(table, indices, rows, k, run_key,
+                                           seed_mask=tmask,
+                                           gather_mode=gather_mode,
+                                           sample_rng=sample_rng)
+                nfalls.append(out.nfall)
+                t = tgt.shape[0]
+                pos = (run.sources + jnp.arange(t, dtype=jnp.int32)[:, None]
+                       * k + jnp.arange(k, dtype=jnp.int32)[None, :])
+                hop.append(
+                    LayerBlock(
+                        nbr_local=jnp.where(out.mask, pos, 0),
+                        mask=out.mask,
+                        num_targets=tmask.sum().astype(jnp.int32),
+                        # None lets XLA DCE the eid computation entirely —
+                        # an extra [T, k] int32 per hop is ~40% more
+                        # sampler output HBM traffic, only worth it for
+                        # edge-featured models
+                        eid=out.eid if return_eid else None,
+                        layout=POSITIONAL if schema is None else run,
+                    )
                 )
-            )
+                outs.append(out)
+            blocks.append(hop[0] if schema is None else tuple(hop))
             frontier = jnp.concatenate(
-                [frontier, jnp.where(out.mask, out.nbrs, 0).reshape(-1)]
+                [frontier] + [jnp.where(out.mask, out.nbrs, 0).reshape(-1)
+                              for out in outs]
             )
-            fmask = jnp.concatenate([fmask, out.mask.reshape(-1)])
+            fmask = jnp.concatenate(
+                [fmask] + [out.mask.reshape(-1) for out in outs])
     with jax.named_scope(SAMPLER):
         num_nodes = fmask.sum().astype(jnp.int32)
         drops = jnp.zeros((len(sizes),), jnp.int32)  # nothing ever dropped
@@ -330,15 +558,21 @@ def _is_stream_graph(obj) -> bool:
 
 def run_pipeline(dedup, indptr, indices, seeds, key, sizes, caps,
                  gather_mode="xla", cum_weights=None, return_eid=False,
-                 sample_rng="auto", overlay=None):
+                 sample_rng="auto", overlay=None, schema=None,
+                 windows=None):
     """Dispatch to the dedup='none' or dedup='hop' traced pipeline — the
     single place that mapping lives (sampler jit + fused train/eval).
 
     ``overlay`` (a dict of delta-CSR arrays + window scalars, see
     ``GraphSageSampler._build_stream_jit``) routes to the streaming
     overlay pipeline; it rides the positional (``dedup='none'``)
-    formulation only.
+    formulation only.  So does a typed graph's ``schema`` with its
+    ``windows`` table (:class:`Schema`), uniform draws only.
     """
+    if schema is not None and (dedup != "none" or overlay is not None
+                               or cum_weights is not None):
+        raise ValueError("a typed graph samples through the positional "
+                         "pipeline (dedup='none'), frozen and uniform")
     if overlay is not None:
         if dedup != "none":
             raise ValueError(
@@ -360,7 +594,8 @@ def run_pipeline(dedup, indptr, indices, seeds, key, sizes, caps,
                                         gather_mode=gather_mode,
                                         cum_weights=cum_weights,
                                         return_eid=return_eid,
-                                        sample_rng=sample_rng)
+                                        sample_rng=sample_rng,
+                                        schema=schema, windows=windows)
     return _sample_pipeline(indptr, indices, seeds, key, sizes, caps,
                             gather_mode=gather_mode,
                             cum_weights=cum_weights, return_eid=return_eid,
@@ -391,6 +626,14 @@ class GraphSageSampler:
         models.  Off by default: it costs an extra ``[T, k]`` int32 per
         hop of output traffic, and the reference's default e_id is empty
         too (``sage_sampler.py:143``).
+      schema: a typed graph's :class:`Schema`.  Each hop then draws
+        ``sizes[h]`` sources per relation into the typed tree of
+        :meth:`Schema.tree`; seeds must be of ``schema.seed_type``.  The
+        relations' windows are read off the CSR here, once, and ride into
+        every program as an argument (:meth:`windows_device`).  TPU mode,
+        the positional pipeline (``dedup`` ``"auto"`` / ``"none"``), a frozen
+        CSR and uniform draws only.  ``relation_stats()`` counts each
+        relation's slots and draws.
     """
 
     def __init__(self, csr_topo: CSRTopo, sizes: Sequence[int], device=None,
@@ -400,7 +643,8 @@ class GraphSageSampler:
                  edge_weights=None, return_eid: bool = False,
                  uva_budget: Union[int, str, None] = None,
                  sample_rng: str = "auto", uva_overlap: bool = True,
-                 uva_timings: Optional[dict] = None):
+                 uva_timings: Optional[dict] = None,
+                 schema: Optional[Schema] = None):
         assert mode in ("TPU", "CPU", "UVA", "GPU"), mode
         if mode == "GPU":  # compat alias from the reference API
             mode = "TPU"
@@ -465,6 +709,15 @@ class GraphSageSampler:
                 "UVA mode: hot-tier edge positions are sub-CSR local, so "
                 "global eids are unavailable; use TPU or CPU mode"
             )
+        if schema is not None and (mode != "TPU" or is_stream
+                                   or edge_weights is not None
+                                   or dedup != "none"):
+            raise ValueError("a typed graph samples in TPU mode over a "
+                             "frozen CSR, uniformly, with dedup='none'")
+        self.schema = schema
+        self._windows = None if schema is None else schema.windows(
+            csr_topo.indptr, csr_topo.indices)
+        self._windows_dev = {}
         self._cum_weights = None
         self._edge_weights = edge_weights
         if edge_weights is not None and mode == "TPU":
@@ -482,6 +735,20 @@ class GraphSageSampler:
                 self._stream.snapshot(device)  # warm the device view
             else:
                 csr_topo.to_device(device)
+            self.windows_device()
+
+    def windows_device(self):
+        """The typed graph's window table on the sampler's device (placed
+        once), or None for an untyped graph: what a program reads in place
+        of ``indptr`` to find a node's part of its row under a relation
+        (:meth:`Schema.windows`)."""
+        if self._windows is None:
+            return None
+        dev = self._windows_dev.get(self.device)
+        if dev is None:
+            dev = self._windows_dev[self.device] = jax.device_put(
+                self._windows, self.device)
+        return dev
 
     # -- topology access ----------------------------------------------
     @property
@@ -550,17 +817,22 @@ class GraphSageSampler:
         ret_eid = self.return_eid
 
         srng = self.sample_rng
+        schema = self.schema
+        relations = 1 if schema is None else len(schema.relations)
 
         @jax.jit
-        def fn(indptr, indices, cw, seeds, key):
-            return run_pipeline(dedup, indptr, indices, seeds, key, sizes,
-                                caps, gather_mode=gm, cum_weights=cw,
-                                return_eid=ret_eid, sample_rng=srng)
+        def fn(indptr, indices, cw, windows, seeds, key):
+            out = run_pipeline(dedup, indptr, indices, seeds, key, sizes,
+                               caps, gather_mode=gm, cum_weights=cw,
+                               return_eid=ret_eid, sample_rng=srng,
+                               schema=schema, windows=windows)
+            return out + (_relation_live(out[3], relations),)
 
         # the tables ride as ARGUMENTS: a device array captured by the
         # closure is baked into the executable as a constant — a second
         # copy of the graph in HBM per compiled batch size
-        return functools.partial(fn, indptr, indices, cw)
+        return functools.partial(fn, indptr, indices, cw,
+                                 self.windows_device())
 
     def _build_stream_jit(self, batch_size: int, windowed: bool):
         """Compile the overlay pipeline for one (batch, snapshot-shape)
@@ -645,16 +917,18 @@ class GraphSageSampler:
             from .utils.rng import make_key
 
             key = make_key(np.random.randint(0, 2**31 - 1))
-        n_id, n_mask, num_nodes, blocks, drops, misses = fn(seeds, key)
-        # [L] per-hop frontier-cap drop counts (always 0 without caps)
-        # and window misses; kept on device until someone asks via
-        # overflow_stats() / window_stats() — the counters are
-        # incremented there, at materialization, so the hot loop never
-        # pays a device sync for accounting
-        self._keep_counts(drops, misses, blocks)
+        n_id, n_mask, num_nodes, blocks, drops, misses, live = fn(seeds, key)
+        # [L] per-hop frontier-cap drop counts (always 0 without caps),
+        # window misses and each relation's draws; kept on device until
+        # someone asks via overflow_stats() / window_stats() /
+        # relation_stats() — the counters are incremented there, at
+        # materialization, so the hot loop never pays a device sync for
+        # accounting
+        self._keep_counts(drops, misses, blocks, live, B)
         return SampledBatch(
             n_id=n_id, n_id_mask=n_mask, num_nodes=num_nodes,
             batch_size=B, layers=blocks, drops=drops, window_misses=misses,
+            relation_live=live,
         )
 
     def _sample_stream(self, input_nodes, key, time_window) -> SampledBatch:
@@ -705,14 +979,53 @@ class GraphSageSampler:
             version=snap.version, window_misses=misses,
         )
 
-    def _keep_counts(self, drops, misses, blocks):
-        """The newest call's device-side counts, for ``overflow_stats()``
-        and ``window_stats()`` (the arrays and the hops' target counts,
-        not the batch: that would pin its frontier)."""
+    def _keep_counts(self, drops, misses, blocks, live=None, batch_size=0):
+        """The newest call's device-side counts, for ``overflow_stats()``,
+        ``window_stats()`` and ``relation_stats()`` (the arrays and the
+        blocks' target counts, not the batch: that would pin its
+        frontier)."""
         self.last_drops = drops
         self._drops_recorded = False
         self._last_window = (misses, _hop_targets(blocks))
         self._window_recorded = False
+        self._last_live = (live, batch_size)
+        self._live_recorded = False
+
+    def relation_stats(self, batch: Optional[SampledBatch] = None):
+        """Per relation, its draw slots and the live draws in them over all
+        hops: a list of ``{"relation", "slots", "live"}`` (``relation``:
+        the schema's name, else the index; one relation, ``"0"``, on an
+        untyped graph).  Slots come from the tree's shapes, so ``live /
+        slots`` is the share of the relation blocks' slots that hold a
+        draw.
+
+        ``batch`` / no ``batch`` as :meth:`overflow_stats`; only the
+        sampler-level form feeds ``sampler_relation_slots_total`` and
+        ``sampler_relation_live_slots_total`` (label ``relation``), once per
+        ``sample`` call.  None before any TPU-mode call.
+        """
+        if batch is not None:
+            live, seeds = batch.relation_live, batch.batch_size
+        else:
+            live, seeds = getattr(self, "_last_live", (None, 0))
+        if live is None:
+            return None
+        # the deliberate materialization point for the counts
+        live = np.asarray(live).tolist()
+        schema = self.schema or _UNTYPED
+        slots = schema.slots(seeds, self.sizes)
+        stats = [{"relation": schema.label(r), "slots": s, "live": n}
+                 for r, (s, n) in enumerate(zip(slots, live))]
+        if batch is None and not getattr(self, "_live_recorded", True):
+            self._live_recorded = True
+            for s in stats:
+                telemetry.counter("sampler_relation_slots_total",
+                                  relation=s["relation"]).inc(
+                                      float(s["slots"]))
+                telemetry.counter("sampler_relation_live_slots_total",
+                                  relation=s["relation"]).inc(
+                                      float(s["live"]))
+        return stats
 
     def overflow_stats(self, batch: Optional[SampledBatch] = None):
         """[L] per-hop counts of frontier nodes dropped by ``frontier_caps``.
@@ -743,12 +1056,13 @@ class GraphSageSampler:
         return arr
 
     def window_stats(self, batch: Optional[SampledBatch] = None):
-        """Per hop, how the ``blocked`` path fetched the draws:
-        a list of ``{"window", "fallback", "classic"}`` - targets served
-        by their covering block, targets compacted into the per-draw
-        fallback, and whether the WHOLE hop took the per-draw path (more
-        misses than fallback slots, a hop with ``k <= U``, or the ``xla``
-        path; ``window`` and ``fallback`` are then 0).
+        """Per block, hop 1 first (a hop's blocks in relation order on a
+        typed graph, one a hop otherwise), how the ``blocked`` path fetched
+        the draws: a list of ``{"window", "fallback", "classic"}`` -
+        targets served by their covering block, targets compacted into the
+        per-draw fallback, and whether the WHOLE draw took the per-draw
+        path (more misses than fallback slots, a hop with ``k <= U``, or
+        the ``xla`` path; ``window`` and ``fallback`` are then 0).
 
         ``batch`` / no ``batch`` as :meth:`overflow_stats`; only the
         sampler-level form feeds the registry, once per ``sample`` call.

@@ -16,9 +16,11 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "cellbench")
-# the cells whose model carries state and has a bfloat16 path of its own
+# the cells whose model carries state and has a bfloat16 path of its own,
+# and the typed tree's, whose model has the path and no state
 STATEFUL = ["mag240m-rgat.train-fused-typed",
-            "mag240m-gat.train-fused-stateful"]
+            "mag240m-gat.train-fused-stateful",
+            "igbh-rgat.train-fused-relfanout"]
 
 
 def cells():
@@ -60,7 +62,8 @@ print(json.dumps({{"correct": correct, "compared": compared,
 @pytest.mark.parametrize("cell", STATEFUL)
 def test_typed_cells_bf16_control_is_not_correct(cell):
     """The program's own lower-precision path (``RGNN(dtype=bfloat16)``,
-    ``GNN(dtype=bfloat16)``: the products' results in bfloat16), through
+    ``GNN(dtype=bfloat16)``, ``TypedRGNN(dtype=bfloat16)``: the products'
+    results in bfloat16), through
     ``run.run_cell(..., control=True)``, has to fail the cell's limits.  In
     a process of its own: ``run_cell`` points JAX's compile cache at the
     benchmark's."""
@@ -73,6 +76,41 @@ def test_typed_cells_bf16_control_is_not_correct(cell):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert line["correct"] is False, line
     assert line["failed"] == 0      # wrong, not broken
+
+
+STAND_IN = """
+import json, sys
+sys.path[:0] = [{root!r}, {bench!r}]
+import run
+_, cell, cfg, traffic = run.find_cell({cell!r})
+run.rehearsal_size(cfg, traffic)
+out = run.run_cell(cell, cfg, traffic, seed=2**31 + 77, seconds=0.5, trace=0)
+limits, prec = run.limits_of(cfg, cell), cfg["precision"]
+lower = out["numbers_fn"](out["replayed"], prec["matmul"],
+                          stand_in=prec["control"])
+print(json.dumps({{"sound": run.compare(out["numbers"], limits),
+                  "lower": run.compare(lower, limits)}}))
+"""
+
+
+def test_igbh_reference_one_precision_below_is_not_correct():
+    """The reference one precision below the stated one (``bf16_all``: the
+    products' results in bfloat16 too) in the program's place fails the
+    IGBH cell through the harness's own comparison, on the number that
+    sees a result's rounding (``logit_dist``: the first step's logits,
+    element by element), where the sound program keeps it."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "-c",
+         STAND_IN.format(root=ROOT, bench=BENCH,
+                         cell="igbh-rgat.train-fused-relfanout")],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    (sound, sound_ok), (lower, lower_ok) = line["sound"], line["lower"]
+    assert sound_ok is True and lower_ok is False, line
+    assert sound["logit_dist"]["value"] <= sound["logit_dist"]["limit"]
+    assert lower["logit_dist"]["value"] > lower["logit_dist"]["limit"]
 
 
 HOST = "papers100m-sage-host.train-dist"

@@ -401,8 +401,9 @@ def test_readers_log_every_span_beside_its_parts_once(load_reader, capsys):
 # ------------------------------------------------------- the benchmark file
 # sha256 of what ``BENCHMARK.json`` held at commit b4cbdf5 (PR 37), each list
 # as ``json.dumps(..., sort_keys=True)``: a PR that is not a ``benchmark``
-# PR may append to the lists and do nothing else.  A ``benchmark`` PR that
-# means to edit what is there records the new hashes here.
+# PR may append to the lists, and append new cells to an entry's
+# ``workloads``, and do nothing else.  A ``benchmark`` PR that means to
+# edit what is there records the new hashes here.
 ACCEPTED = {     # key -> (entries it had, their hash)
     "configs": (4, "4e5d6c6438a8bc191370f70d4c1546e5ee0122f2006276a669e971d21d1e3f84"),
     "workloads": (4, "c148ec71d89e0298f243fba94b0de81306c0a89a0d784649b2b161654448b51f"),
@@ -421,18 +422,36 @@ def _sha(value):
 
 
 def test_benchmark_json_only_grew():
-    """What the accepted benchmark had comes first and as it was; every
-    entry after it names a reader that exists and cells that exist."""
+    """What the accepted benchmark had comes first and as it was, but for
+    new cells appended to an entry's ``workloads`` after the cells it
+    listed; every entry after it names files that exist and cells that
+    exist, and every new cell reports the end-to-end metrics that list
+    cells."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     top = {k: bench[k] for k in ("command", "paths", "run_seconds")}
     assert set(bench) == set(top) | set(ACCEPTED)
     assert _sha(top) == ACCEPTED_TOP
+    was = {w["name"] for w in bench["workloads"][:ACCEPTED["workloads"][0]]}
     for key, (n, sha) in ACCEPTED.items():
-        assert _sha(bench[key][:n]) == sha, key
-    for key in ("configs", "workloads", "end_to_end"):
-        assert len(bench[key]) == ACCEPTED[key][0], key     # no new cell
+        kept = []
+        for entry in bench[key][:n]:
+            entry = dict(entry)
+            if "workloads" in entry:
+                old = [w for w in entry["workloads"] if w in was]
+                assert entry["workloads"][:len(old)] == old, entry["name"]
+                entry["workloads"] = old
+            kept.append(entry)
+        assert _sha(kept) == sha, key
     cells = {w["name"] for w in bench["workloads"]}
+    configs = {c["name"] for c in bench["configs"]}
+    for c in bench["configs"][ACCEPTED["configs"][0]:]:
+        assert os.path.isfile(os.path.join(ROOT, c["file"])), c["name"]
+    for w in bench["workloads"][ACCEPTED["workloads"][0]:]:
+        assert w["config"] in configs and os.path.isfile(os.path.join(
+            BENCH, "traffic", w["traffic"] + ".json")), w["name"]
+        assert all(w["name"] in m["workloads"] for m in bench["end_to_end"]
+                   if "workloads" in m), w["name"]
     moved = {m["name"] for m in bench["end_to_end"]}
     added = bench["per_layer"][ACCEPTED["per_layer"][0]:]
     assert [m["name"] for m in added][:len(NEW_IN_PR_38)] == NEW_IN_PR_38
