@@ -154,7 +154,9 @@ class RelGATConv(nn.Module):
     re-laid by the TPU's compiler (neither ``heads`` nor an odd ``k`` is a
     multiple of its 8-row tile), a pass over 1.6 GB each time.  Per-head
     sums over the lanes are products with a 0/1 matrix (``head_of``), in
-    float32 proper."""
+    float32 proper, but for the weights' gradient, a float32
+    multiply-reduce over each head's slice of lanes
+    (``layers._weighted_sum``)."""
 
     features: int           # per head
     heads: int
@@ -315,7 +317,9 @@ class TypedGATConv(nn.Module):
     runs over a target's live draws of the relation; a target with none
     gets ``b_r`` alone.  A row's output is the sum over the relations into
     its type (zeros for a type no relation reaches).  Per-head sums over
-    the lanes are products with a 0/1 matrix, in float32 proper.
+    the lanes are products with a 0/1 matrix in float32 proper, but for
+    the weights' gradient, a float32 multiply-reduce over each head's
+    slice of lanes (``layers._weighted_sum``).
     ``dtype=jnp.bfloat16``: the projections' products and results in
     bfloat16, the attention in float32."""
 

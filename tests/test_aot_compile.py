@@ -569,10 +569,14 @@ def _typed_fused_step(one_chip):
 
 # sha256 of ``_typed_fused_step(...).as_text()``.  It stood at
 # cb5ebd4e3bad9fdf3dcc0818faa6a2c8cefb0f19922bbc4beb81b0be33a7a043 from
-# ab18fb6 (PR 31) through da26c51 (PR 32).  Re-recorded on PR 33's tree,
-# which MEANS to change the typed step's program: its lookup is handed
-# the sampler's mask, as the SAGE step's above
-TYPED_STEP_RECORDED = "45d1a2cd8c96e8d41c8ca0b3ae21f5486168ad480427d6832adebb030e8f7d74"
+# ab18fb6 through da26c51, and at
+# 45d1a2cd8c96e8d41c8ca0b3ae21f5486168ad480427d6832adebb030e8f7d74 from
+# e2bb154 (its lookup is handed the sampler's mask, as the SAGE step's
+# above) through 5a9238d.  Re-recorded on the tree that MEANS to change
+# the typed step's program: the attention's weights' gradient is a
+# multiply-reduce per head's slice of lanes (``layers._weighted_sum``),
+# no longer a product with ``head_of``
+TYPED_STEP_RECORDED = "059cc5a6961d31788858aa1180a5a37d93c0a38a1b57263f409f1764eecf3e3f"
 
 
 def test_fused_typed_step_lowers_as_recorded(one_chip):
@@ -622,10 +626,14 @@ def _gat_fused_step(one_chip):
                                 GAT_PAPERS, GAT_EDGES, BATCH)
 
 
-# sha256 of ``_gat_fused_step(...).as_text()``, recorded on PR 36's tree,
-# which adds the program.  A PR that MEANS to change it (ROADMAP S12: the
-# attention's passes) records the new text's hash here
-GAT_STEP_RECORDED = "4bd8cabc8b89275f40288e6b307c28486c028264a1643a60c2ee4806fa641b14"
+# sha256 of ``_gat_fused_step(...).as_text()``.  It stood at
+# 4bd8cabc8b89275f40288e6b307c28486c028264a1643a60c2ee4806fa641b14 from
+# 1d1b1e2, which adds the program, through 5a9238d.  Re-recorded on the
+# tree that MEANS to change it (ROADMAP S12 (a)): the attention's weights'
+# gradient is a multiply-reduce per head's slice of lanes, so ``dout``'s
+# product with the projection is never written.  A PR that MEANS to
+# change it again records the new text's hash here
+GAT_STEP_RECORDED = "6ccb746ad572ef1a3673f3ce3024c0cb04fa60cb0696032a63b23fdf6805f1d4"
 
 
 def test_fused_gat_step_lowers_as_recorded(one_chip):
@@ -644,7 +652,10 @@ def test_fused_gat_step_compiles_at_the_cells_real_shapes(one_chip):
     (a target's 15 neighbours and its self-loop, slot by slot) is free:
     nothing copies, concatenates, pads or re-lays the PROJECTION, forward
     or backward (what is laid out by slot is the narrower float16 input);
-    and both parts of the convolution carry their scope in both passes."""
+    the attention's weights' gradient is a multiply-reduce per head (four
+    ``f32[26624,16]`` sums of a head's 256 lanes), so no multiply writes
+    ``dout``'s product with the projection; and both parts of the
+    convolution carry their scope in both passes."""
     from quiver_tpu.telemetry.device_scopes import parse_hlo_scopes
 
     c = _gat_fused_step(one_chip).compile()
@@ -666,6 +677,16 @@ def test_fused_gat_step_compiles_at_the_cells_real_shapes(one_chip):
                                             "transpose", "gather")
              or m[1] in ("concatenate", "pad", "copy", "transpose")]
     assert not moved, moved
+    assert not [m for m in made_by if m[0] == "multiply"], made_by
+    # ``dout`` over the slots is still written once, for the projection's
+    # cotangent: a 0/1 product's output fusion takes no row-repeating
+    # broadcast (ROADMAP S12 (b))
+    assert len([m for m in made_by if m[0] == "broadcast"]) <= 1, made_by
+    per_head = [line for line in entry.splitlines()
+                if re.search(r" = f32\[26624,16\]\{", line)
+                and "transpose(" in line
+                and "GNN/conv0/qt.model.attention/reduce_sum" in line]
+    assert len(per_head) == 4, per_head
     _, table = parse_hlo_scopes(text)
     for scope in ("qt.model.project", "qt.model.attention"):
         for backward in (False, True):

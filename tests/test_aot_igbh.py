@@ -129,11 +129,16 @@ def _on(sharding, tree):
         tree)
 
 
-# sha256 of the lowered text of the cell's step, recorded on the tree that
-# adds the cell.  A PR that MEANS to change the program records the new
+# sha256 of the lowered text of the cell's step.  It stood at
+# 18d59754f26cff0e421e0ad1f1e0a84b48552a71c52373502e7ccd524ee55c2d on the
+# tree that adds the cell (5a9238d).  Re-recorded on the tree that MEANS
+# to change the program: each relation block's weights' gradient is
+# a multiply-reduce per head's slice of lanes (``layers._weighted_sum``),
+# no longer a flat product re-laid to ``[n, k, 512]`` and multiplied with
+# ``head_of``.  A PR that MEANS to change the program records the new
 # text's hash here
-IGBH_STEP_RECORDED = ("18d59754f26cff0e421e0ad1f1e0a84b48552a71c52373502e7c"
-                      "cd524ee55c2d")
+IGBH_STEP_RECORDED = ("261d067e909eb0e3ecbde23eb38f8ddf906af623b3ad6c931034"
+                      "56a57a6eee29")
 
 
 def test_igbh_step_lowers_as_recorded(step):

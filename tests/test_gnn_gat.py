@@ -385,3 +385,36 @@ def test_gnn_and_rgnn_share_one_frame():
             frontier=(n_id, n_mask),
             model_state={"batch_stats": v["batch_stats"]})
         assert logits.shape == (4, 3) and "batch_stats" in state
+
+
+@pytest.mark.parametrize("n", [5, 16])
+@pytest.mark.parametrize("f", [8, 128, 256])
+@pytest.mark.parametrize("h", [1, 4])
+def test_weighted_sums_backward_is_the_plain_expressions(h, f, n):
+    """``layers._weighted_sum``'s own backward (a multiply-reduce per
+    head's slice of lanes) gives what ``jax.vjp`` of the plain expression
+    gives, for the weights and the projection, to 1e-6 of the largest
+    entry: the same float32 sums of ``f`` terms, in another order.  Two
+    targets' weights are all 0 (no live draw), as a typed block's are."""
+    from quiver_tpu.models.layers import _weighted_sum, _widen
+
+    rng = np.random.default_rng(h * 1000 + f * 10 + n)
+    t = 6
+    head_of = jnp.asarray(np.arange(h * f)[:, None] // f == np.arange(h),
+                          jnp.float32)
+    alpha = rng.random((t, n, h)).astype(np.float32)
+    alpha /= alpha.sum(axis=1, keepdims=True)
+    alpha[[1, 4]] = 0
+    s = rng.standard_normal((t, n, h * f)).astype(np.float32)
+    g = rng.standard_normal((t, h * f)).astype(np.float32)
+
+    def plain(a, x):
+        return (_widen(a, head_of) * x).sum(axis=1)
+
+    out, vjp = jax.vjp(lambda a, x: _weighted_sum(a, x, head_of), alpha, s)
+    want_out, want_vjp = jax.vjp(plain, alpha, s)
+    assert close(np.asarray(out), np.asarray(want_out), rel=1e-6)
+    for got, want in zip(vjp(jnp.asarray(g)), want_vjp(jnp.asarray(g))):
+        assert got.shape == want.shape and got.dtype == jnp.float32
+        assert close(np.asarray(got), np.asarray(want), rel=1e-6)
+    assert not np.asarray(vjp(jnp.asarray(g))[1])[[1, 4]].any()
